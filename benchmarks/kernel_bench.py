@@ -90,8 +90,8 @@ def run(emit=True):
     from repro.kernels import paged_attention as PA
     kvh, dh, ps, npg = 4, 64, 16, 16
     rng = jax.random.PRNGKey(2)
-    kp = jax.random.normal(rng, (npg, ps, kvh, dh))
-    vp = jax.random.normal(jax.random.PRNGKey(3), (npg, ps, kvh, dh))
+    kp = jax.random.normal(rng, (npg, kvh, ps, dh))
+    vp = jax.random.normal(jax.random.PRNGKey(3), (npg, kvh, ps, dh))
     bsl, pages = 4, 4                                  # 4 slots x 4 pages
     tab = jnp.arange(bsl * pages, dtype=jnp.int32).reshape(bsl, pages)
     pos = jnp.full((bsl,), pages * ps - 8, jnp.int32)

@@ -606,6 +606,17 @@ def run_mesh(*, tp: int = 2) -> dict:
     import subprocess
     import sys
 
+    import jax
+    if jax.default_backend() != "cpu":
+        # the child below runs on forced CPU host devices while this
+        # process holds the accelerator: its numbers would be CPU numbers
+        # under a serve-bench name
+        raise SystemExit(
+            f"run_mesh measures a forced CPU host-device mesh, but this "
+            f"process runs on {jax.default_backend()!r}; serve the "
+            f"tensor-parallel path on chips with `python chip_smoke.py "
+            f"--four-chips`")
+
     env = dict(os.environ,
                XLA_FLAGS=f"--xla_force_host_platform_device_count={tp}",
                JAX_PLATFORMS="cpu",
@@ -678,6 +689,8 @@ def main(argv=None) -> int:
                     help="where the traced case writes its Chrome-trace/"
                          "Perfetto JSON (uploaded as a CI artifact)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     n_requests = args.n_requests or (8 if args.smoke else 24)
     s_max = args.s_max or (64 if args.smoke else 128)

@@ -96,3 +96,18 @@ def op_histogram(hlo_text: str, top: int = 15) -> Dict[str, int]:
         op = m.group(1)
         hist[op] = hist.get(op, 0) + 1
     return dict(sorted(hist.items(), key=lambda kv: -kv[1])[:top])
+
+
+_TPU_KERNEL_RE = re.compile(
+    r'^\s*(?:ROOT\s+)?%([\w-]+?)(?:\.\d+)?\s*=.*'
+    r'custom_call_target="tpu_custom_call"', re.M)
+
+
+def tpu_kernels(hlo_text: str) -> Dict[str, int]:
+    """Compiled Pallas kernels in a TPU program: instruction name (the
+    ``name=`` each ``pallas_call`` carries) -> number of call sites.  Empty
+    for a CPU program, where kernels run as interpreted XLA ops."""
+    out: Dict[str, int] = {}
+    for name in _TPU_KERNEL_RE.findall(hlo_text):
+        out[name] = out.get(name, 0) + 1
+    return out

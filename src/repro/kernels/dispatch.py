@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.kernels.muxq_gemm import pick_bn
 
 Backend = Literal["fused", "fake", "fp"]
 FusedImpl = Literal["auto", "pallas", "interpret", "ref"]
@@ -153,6 +154,7 @@ def pack_site_buffer(w: jnp.ndarray, mask: Optional[np.ndarray], cfg, *,
                                    weight_bits=cfg.weight_bits,
                                    k_pad_to=k_pad_to)
 
+    pick_bn(w.shape[-1])              # raises now, not in the chip compiler
     if w.ndim == 2:
         mw = pack2d(w)
         w_int, sw = mw.w_int, mw.sw

@@ -12,7 +12,9 @@ accumulator; dequant (row scale x col scale) fused into the final K step.
 VMEM budget per step (defaults bm=bn=256, bk=512):
     x tile 256x512 int8 = 128 KiB, w tile 512x256 int8 = 128 KiB,
     acc 256x256 int32 = 256 KiB, out 256x256 bf16 = 128 KiB  << 16 MiB.
-MXU alignment: all tile dims multiples of 128.
+MXU alignment: tile dims are multiples of 128 (or span the whole axis);
+``block_scale`` rides whole in SMEM — a rank-1 VMEM block of one element
+is not a tile the TPU compiler accepts.
 """
 from __future__ import annotations
 
@@ -29,18 +31,40 @@ def _kernel(x_ref, w_ref, bs_ref, sx_ref, sw_ref, o_ref, acc_ref, *, nk: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # precision pinned: an int8 x int8 -> int32 product is exact, and a
+    # process-wide jax_default_matmul_precision="highest" would otherwise
+    # ask Mosaic for an fp32 contraction, which it refuses for int8
     prod = jax.lax.dot_general(
         x_ref[...], w_ref[...],
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
         preferred_element_type=jnp.int32,
     )
     # per-K-block exponent scaling: 2^exp on outlier blocks, 1 elsewhere
-    acc_ref[...] += prod * bs_ref[0]
+    # (the whole [K/bk] scale vector sits in SMEM; a scalar read per step)
+    acc_ref[...] += prod * bs_ref[pl.program_id(2)]
 
     @pl.when(pl.program_id(2) == nk - 1)
     def _finalize():
         o_ref[...] = (acc_ref[...].astype(jnp.float32)
                       * sx_ref[...] * sw_ref[...]).astype(o_ref.dtype)
+
+
+LANE = 128
+
+
+def pick_bn(n: int, bn: int = 256) -> int:
+    """Output-column tile for an N-wide weight: the whole axis when it fits
+    in one tile, else the largest multiple of 128 (<= ``bn``) dividing N.
+    Raises when no such tile exists — weights are packed offline at a known
+    width, so this fails at pack time, not inside the chip's compiler."""
+    if n <= bn:
+        return n
+    for t in range(bn - bn % LANE, 0, -LANE):
+        if n % t == 0:
+            return t
+    raise ValueError(f"N={n} has no column tile: it exceeds bn={bn} and no "
+                     f"multiple of {LANE} up to {bn} divides it")
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "out_dtype",
@@ -66,11 +90,8 @@ def muxq_gemm(x_int: jnp.ndarray, w_int: jnp.ndarray,
     if pad_m:
         x_int = jnp.pad(x_int, ((0, pad_m), (0, 0)))
         sx = jnp.pad(sx, ((0, pad_m), (0, 0)))
-    # N stays un-padded (weights are packed offline at a known width); pick
-    # the largest tile that divides it instead
-    bn = min(bn, n)
-    while n % bn:
-        bn -= 1
+    # N stays un-padded (weights are packed offline at a known width)
+    bn = pick_bn(n, bn)
     nk = k // bk
 
     out = pl.pallas_call(
@@ -79,7 +100,7 @@ def muxq_gemm(x_int: jnp.ndarray, w_int: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1,), lambda i, j, kk: (kk,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((bm, 1), lambda i, j, kk: (i, 0)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
@@ -87,5 +108,6 @@ def muxq_gemm(x_int: jnp.ndarray, w_int: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((m + pad_m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="muxq_gemm",
     )(x_int, w_int, block_scale, sx, sw)
     return out[:m] if pad_m else out

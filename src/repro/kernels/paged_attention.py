@@ -15,6 +15,11 @@ magnitude-redistribution rows (``k_redist``/``v_redist`` [kvh, dh]: 2^e
 on calibrated outlier channels) — so the *packed* int4 bytes are what
 crosses HBM, half the int8 traffic.
 
+Pages are HEAD-MAJOR, ``[n_pages, kvh, ps, dh]``: one (page, head) block
+is a ``(ps, dh)`` tile whose last two dims the TPU compiler accepts (ps a
+multiple of 8, dh the whole axis).  A position-major page would put a
+size-1 head block on the second-minor axis, which it refuses.
+
 The query side is a ``[slot, sq]`` BLOCK, not a single token:
 
   * decode           — sq=1, ``pos[b]`` the slot's write position;
@@ -98,8 +103,8 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, pos, *,
                         softcap: Optional[float] = None):
     """Gather-then-attend reference.  q [b, h, dh] (decode) or
     [b, sq, h, dh] (verify block / prefill chunk); k/v_pages
-    [n_pages, ps, kvh, dh] (+ optional [n_pages, ps, kvh, 1] int8 scales;
-    int4 pages store nibble-packed [n_pages, ps, kvh, dh//2] with bf16
+    [n_pages, kvh, ps, dh] (+ optional [n_pages, kvh, ps, 1] int8 scales;
+    int4 pages store nibble-packed [n_pages, kvh, ps, dh//2] with bf16
     scales and per-head [kvh, dh] ``k_redist``/``v_redist`` inverse
     redistribution rows); page_table [b, pages] int32; pos [b] int32 —
     the absolute position of each slot's FIRST query row (query row i
@@ -114,11 +119,12 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, pos, *,
     if squeeze:
         q = q[:, None]                                # [b, 1, h, dh]
     b, sq, h, dh = q.shape
-    kvh = k_pages.shape[2]
+    kvh = k_pages.shape[1]
     g = h // kvh
 
     def gather(pages):
-        gp = pages[page_table]                        # [b, P, ps, kvh, *]
+        gp = pages[page_table]                        # [b, P, kvh, ps, *]
+        gp = gp.transpose(0, 1, 3, 2, 4)              # [b, P, ps, kvh, *]
         return gp.reshape(b, -1, *gp.shape[3:])
 
     kk, vv = gather(k_pages), gather(v_pages)
@@ -173,19 +179,19 @@ def _kernel(tab_ref, pos_ref, win_ref,              # scalar prefetch
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)               # [sq*g, dh]
-    k = k_ref[0, :, 0]                                # [ps, dh | dh//2]
-    v = v_ref[0, :, 0]
+    k = k_ref[0, 0]                                   # [ps, dh | dh//2]
+    v = v_ref[0, 0]
     if mode == "int4":
         # unpack two nibbles per byte, apply the per-(pos, head) scale and
-        # the per-head inverse redistribution rows ([1, dh] block bcast):
-        # only the packed int4 bytes ever crossed HBM
+        # the per-head inverse redistribution row ([1, dh] bcast): only
+        # the packed int4 bytes ever crossed HBM
         k = (unpack_int4(k).astype(jnp.float32)
-             * ks_ref[0, :, 0].astype(jnp.float32) * kr_ref[...])
+             * ks_ref[0, 0].astype(jnp.float32) * kr_ref[0])
         v = (unpack_int4(v).astype(jnp.float32)
-             * vs_ref[0, :, 0].astype(jnp.float32) * vr_ref[...])
+             * vs_ref[0, 0].astype(jnp.float32) * vr_ref[0])
     elif mode == "int8":
-        k = k.astype(jnp.float32) * ks_ref[0, :, 0].astype(jnp.float32)
-        v = v.astype(jnp.float32) * vs_ref[0, :, 0].astype(jnp.float32)
+        k = k.astype(jnp.float32) * ks_ref[0, 0].astype(jnp.float32)
+        v = v.astype(jnp.float32) * vs_ref[0, 0].astype(jnp.float32)
     else:
         k = k.astype(jnp.float32)
         v = v.astype(jnp.float32)
@@ -236,13 +242,13 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, pos, *,
     per grid step with online softmax, so the verify block (sq=k) and the
     chunked-prefill read (sq=C) cost ONE pass over the key pages — not sq
     passes.  Int4 pages arrive nibble-packed (last dim dh//2) with
-    [kvh, dh] redistribution rows; the kernel block loads one page of
-    *packed* bytes and dequantizes in VMEM."""
+    [kvh, dh] redistribution rows; the kernel block loads one
+    (page, head) tile of *packed* bytes and dequantizes in VMEM."""
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, None]
     b, sq, h, dh = q.shape
-    n_pages, ps, kvh, pk_dh = k_pages.shape
+    n_pages, kvh, ps, pk_dh = k_pages.shape
     assert h % kvh == 0
     g = h // kvh
     nj = page_table.shape[1]
@@ -260,11 +266,11 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, pos, *,
 
     # page blocks: physical page tab[b, j], kv head hh, all ps positions
     kv_spec = pl.BlockSpec(
-        (1, ps, 1, pk_dh),
-        lambda bb, hh, j, tab, pos_r, win_r: (tab[bb, j], 0, hh, 0))
+        (1, 1, ps, pk_dh),
+        lambda bb, hh, j, tab, pos_r, win_r: (tab[bb, j], hh, 0, 0))
     sc_spec = pl.BlockSpec(
-        (1, ps, 1, 1),
-        lambda bb, hh, j, tab, pos_r, win_r: (tab[bb, j], 0, hh, 0))
+        (1, 1, ps, 1),
+        lambda bb, hh, j, tab, pos_r, win_r: (tab[bb, j], hh, 0, 0))
     q_spec = pl.BlockSpec(
         (1, 1, sq * g, dh),
         lambda bb, hh, j, tab, pos_r, win_r: (bb, hh, 0, 0))
@@ -283,12 +289,13 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, pos, *,
         in_specs += [_inert_spec(), _inert_spec()]
         args += [_inert, _inert]
     if mode == "int4":
-        # per-head inverse redistribution rows: block [1, dh] at row hh
+        # per-head inverse redistribution rows as [kvh, 1, dh]: block
+        # (1, 1, dh) at head hh keeps the tile's last two dims whole
         rd_spec = pl.BlockSpec(
-            (1, dh), lambda bb, hh, j, tab, pos_r, win_r: (hh, 0))
+            (1, 1, dh), lambda bb, hh, j, tab, pos_r, win_r: (hh, 0, 0))
         in_specs += [rd_spec, rd_spec]
-        args += [jnp.asarray(k_redist, jnp.float32),
-                 jnp.asarray(v_redist, jnp.float32)]
+        args += [jnp.reshape(k_redist, (kvh, 1, dh)).astype(jnp.float32),
+                 jnp.reshape(v_redist, (kvh, 1, dh)).astype(jnp.float32)]
     else:
         in_specs += [_inert_spec(), _inert_spec()]
         args += [_inert, _inert]
@@ -310,6 +317,7 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, pos, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, sq * g, dh), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(table, pos32, win, *args)
     out = out.reshape(b, kvh, sq, g, dh).transpose(0, 2, 1, 3, 4)
     out = out.reshape(b, sq, h, dh)

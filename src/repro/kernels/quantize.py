@@ -45,5 +45,6 @@ def rowwise_quantize(x: jnp.ndarray, *, bits: int = 8, bm: int = 128,
         out_shape=[jax.ShapeDtypeStruct((m + pad_m, k), jnp.int8),
                    jax.ShapeDtypeStruct((m + pad_m, 1), jnp.float32)],
         interpret=interpret,
+        name="rowwise_quantize",
     )(x)
     return (q[:m], s[:m]) if pad_m else (q, s)

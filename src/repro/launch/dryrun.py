@@ -1,38 +1,35 @@
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell and
 record memory / cost / collective analysis for the roofline report.
 
-MUST set the placeholder device count before ANY jax import (jax locks the
-device count at first init).
+``main`` asks for 512 placeholder CPU devices before JAX brings up its
+backend (the device count locks at first use); importing this module
+leaves the process's device setup alone.
 """
+import argparse
+import json
 import os
+import sys
+import time
+import traceback
+from pathlib import Path
 
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
-                           + os.environ.get("XLA_FLAGS_EXTRA", ""))
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-import argparse  # noqa: E402
-import json  # noqa: E402
-import sys  # noqa: E402
-import time  # noqa: E402
-import traceback  # noqa: E402
-from pathlib import Path  # noqa: E402
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-
-from repro.analysis import hlo as hlo_mod  # noqa: E402
-from repro.analysis import roofline as R  # noqa: E402
-from repro.configs import get_config, list_archs  # noqa: E402
-from repro.core.muxq import QuantConfig  # noqa: E402
-from repro.core.prequant import prequantize_params  # noqa: E402
-from repro.launch import specs as SP  # noqa: E402
-from repro.launch import steps as ST  # noqa: E402
-from repro.launch.mesh import make_production_mesh  # noqa: E402
-from repro.models import moe as moe_mod  # noqa: E402
-from repro.models import transformer as T  # noqa: E402
-from repro.optim import adamw  # noqa: E402
-from repro.parallel import sharding as SH  # noqa: E402
-from repro.parallel.act_sharding import (set_activation_sharding,  # noqa: E402
+from repro.analysis import hlo as hlo_mod
+from repro.analysis import roofline as R
+from repro.configs import get_config, list_archs
+from repro.core.muxq import QuantConfig
+from repro.core.prequant import prequantize_params
+from repro.launch import specs as SP
+from repro.launch import steps as ST
+from repro.launch.mesh import make_production_mesh
+from repro.models import moe as moe_mod
+from repro.models import transformer as T
+from repro.optim import adamw
+from repro.parallel import sharding as SH
+from repro.parallel.act_sharding import (set_activation_sharding,
                                           set_cache_update_mode)
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "benchmarks" / "results" / "dryrun"
@@ -69,13 +66,10 @@ def _mem_dict(compiled):
 
 def _cost_dict(compiled):
     try:
-        c = compiled.cost_analysis()
+        c = compiled.cost_analysis() or {}
     except Exception:
         return {}
-    if isinstance(c, (list, tuple)):
-        c = c[0] if c else {}
-    return {k: float(v) for k, v in dict(c).items()
-            if isinstance(v, (int, float))}
+    return {k: float(v) for k, v in c.items() if isinstance(v, (int, float))}
 
 
 def _lower_cell(cfg, shape, mesh, quant: str, *, fsdp: bool, seq_shard: bool,
@@ -169,7 +163,8 @@ def lower_paged_cell(arch: str, tp: int, *, kv_mode: str = "int8",
     tokens = jax.ShapeDtypeStruct((max_batch, 1), jnp.int32)
     table = jax.ShapeDtypeStruct((max_batch, bucket), jnp.int32)
     pos = jax.ShapeDtypeStruct((max_batch,), jnp.int32)
-    lowered = eng._decode.lower(abs_p, tokens, pool.state(), table, pos)
+    lowered = eng._decode.lower(eng.weights, tokens, pool.state(), table,
+                                pos)
     return {"arch": arch, "tp": tp, "kv_mode": kv_mode,
             "n_kv_heads": cfg.n_kv_heads,
             "heads_sharded": pool.heads_sharded,
@@ -288,6 +283,8 @@ def _save(rec: dict, save: bool):
 
 
 def main(argv=None):
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
+                               + os.environ.get("XLA_FLAGS_EXTRA", ""))
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all", choices=["all", *SP.SHAPES])

@@ -8,12 +8,20 @@ parallel/collectives.py treat it accordingly.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: the compiler propagates shardings from the in/out specs
+    # (jax.make_mesh defaults to Explicit axes, which make every gather on
+    # a sharded operand name its output sharding)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -21,4 +29,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     if data * model > n:
         data, model = n, 1
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

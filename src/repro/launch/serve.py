@@ -3,6 +3,9 @@ MUXQ artifact (calibrate → plan → prequantize → pack), serve a batch of
 prompts through the continuous-batching engine and report serving metrics
 (tokens/s, TTFT, page-pool occupancy/fragmentation).
 
+``--arch`` is served at its published widths with seeded random weights;
+``--reduced`` serves the arch's small CPU-rehearsal config instead.
+
 Observability (see docs/OBSERVABILITY.md): ``--trace-out PATH`` records the
 run's request/step lifecycle and writes a Chrome-trace/Perfetto JSON;
 ``--obs`` turns on the quant-quality observers (per-site activation stats
@@ -24,6 +27,7 @@ from repro.core.muxq import QuantConfig
 from repro.core.policy import SitePolicy
 from repro.data.pipeline import PipelineConfig, TokenPipeline
 from repro.kernels import dispatch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.obs.quality import QualityObserver
 from repro.obs.trace import TraceRecorder
@@ -34,6 +38,10 @@ from repro.serve.engine import Request, ServeEngine
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="gpt2-small")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the arch's reduced config (a few layers at "
+                         "small width: the CPU rehearsal size) instead of "
+                         "its published widths")
     ap.add_argument("--quant", default="muxq",
                     choices=["fp", "naive", "muxq", "llm_int8", "smoothquant"])
     ap.add_argument("--backend", default="fake", choices=["fake", "fused"],
@@ -113,7 +121,7 @@ def main(argv=None) -> int:
                          "JSON to this path")
     args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch, reduced=True)
+    cfg = get_config(args.arch, reduced=args.reduced)
     if args.tp < 1:
         raise SystemExit(f"--tp must be >= 1, got {args.tp}")
     if args.tp > jax.device_count():
@@ -122,6 +130,7 @@ def main(argv=None) -> int:
             f"only {jax.device_count()} device(s) are visible — lower --tp "
             f"or expose more devices (CPU test meshes: "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={args.tp})")
+    enable_compile_cache()
     params = T.init_params(cfg, jax.random.PRNGKey(0))
     kv_mode = None if args.kv_mode == "auto" else args.kv_mode
     recorder = TraceRecorder() if args.trace_out else None

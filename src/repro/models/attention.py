@@ -208,8 +208,8 @@ def attention_decode_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     x [b, 1, d].  ``cache`` holds one layer's page pool plus the pool-wide
     routing state:
 
-      k/v          [n_pages, page_size, kvh, dh]  (int8 pages carry
-      k/v_scale    [n_pages, page_size, kvh, 1]   per-(pos, head) scales)
+      k/v          [n_pages, kvh, page_size, dh]  (int8 pages carry
+      k/v_scale    [n_pages, kvh, page_size, 1]   per-(pos, head) scales)
       page_table   [b, pages_per_slot] int32 — physical page per logical
                    page; 0 is the reserved scratch page (inactive slots /
                    unallocated tail)
@@ -233,7 +233,7 @@ def attention_decode_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     b, one, d = x.shape
     pos = cache["pos"]                                      # [b]
     page_table = cache["page_table"]                        # [b, P]
-    ps = cache["k"].shape[1]
+    ps = cache["k"].shape[2]
     qkv = ctx("attn_qkv", x, p["wqkv"], mask=sq.get("attn_qkv"),
               smooth=sq.get("attn_qkv@smooth"), fused=sq.get("attn_qkv@fused"))
     if "bqkv" in p:
@@ -262,7 +262,7 @@ def attention_decode_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     page_idx = jnp.take_along_axis(page_table, (pos // ps)[:, None], 1)[:, 0]
     offset = pos % ps
     new_cache = _write_cache(cache, {
-        n: cache[n].at[page_idx, offset].set(
+        n: cache[n].at[page_idx, :, offset].set(
             parts[n][:, 0].astype(cache[n].dtype)) for n in parts})
 
     # read path: the jnp gather reference on CPU, the Pallas kernel
@@ -314,7 +314,7 @@ def attention_verify_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     pos = cache["pos"]                                      # [b]
     n_valid = cache["n_valid"]                              # [b]
     page_table = cache["page_table"]                        # [b, P]
-    ps = cache["k"].shape[1]
+    ps = cache["k"].shape[2]
     qkv = ctx("attn_qkv", x, p["wqkv"], mask=sq.get("attn_qkv"),
               smooth=sq.get("attn_qkv@smooth"), fused=sq.get("attn_qkv@fused"))
     if "bqkv" in p:
@@ -341,7 +341,7 @@ def attention_verify_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     page_idx = jnp.where(valid, page, 0)
     offset = positions % ps
     new_cache = _write_cache(cache, {
-        n: cache[n].at[page_idx, offset].set(
+        n: cache[n].at[page_idx, :, offset].set(
             parts[n].astype(cache[n].dtype)) for n in parts})
 
     win = jnp.where(jnp.asarray(window_flag), cfg.window_size,
@@ -371,8 +371,8 @@ def attention_prefill_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     tokens is padding, and slots not advancing this step are all-padding
     rows).  ``cache`` holds one layer's page pool plus routing state:
 
-      k/v          [n_pages, ps, kvh, dh]  (int8 pages carry
-      k/v_scale    [n_pages, ps, kvh, 1]   per-(pos, head) scales)
+      k/v          [n_pages, kvh, ps, dh]  (int8 pages carry
+      k/v_scale    [n_pages, kvh, ps, 1]   per-(pos, head) scales)
       page_table   [b, pages] int32 — the prefilling slots' page-table
                    rows, sliced to the step's bucketed page budget (rows
                    of idle slots are all scratch page 0)
@@ -403,7 +403,7 @@ def attention_prefill_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     form) is normalized to the batched shapes with b=1."""
     sq = sq or {}
     b, C, d = x.shape
-    ps = cache["k"].shape[1]
+    ps = cache["k"].shape[2]
     start = jnp.asarray(cache["start"], jnp.int32)
     write_lo = jnp.asarray(cache["write_lo"], jnp.int32)
     write_hi = jnp.asarray(cache["write_hi"], jnp.int32)
@@ -445,7 +445,7 @@ def attention_prefill_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     page_idx = jnp.where(writable, page, 0)
     offset = p_abs % ps
     new_cache = _write_cache(cache, {
-        n: cache[n].at[page_idx, offset].set(
+        n: cache[n].at[page_idx, :, offset].set(
             parts[n].astype(cache[n].dtype)) for n in parts})
 
     # read every slot's whole logical key range [0, pages*ps) through the

@@ -545,8 +545,8 @@ def decode_step_paged(cfg: ModelConfig, params, tokens, kv: dict,
     """One-token decode for the WHOLE slot pool against a paged KV pool
     (``repro.serve.pool``), with a per-slot position vector.
 
-    tokens [b, 1]; ``kv`` = {"k"/"v": [L, n_pages, ps, kvh, dh]} (int8 pages
-    add "k_scale"/"v_scale" [L, n_pages, ps, kvh, 1]); ``page_table``
+    tokens [b, 1]; ``kv`` = {"k"/"v": [L, n_pages, kvh, ps, dh]} (int8 pages
+    add "k_scale"/"v_scale" [L, n_pages, kvh, ps, 1]); ``page_table``
     [b, page_budget] int32; ``pos`` [b] int32.  Returns
     (logits [b, 1, V], updated kv dict).  Unlike :func:`decode_step` the
     position is per slot, so misaligned sequences decode in ONE traced step
@@ -684,7 +684,7 @@ def prefill_chunk_paged(cfg: ModelConfig, params, tokens, kv: dict,
 
     tokens [b, C] (C = the scheduler's bucketed chunk shape; ids past a
     slot's valid tokens are padding, and slots not advancing this step are
-    all-padding rows); ``kv`` = {"k"/"v": [L, n_pages, ps, kvh, dh]} (int8
+    all-padding rows); ``kv`` = {"k"/"v": [L, n_pages, kvh, ps, dh]} (int8
     pages add "k_scale"/"v_scale"); ``page_table`` [b, pages] int32 is the
     prefilling slots' table rows sliced to the bucketed page budget;
     ``start`` / ``write_lo`` / ``write_hi`` are traced int32 [b] vectors

@@ -139,7 +139,7 @@ class QualityObserver:
             return
         self.pool_samples += 1
         for side in ("k", "v"):
-            # [L, pages, ps, kvh, dh(/2)] int8 -> live pages only
+            # [L, pages, kvh, ps, dh(/2)] int8 -> live pages only
             q = np.asarray(pool.kv[side])[:, live]
             if pool.mode == "int4":
                 import jax.numpy as jnp
@@ -159,11 +159,11 @@ class QualityObserver:
             redist = pool.kv.get(f"{side}_redist")
             if redist is not None:
                 r = np.asarray(redist, np.float32)      # [L, kvh, dh]
-                deq = deq * r[:, None, None]
+                deq = deq * r[:, None, :, None]
                 mask = (r > 1.0).any(axis=0)            # [kvh, dh]
             else:
                 mask = None
-            ch_amax = deq.max(axis=(0, 1, 2))           # [kvh, dh(/…)]
+            ch_amax = deq.max(axis=(0, 1, 3))           # [kvh, dh(/…)]
             st.amax = max(st.amax, float(ch_amax.max()))
             hot = _hot_mask(ch_amax.reshape(-1), self.ratio).reshape(
                 ch_amax.shape)

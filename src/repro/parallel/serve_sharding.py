@@ -1,7 +1,7 @@
 """Sharding specs + scoped shard context for tensor-parallel paged serving.
 
 The serve stack shards by KV-head over a 1-D ``("model",)`` mesh: K/V
-pages ``[L, n_pages, ps, kvh, dh]`` (and their int8/int4 scales) split on
+pages ``[L, n_pages, kvh, ps, dh]`` (and their int8/int4 scales) split on
 the kvh axis, the int4 outlier-redistribution rows ``[L, kvh, dh]`` split
 on the same axis, while page tables, positions and tokens stay replicated
 and the scheduler stays host-side and mesh-oblivious.  This is the
@@ -102,15 +102,15 @@ def mesh_size(mesh: Mesh) -> int:
 def pool_specs(mesh: Mesh, kv: Dict[str, jnp.ndarray]) -> Dict[str, P]:
     """PartitionSpec per pool array, sharding the KV-head axis on "model".
 
-    Page arrays ``[L, n_pages, ps, kvh, dh]`` (K/V and their scales) carry
-    kvh on axis 3; pool-state rows ``[L, kvh, dh]`` (int4 redistribution)
+    Page arrays ``[L, n_pages, kvh, ps, dh]`` (K/V and their scales) carry
+    kvh on axis 2; pool-state rows ``[L, kvh, dh]`` (int4 redistribution)
     carry it on axis 1.  Everything goes through ``fit_spec``, so a kvh the
     mesh doesn't divide drops the axis — the whole-pool replicated
     fallback the engine detects via :func:`heads_sharded`."""
     specs: Dict[str, P] = {}
     for name, arr in kv.items():
-        if arr.ndim == 5:       # pages / scales: [L, np, ps, kvh, dh|1]
-            wanted = [None, None, None, SERVE_AXIS, None]
+        if arr.ndim == 5:       # pages / scales: [L, np, kvh, ps, dh|1]
+            wanted = [None, None, SERVE_AXIS, None, None]
         elif arr.ndim == 3:     # per-head pool state: [L, kvh, dh]
             wanted = [None, SERVE_AXIS, None]
         else:                   # anything else: replicated

@@ -26,7 +26,6 @@ from typing import Callable, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.context import QuantCtx, as_ctx
@@ -149,6 +148,10 @@ class ServeEngine:
         kv_calib = getattr(quant, "kv_calib", None) or None
         self.ctx, qparams = as_ctx(quant)
         self.qparams = qparams
+        # the steps take (params, qparams) as their first ARGUMENT: closed
+        # over, the packed int8 buffers would be baked into every compiled
+        # bucket's executable as constants
+        self.weights = (params, qparams)
         self.greedy = greedy
         self.cache_dtype = cache_dtype
         # fail at construction, not deep inside a traced layer loop: a policy
@@ -229,8 +232,8 @@ class ServeEngine:
         def tp_wrap(body, n_rest):
             """shard_map the step body when head-sharded, else pass through.
 
-            Signature contract: ``body(params, tokens, kv, *rest)`` with
-            the pool tree at position 2.  params/tokens/page tables/
+            Signature contract: ``body(weights, tokens, kv, *rest)`` with
+            the pool tree at position 2.  weights/tokens/page tables/
             positions are replicated (``P()`` pytree prefixes); the pool
             tree carries the pool's allocation PartitionSpecs in AND out,
             so the shard_map'd step donates and returns pages exactly as
@@ -244,12 +247,13 @@ class ServeEngine:
             if shard is None:
                 return body
             kv_specs = self.pool.kv_pspecs
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=(P(), P(), kv_specs) + (P(),) * n_rest,
-                out_specs=(P(), kv_specs), check_rep=False)
+                out_specs=(P(), kv_specs), check_vma=False)
 
-        def decode_body(params, tokens, kv, page_table, pos):
+        def decode_body(weights, tokens, kv, page_table, pos):
+            params, qparams = weights
             with SS.head_sharding(shard):
                 logits, new_kv = T.decode_step_paged(cfg, params, tokens, kv,
                                                      page_table, pos, self.ctx,
@@ -259,9 +263,9 @@ class ServeEngine:
 
         decode_step = tp_wrap(decode_body, 2)
 
-        def decode(params, tokens, kv, page_table, pos):
+        def decode(weights, tokens, kv, page_table, pos):
             self.decode_traces += 1      # python side effect: trace time only
-            return decode_step(params, tokens, kv, page_table, pos)
+            return decode_step(weights, tokens, kv, page_table, pos)
 
         # one compiled executable per page-budget bucket (the table's width):
         # the scheduler buckets ceil(pos/ps) to powers of two, so the step
@@ -270,8 +274,9 @@ class ServeEngine:
         # invariant (traces == buckets seen) holds at every mesh size
         self._decode = jax.jit(decode, donate_argnums=(2,))
 
-        def prefill_body(params, tokens, kv, page_table, start, write_lo,
+        def prefill_body(weights, tokens, kv, page_table, start, write_lo,
                          write_hi):
+            params, qparams = weights
             with SS.head_sharding(shard):
                 logits, new_kv = T.prefill_chunk_paged(
                     cfg, params, tokens, kv, page_table, start, write_lo,
@@ -281,9 +286,10 @@ class ServeEngine:
 
         prefill_step = tp_wrap(prefill_body, 4)
 
-        def prefill(params, tokens, kv, page_table, start, write_lo, write_hi):
+        def prefill(weights, tokens, kv, page_table, start, write_lo,
+                    write_hi):
             self.prefill_traces += 1     # python side effect: trace time only
-            return prefill_step(params, tokens, kv, page_table, start,
+            return prefill_step(weights, tokens, kv, page_table, start,
                                 write_lo, write_hi)
 
         # chunk shapes are bucketed like decode page budgets: the chunked
@@ -291,7 +297,8 @@ class ServeEngine:
         # start/write_lo/write_hi ride as traced scalars, never shapes
         self._prefill_step = jax.jit(prefill, donate_argnums=(2,))
 
-        def verify_body(params, tokens, kv, page_table, pos, n_valid):
+        def verify_body(weights, tokens, kv, page_table, pos, n_valid):
+            params, qparams = weights
             with SS.head_sharding(shard):
                 logits, new_kv = T.decode_verify_paged(
                     cfg, params, tokens, kv, page_table, pos, n_valid,
@@ -301,9 +308,9 @@ class ServeEngine:
 
         verify_step = tp_wrap(verify_body, 3)
 
-        def verify(params, tokens, kv, page_table, pos, n_valid):
+        def verify(weights, tokens, kv, page_table, pos, n_valid):
             self.verify_traces += 1      # python side effect: trace time only
-            return verify_step(params, tokens, kv, page_table, pos, n_valid)
+            return verify_step(weights, tokens, kv, page_table, pos, n_valid)
 
         # the speculative k-token verify: k buckets to pow2 in the
         # scheduler and n_valid rides as a traced vector, so verify
@@ -321,7 +328,7 @@ class ServeEngine:
         bucket = (int(tokens.shape[1]), int(page_table.shape[1]))
         self.prefill_buckets.add(bucket)
         before = self.prefill_traces
-        out = self._prefill_step(self.params, tokens, kv, page_table,
+        out = self._prefill_step(self.weights, tokens, kv, page_table,
                                  start, write_lo, write_hi)
         if self.prefill_traces > before and self.recorder.enabled:
             self.recorder.compile_event("prefill", chunk_bucket=bucket[0],
@@ -333,7 +340,7 @@ class ServeEngine:
         bucket = int(page_table.shape[1])
         self.decode_buckets.add(bucket)
         before = self.decode_traces
-        out = self._decode(self.params, tokens, kv, page_table, pos)
+        out = self._decode(self.weights, tokens, kv, page_table, pos)
         if self.decode_traces > before and self.recorder.enabled:
             self.recorder.compile_event("decode", page_bucket=bucket,
                                         traces=self.decode_traces)
@@ -343,7 +350,7 @@ class ServeEngine:
         bucket = (int(tokens.shape[1]), int(page_table.shape[1]))
         self.verify_buckets.add(bucket)
         before = self.verify_traces
-        out = self._verify_step(self.params, tokens, kv, page_table, pos,
+        out = self._verify_step(self.weights, tokens, kv, page_table, pos,
                                 n_valid)
         if self.verify_traces > before and self.recorder.enabled:
             self.recorder.compile_event("verify", k_bucket=bucket[0],

@@ -122,7 +122,9 @@ class KVQuantizer:
 
     def page_arrays(self, L: int, n_pages: int, ps: int, kvh: int, dh: int
                     ) -> Dict[str, jnp.ndarray]:
-        """Zero-initialized pool arrays, all laid out [L, n_pages, ps, ...]."""
+        """Zero-initialized pool arrays, all laid out head-major
+        [L, n_pages, kvh, ps, ...] (a (page, head) block is a (ps, dh) tile
+        the paged-attention kernel loads whole)."""
         raise NotImplementedError
 
     def pool_state(self, L: int, kvh: int, dh: int) -> Dict[str, jnp.ndarray]:
@@ -153,7 +155,7 @@ class FpKVQuantizer(KVQuantizer):
         return parts["k"].astype(dtype), parts["v"].astype(dtype)
 
     def page_arrays(self, L, n_pages, ps, kvh, dh):
-        shape = (L, n_pages, ps, kvh, dh)
+        shape = (L, n_pages, kvh, ps, dh)
         return {"k": jnp.zeros(shape, self.dtype),
                 "v": jnp.zeros(shape, self.dtype)}
 
@@ -178,7 +180,7 @@ class Int8KVQuantizer(KVQuantizer):
         return k, v
 
     def page_arrays(self, L, n_pages, ps, kvh, dh):
-        shape = (L, n_pages, ps, kvh, dh)
+        shape = (L, n_pages, kvh, ps, dh)
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
                 "k_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32),
@@ -234,7 +236,7 @@ class Int4KVQuantizer(KVQuantizer):
 
     def page_arrays(self, L, n_pages, ps, kvh, dh):
         assert dh % 2 == 0, f"int4 pages need an even head_dim, got {dh}"
-        shape = (L, n_pages, ps, kvh, dh // 2)
+        shape = (L, n_pages, kvh, ps, dh // 2)
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
                 "k_scale": jnp.zeros(shape[:-1] + (1,), self.scale_dtype),

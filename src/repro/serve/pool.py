@@ -21,10 +21,12 @@ quantize/dequantize seam shared with the attention write/read paths):
   * ``fp``   — pages in ``dtype`` (default bf16), the parity-testing mode
     (bit-exact against the dense cache path).
 
-Layout (``L`` = attention layers, leading so the pool rides ``lax.scan``):
+Layout (``L`` = attention layers, leading so the pool rides ``lax.scan``;
+head-major pages, so one (page, head) block is a ``(page_size, dh)`` tile
+the paged-attention kernel loads whole):
 
-  k/v        [L, n_pages, page_size, kvh, dh]     (int4: [..., dh//2] int8)
-  k/v_scale  [L, n_pages, page_size, kvh, 1]      (int8: f32; int4: bf16)
+  k/v        [L, n_pages, kvh, page_size, dh]     (int4: [..., dh//2] int8)
+  k/v_scale  [L, n_pages, kvh, page_size, 1]      (int8: f32; int4: bf16)
   k/v_redist [L, kvh, dh] f32                     (int4 only; NOT pages —
                                                    per-head channel
                                                    redistribution rows)
@@ -416,6 +418,7 @@ class PagePool:
             if pad:
                 a = jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
             a = a.reshape(a.shape[0], n, self.page_size, *a.shape[2:])
+            a = jnp.swapaxes(a, 2, 3)           # head-major [L, n, kvh, ps, *]
             self.kv[name] = self._constrain(
                 name, self.kv[name].at[:, jnp.asarray(pids)].set(a))
 

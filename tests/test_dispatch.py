@@ -51,6 +51,21 @@ def small_model():
 # Backend selection
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("n,bn", [(200, 200), (256, 256), (384, 128),
+                                  (768, 256), (2304, 256), (3072, 256)])
+def test_pick_bn_is_whole_axis_or_lane_multiple(n, bn):
+    from repro.kernels.muxq_gemm import pick_bn
+    assert pick_bn(n) == bn
+
+
+def test_pack_rejects_width_without_lane_tile():
+    """An out width over one tile with no 128-multiple divisor fails when
+    the weight is packed, not later inside the TPU compiler."""
+    w = jnp.zeros((64, 300), jnp.float32)
+    with pytest.raises(ValueError, match="no column tile"):
+        dispatch.pack_site_buffer(w, None, FUSED)
+
+
 def test_site_backend_resolution():
     assert dispatch.site_backend(QuantConfig(method="fp")) == "fp"
     assert dispatch.site_backend(BASE) == "fake"

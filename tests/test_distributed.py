@@ -70,12 +70,13 @@ def test_compressed_psum_and_error_feedback():
     import jax, jax.numpy as jnp, numpy as np
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
+    from repro.launch.mesh import make_host_mesh
     from repro.optim.compress import ef_compressed_psum
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_host_mesh(data=8)
 
     @partial(shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
-             out_specs=(P("data"), P("data")), check_rep=False)
+             out_specs=(P("data"), P("data")), check_vma=False)
     def run(g, err):
         tot, new_err = ef_compressed_psum(g[0], err[0], "data")
         return tot[None], new_err[None]
@@ -106,12 +107,12 @@ def test_hierarchical_psum_matches_flat():
     import jax, jax.numpy as jnp, numpy as np
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.parallel.collectives import hierarchical_psum
     mesh = jax.make_mesh((2, 4), ("pod", "data"))
 
     @partial(shard_map, mesh=mesh, in_specs=P(("pod", "data")),
-             out_specs=P(("pod", "data")), check_rep=False)
+             out_specs=P(("pod", "data")), check_vma=False)
     def run(x):
         return hierarchical_psum(x, "data", "pod")
 
@@ -129,7 +130,7 @@ def test_ring_allgather_matmul_exact():
     import jax, jax.numpy as jnp, numpy as np
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.parallel.collectives import allgather_matmul, ring_allreduce_reference
     mesh = jax.make_mesh((4,), ("tp",))
     m, k, n = 16, 32, 24
@@ -137,7 +138,7 @@ def test_ring_allgather_matmul_exact():
     w = jax.random.normal(jax.random.PRNGKey(1), (k, n))
 
     @partial(shard_map, mesh=mesh, in_specs=(P("tp"), P(None, "tp")),
-             out_specs=P(None, "tp"), check_rep=False)
+             out_specs=P(None, "tp"), check_vma=False)
     def run(xs, ws):
         return allgather_matmul(xs, ws, "tp")
 
@@ -145,7 +146,7 @@ def test_ring_allgather_matmul_exact():
     np.testing.assert_allclose(np.asarray(out), np.asarray(x @ w), atol=1e-4)
 
     @partial(shard_map, mesh=mesh, in_specs=P("tp"), out_specs=P("tp"),
-             check_rep=False)
+             check_vma=False)
     def rr(xs):
         return ring_allreduce_reference(xs, "tp")
     v = jax.random.normal(jax.random.PRNGKey(2), (4, 7))
@@ -191,9 +192,10 @@ def test_small_mesh_train_step_runs():
     from repro.launch.steps import make_train_step
     from repro.models import transformer as T
     from repro.optim import adamw
+    from repro.launch.mesh import make_host_mesh
     from repro.parallel.sharding import param_specs, batch_specs
     cfg = get_config("qwen2-0.5b", reduced=True)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh(data=2, model=4)
     params = T.init_params(cfg, jax.random.PRNGKey(0))
     pspecs = param_specs(cfg, params, mesh, fsdp=True)
     params = jax.device_put(params, pspecs)
@@ -215,7 +217,7 @@ def test_pipeline_parallel_exact():
     import jax, jax.numpy as jnp, numpy as np
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.parallel.pipeline import pipeline_apply, split_stages, microbatch
 
     n_stages, L, n_micro, mb, d = 4, 8, 4, 2, 16
@@ -238,7 +240,7 @@ def test_pipeline_parallel_exact():
 
     @partial(shard_map, mesh=mesh,
              in_specs=(P("stage"), P(None)), out_specs=P(None),
-             check_rep=False)
+             check_vma=False)
     def run(stage_ws, xm):
         out = pipeline_apply(block_fn, jax.tree.map(lambda w: w[0], stage_ws), xm, "stage")
         return out

@@ -84,8 +84,6 @@ def test_cell_lowers_and_compiles_small_mesh(arch, mode):
         lowered = jf.lower(abs_p, abs_b)
 
     compiled = lowered.compile()
-    # cost_analysis() returns a bare dict on older JAX and a one-element
-    # list of dicts on newer releases; _cost_dict normalizes both
     from repro.launch.dryrun import _cost_dict
     cost = _cost_dict(compiled)
     coll = H.collective_bytes(compiled.as_text())

@@ -72,9 +72,22 @@ def stubbed(monkeypatch):
 
 
 def _engine_kw(argv, stubbed):
-    assert L.main(argv) == 0
+    # --reduced: the CPU rehearsal size (the stubs skip compute, but the
+    # launcher still draws the arch's random weights)
+    assert L.main(["--reduced", *argv]) == 0
     assert len(_StubEngine.calls) == 1
     return _StubEngine.calls[0]
+
+
+@pytest.mark.parametrize("reduced", [False, True])
+def test_arch_width_follows_reduced_flag(stubbed, monkeypatch, reduced):
+    """The published widths by default; --reduced keeps the CPU rehearsal
+    config (weights are stubbed out: no full-width tensor is drawn)."""
+    from repro.configs import get_config
+    monkeypatch.setattr(L.T, "init_params", lambda cfg, key: {})
+    assert L.main(["--quant", "fp"] + (["--reduced"] if reduced else [])) == 0
+    assert _StubEngine.calls[0].cfg == get_config("gpt2-small",
+                                                  reduced=reduced)
 
 
 def test_defaults_reach_engine(stubbed):
@@ -168,14 +181,14 @@ def test_pack_target_flag_reaches_quantizer(stubbed):
 
 def test_fused_tree_pack_target_rejected(stubbed):
     with pytest.raises(SystemExit, match="pack-target"):
-        L.main(["--quant", "muxq", "--backend", "fused",
+        L.main(["--reduced", "--quant", "muxq", "--backend", "fused",
                 "--pack-target", "tree"])
     assert not _StubEngine.calls
 
 
 def test_llm_int8_fused_rejected(stubbed):
     with pytest.raises(SystemExit, match="llm_int8"):
-        L.main(["--quant", "llm_int8", "--backend", "fused"])
+        L.main(["--reduced", "--quant", "llm_int8", "--backend", "fused"])
     assert not _StubEngine.calls
 
 
@@ -239,3 +252,36 @@ def test_tp_zero_rejected(stubbed):
     with pytest.raises(SystemExit, match="--tp"):
         L.main(["--quant", "fp", "--tp", "0"])
     assert not _StubEngine.calls
+
+
+# -- persistent compile cache placement ---------------------------------------
+
+@pytest.mark.parametrize("backend,env,want", [
+    ("cpu", None, None),                     # tests / CPU rehearsals: off
+    ("tpu", None, "repo"),                   # fixed in-checkout directory
+    ("tpu", "/elsewhere/cache", "/elsewhere/cache"),  # the env var wins
+])
+def test_compile_cache_placement(monkeypatch, backend, env, want):
+    """The entry points' cache goes where JAX_COMPILATION_CACHE_DIR says
+    (setting nothing else), else to <repo>/.jax_cache (git-ignored), and
+    stays off on the CPU.  The config update is captured, so the test
+    never turns a cache on."""
+    import jax
+    from pathlib import Path
+    from repro.launch import compile_cache as CC
+    updates = []
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    repo = Path(__file__).resolve().parents[1]
+    expect = str(repo / ".jax_cache") if want == "repo" else want
+    assert CC.enable_compile_cache() == expect
+    if want == "repo":
+        assert updates == [("jax_compilation_cache_dir", expect)]
+        assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+    else:
+        assert updates == []

@@ -305,26 +305,26 @@ def _random_paged_case(seed, *, b=3, h=8, kvh=4, dh=16, ps=8, pages=4,
     q = jnp.asarray(rng.normal(size=(b, h, dh)).astype(np.float32))
     kw = {}
     if mode == "int8":
-        kp = jnp.asarray(rng.integers(-127, 128, (n_pages, ps, kvh, dh)),
+        kp = jnp.asarray(rng.integers(-127, 128, (n_pages, kvh, ps, dh)),
                          dtype=jnp.int8)
-        vp = jnp.asarray(rng.integers(-127, 128, (n_pages, ps, kvh, dh)),
+        vp = jnp.asarray(rng.integers(-127, 128, (n_pages, kvh, ps, dh)),
                          dtype=jnp.int8)
         kw["k_scale"] = jnp.asarray(rng.uniform(1e-3, 2e-2,
-                                                (n_pages, ps, kvh, 1))
+                                                (n_pages, kvh, ps, 1))
                                     .astype(np.float32))
         kw["v_scale"] = jnp.asarray(rng.uniform(1e-3, 2e-2,
-                                                (n_pages, ps, kvh, 1))
+                                                (n_pages, kvh, ps, 1))
                                     .astype(np.float32))
     elif mode == "int4":
-        ki = rng.integers(-7, 8, (n_pages, ps, kvh, dh)).astype(np.int8)
-        vi = rng.integers(-7, 8, (n_pages, ps, kvh, dh)).astype(np.int8)
+        ki = rng.integers(-7, 8, (n_pages, kvh, ps, dh)).astype(np.int8)
+        vi = rng.integers(-7, 8, (n_pages, kvh, ps, dh)).astype(np.int8)
         kp = kvq.pack_int4(jnp.asarray(ki))          # [..., dh//2] nibbles
         vp = kvq.pack_int4(jnp.asarray(vi))
         kw["k_scale"] = jnp.asarray(rng.uniform(1e-3, 2e-2,
-                                                (n_pages, ps, kvh, 1))
+                                                (n_pages, kvh, ps, 1))
                                     .astype(np.float32)).astype(jnp.bfloat16)
         kw["v_scale"] = jnp.asarray(rng.uniform(1e-3, 2e-2,
-                                                (n_pages, ps, kvh, 1))
+                                                (n_pages, kvh, ps, 1))
                                     .astype(np.float32)).astype(jnp.bfloat16)
         # per-head inverse redistribution rows: a few 2^e channels per head
         mask = rng.random((kvh, dh)) < 0.2
@@ -332,9 +332,9 @@ def _random_paged_case(seed, *, b=3, h=8, kvh=4, dh=16, ps=8, pages=4,
         kw["v_redist"] = jnp.asarray(kvq.redist_from_mask(~mask & (
             rng.random((kvh, dh)) < 0.2)))
     else:
-        kp = jnp.asarray(rng.normal(size=(n_pages, ps, kvh, dh))
+        kp = jnp.asarray(rng.normal(size=(n_pages, kvh, ps, dh))
                          .astype(np.float32))
-        vp = jnp.asarray(rng.normal(size=(n_pages, ps, kvh, dh))
+        vp = jnp.asarray(rng.normal(size=(n_pages, kvh, ps, dh))
                          .astype(np.float32))
     # distinct physical pages per slot, scrambled
     table = np.zeros((b, pages), np.int32)

@@ -34,28 +34,28 @@ def _block_case(seed, *, b, sq, h=8, kvh=4, dh=16, pages=4, mode="fp",
     q = jnp.asarray(rng.normal(size=(b, sq, h, dh)).astype(np.float32))
     kw = {}
     if mode == "int8":
-        kp = jnp.asarray(rng.integers(-127, 128, (n_pages, PS, kvh, dh)),
+        kp = jnp.asarray(rng.integers(-127, 128, (n_pages, kvh, PS, dh)),
                          dtype=jnp.int8)
-        vp = jnp.asarray(rng.integers(-127, 128, (n_pages, PS, kvh, dh)),
+        vp = jnp.asarray(rng.integers(-127, 128, (n_pages, kvh, PS, dh)),
                          dtype=jnp.int8)
         for s in ("k_scale", "v_scale"):
-            kw[s] = jnp.asarray(rng.uniform(1e-3, 2e-2, (n_pages, PS, kvh, 1))
+            kw[s] = jnp.asarray(rng.uniform(1e-3, 2e-2, (n_pages, kvh, PS, 1))
                                 .astype(np.float32))
     elif mode == "int4":
-        ki = rng.integers(-7, 8, (n_pages, PS, kvh, dh)).astype(np.int8)
-        vi = rng.integers(-7, 8, (n_pages, PS, kvh, dh)).astype(np.int8)
+        ki = rng.integers(-7, 8, (n_pages, kvh, PS, dh)).astype(np.int8)
+        vi = rng.integers(-7, 8, (n_pages, kvh, PS, dh)).astype(np.int8)
         kp, vp = kvq.pack_int4(jnp.asarray(ki)), kvq.pack_int4(jnp.asarray(vi))
         for s in ("k_scale", "v_scale"):
-            kw[s] = jnp.asarray(rng.uniform(1e-3, 2e-2, (n_pages, PS, kvh, 1))
+            kw[s] = jnp.asarray(rng.uniform(1e-3, 2e-2, (n_pages, kvh, PS, 1))
                                 .astype(np.float32)).astype(jnp.bfloat16)
         mask = rng.random((kvh, dh)) < 0.2
         kw["k_redist"] = jnp.asarray(kvq.redist_from_mask(mask))
         kw["v_redist"] = jnp.asarray(kvq.redist_from_mask(
             ~mask & (rng.random((kvh, dh)) < 0.2)))
     else:
-        kp = jnp.asarray(rng.normal(size=(n_pages, PS, kvh, dh))
+        kp = jnp.asarray(rng.normal(size=(n_pages, kvh, PS, dh))
                          .astype(np.float32))
-        vp = jnp.asarray(rng.normal(size=(n_pages, PS, kvh, dh))
+        vp = jnp.asarray(rng.normal(size=(n_pages, kvh, PS, dh))
                          .astype(np.float32))
     table = np.zeros((b, pages), np.int32)
     perm = rng.permutation(np.arange(1, n_pages))

@@ -142,11 +142,16 @@ def test_fp_pages_bit_exact_vs_dense_decode(small_model):
     ids = tok.encode("abcdefghijk")
     s = len(ids)
 
-    # dense reference: prefill then one decode step
-    cache = init_cache(cfg, 1, 64, dtype=jnp.float32)
-    out = T.forward(cfg, params, jnp.asarray(ids)[None], cache=cache)
+    # dense reference: prefill then one decode step, at the pool's 2-row
+    # width (the row count picks XLA's matmul kernel, and a 1-row program
+    # rounds differently in the last ulp); row 0 is the one compared
+    cache = init_cache(cfg, 2, 64, dtype=jnp.float32)
+    out = T.forward(cfg, params, jnp.tile(jnp.asarray(ids)[None], (2, 1)),
+                    cache=cache)
     nxt = int(jnp.argmax(out["logits"][0, -1, : cfg.vocab_size]))
-    lg_ref, _ = T.decode_step(cfg, params, jnp.asarray([[nxt]]), out["cache"])
+    lg_ref, _ = T.decode_step(cfg, params, jnp.full((2, 1), nxt),
+                              out["cache"])
+    lg_ref = lg_ref[:1]
 
     def paged_logits(kv_mode):
         eng = ServeEngine(cfg, params, max_batch=2, s_max=64, page_size=16,
@@ -328,6 +333,16 @@ def test_arrivals_gate_admission(small_model):
 # ---------------------------------------------------------------------------
 # serve_bench smoke (CI fast-gate hook)
 # ---------------------------------------------------------------------------
+
+def test_serve_bench_run_mesh_refuses_accelerator(monkeypatch):
+    """run_mesh's child runs on forced CPU host devices: from a process on
+    an accelerator it refuses and points at the chip smoke instead of
+    recording a CPU number under a serve-bench name."""
+    from benchmarks.serve_bench import run_mesh
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(SystemExit, match="chip_smoke.py --four-chips"):
+        run_mesh(tp=2)
+
 
 def test_serve_bench_smoke_case():
     from benchmarks.serve_bench import run_case

@@ -251,8 +251,8 @@ def test_kernel_head_slice_parity():
     b, h, kvh, dh, ps, npages = 2, 8, 4, 16, 8, 6
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((b, h, dh)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((npages, ps, kvh, dh)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((npages, ps, kvh, dh)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((npages, kvh, ps, dh)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((npages, kvh, ps, dh)), jnp.float32)
     table = jnp.asarray([[0, 2, 4], [1, 3, 5]], jnp.int32)
     pos = jnp.asarray([13, 9], jnp.int32)
 
@@ -262,7 +262,7 @@ def test_kernel_head_slice_parity():
         kl, hl = kvh // shards, (kvh // shards) * g
         parts = [paged_attention_ref(
             q[:, i * hl:(i + 1) * hl],
-            k[:, :, i * kl:(i + 1) * kl], v[:, :, i * kl:(i + 1) * kl],
+            k[:, i * kl:(i + 1) * kl], v[:, i * kl:(i + 1) * kl],
             table, pos) for i in range(shards)]
         np.testing.assert_array_equal(np.concatenate(parts, axis=1),
                                       np.asarray(full))
