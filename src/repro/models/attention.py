@@ -44,6 +44,12 @@ def set_kv_observer(fn) -> None:
 _ROUTING_KEYS = ("pos", "page_table", "start", "write_lo", "write_hi",
                  "n_valid")
 
+# name scope of the paged steps' page writes.  XLA names the relayout of a
+# layer's written pages into the paged kernel's operand layout after the
+# write, so a profile finds both under this scope (the scan's own per-layer
+# slice and stack of the pool carry the scan's name).
+POOL_SCOPE = "pool"
+
 
 def _write_cache(cache: dict, updates: dict) -> dict:
     """New cache dict: every non-routing array passes through, quantized
@@ -261,9 +267,10 @@ def attention_decode_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     # there are harmless.
     page_idx = jnp.take_along_axis(page_table, (pos // ps)[:, None], 1)[:, 0]
     offset = pos % ps
-    new_cache = _write_cache(cache, {
-        n: cache[n].at[page_idx, :, offset].set(
-            parts[n][:, 0].astype(cache[n].dtype)) for n in parts})
+    with jax.named_scope(POOL_SCOPE):
+        new_cache = _write_cache(cache, {
+            n: cache[n].at[page_idx, :, offset].set(
+                parts[n][:, 0].astype(cache[n].dtype)) for n in parts})
 
     # read path: the jnp gather reference on CPU, the Pallas kernel
     # (page-table-indexed loads, in-kernel int8 dequant / int4 nibble
@@ -340,9 +347,10 @@ def attention_verify_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     valid = jnp.arange(kb, dtype=jnp.int32)[None] < n_valid[:, None]
     page_idx = jnp.where(valid, page, 0)
     offset = positions % ps
-    new_cache = _write_cache(cache, {
-        n: cache[n].at[page_idx, :, offset].set(
-            parts[n].astype(cache[n].dtype)) for n in parts})
+    with jax.named_scope(POOL_SCOPE):
+        new_cache = _write_cache(cache, {
+            n: cache[n].at[page_idx, :, offset].set(
+                parts[n].astype(cache[n].dtype)) for n in parts})
 
     win = jnp.where(jnp.asarray(window_flag), cfg.window_size,
                     PA.NO_WINDOW).astype(jnp.int32)
@@ -444,9 +452,10 @@ def attention_prefill_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     page = jnp.take_along_axis(page_table, logical, axis=1)         # [b, C]
     page_idx = jnp.where(writable, page, 0)
     offset = p_abs % ps
-    new_cache = _write_cache(cache, {
-        n: cache[n].at[page_idx, :, offset].set(
-            parts[n].astype(cache[n].dtype)) for n in parts})
+    with jax.named_scope(POOL_SCOPE):
+        new_cache = _write_cache(cache, {
+            n: cache[n].at[page_idx, :, offset].set(
+                parts[n].astype(cache[n].dtype)) for n in parts})
 
     # read every slot's whole logical key range [0, pages*ps) through the
     # page table with the per-slot start-offset causal mask — the same

@@ -18,6 +18,14 @@ per active step (slots decoded, prefill slot + chunk bucket, page-budget
 bucket, spec verify k, COW copies) and a ``COMPILE`` instant every time a
 ``decode_traces`` / ``prefill_traces`` / ``verify_traces`` counter grows.
 
+Host spans (:meth:`TraceRecorder.span`, phase ``HOST``) name the
+scheduler's own work between step programs (``sched.iteration``,
+``sched.admit``, ``sched.decode.dispatch``, ...; docs/OBSERVABILITY.md
+lists them).  Each opens a ``jax.profiler.TraceAnnotation``, so while a
+profiler session runs the span lands in the profile's host plane, beside
+the device's programs (the two clocks agree to within a few ms); it also
+pushes B/E events into the ring, which keeps the recorder's own clock.
+
 Two consumers:
 
   * :meth:`TraceRecorder.export_chrome` — Chrome-trace / Perfetto JSON
@@ -35,19 +43,25 @@ them), so a long-lived engine can leave tracing on without growing.
 from __future__ import annotations
 
 import collections
+import contextlib
 import json
 import time
 from pathlib import Path
 from typing import Dict, List, Optional
 
+from jax import profiler
+
 SCHED_RID = -1                       # the scheduler's pseudo-request id
 
 # phase -> chrome tid (stable small ints so exported traces line up per pid)
-PHASES = ("QUEUED", "PREFILLING", "DECODING", "VERIFY", "SCHED")
+PHASES = ("QUEUED", "PREFILLING", "DECODING", "VERIFY", "SCHED", "HOST")
 TIDS = {p: i + 1 for i, p in enumerate(PHASES)}
 
 # span phases a request moves through; instants ride their current phase
 SPAN_PHASES = ("QUEUED", "PREFILLING", "DECODING")
+
+# what NullRecorder.span returns, every call: enters and exits as a no-op
+NULL_SPAN = contextlib.nullcontext()
 
 
 class NullRecorder:
@@ -73,6 +87,9 @@ class NullRecorder:
 
     def compile_event(self, kind, **args):
         pass
+
+    def span(self, name, step=None, **args):
+        return NULL_SPAN
 
     def set_metadata(self, **kw):
         pass
@@ -135,6 +152,23 @@ class TraceRecorder:
         self._push("I", SCHED_RID, "SCHED", "COMPILE", None,
                    dict(args, kind=kind))
 
+    @contextlib.contextmanager
+    def span(self, name, step=None, **args):
+        """A named piece of host work: B/E events on the scheduler's HOST
+        track, and around them a ``jax.profiler.TraceAnnotation`` carrying
+        ``args`` (a ``StepTraceAnnotation`` with ``step_num=step`` when
+        ``step`` is given), which a running profiler session records on
+        its own clock."""
+        annotation = (profiler.TraceAnnotation(name, **args) if step is None
+                      else profiler.StepTraceAnnotation(
+                          name, step_num=int(step), **args))
+        self._push("B", SCHED_RID, "HOST", name, step, args)
+        try:
+            with annotation:
+                yield
+        finally:
+            self._push("E", SCHED_RID, "HOST", name, step, {})
+
     def set_metadata(self, **kw) -> None:
         """Run-level metadata (e.g. the serving mesh shape) stamped into
         the exported Chrome trace: ``otherData`` keys plus a
@@ -155,6 +189,8 @@ class TraceRecorder:
         open_: Dict[tuple, dict] = {}
         out: Dict[int, List[dict]] = {}
         for ev in self._events:
+            if ev["phase"] == "HOST":
+                continue                # scheduler host spans nest
             key = (ev["rid"], ev["phase"])
             if ev["kind"] == "B":
                 open_[key] = {"phase": ev["phase"], "t0": ev["step"],

@@ -12,11 +12,10 @@ Since PR 8 the scalar counters live in a
 attribute reads/writes on the counter/gauge names route to the registry
 (every ``m.decode_steps += 1`` call site is unchanged), latency
 distributions accumulate in fixed-bucket histograms (``hist/ttft_steps``,
-``hist/queue_wait_steps``, ``hist/e2e_steps``, ``hist/accepted_draft_len``,
-``hist/request_decode_steps``), and ``registry.snapshot()`` dumps the whole
-metric surface for ``--json-out`` / the bench artifacts.  :meth:`report`
-keeps every pre-existing key (the serve_bench JSON schema and CI gates are
-pinned on them); the p50/p95 keys are additive.
+``hist/queue_wait_steps``, ``hist/e2e_steps``), and ``registry.snapshot()``
+dumps the whole metric surface for ``--json-out`` / the bench artifacts.
+:meth:`report` keeps every pre-existing key (the serve_bench JSON schema
+and CI gates are pinned on them); the p50/p95 keys are additive.
 
 The KV read counters price the block-sparse decode: ``kv_bytes_read`` is
 what the bucketed page-budget gather actually read; ``kv_bytes_read_dense``
@@ -29,7 +28,7 @@ from __future__ import annotations
 import time
 from typing import Dict, List, Optional
 
-from repro.obs.registry import COUNT_BUCKETS, STEP_BUCKETS, MetricsRegistry
+from repro.obs.registry import STEP_BUCKETS, MetricsRegistry
 
 # scalar int counters the facade routes to registry Counters (attribute
 # name == registry name; report() reads them back by the same names)
@@ -86,8 +85,6 @@ _HISTOGRAMS = (
     ("hist/ttft_steps", STEP_BUCKETS),
     ("hist/queue_wait_steps", STEP_BUCKETS),
     ("hist/e2e_steps", STEP_BUCKETS),
-    ("hist/accepted_draft_len", COUNT_BUCKETS),
-    ("hist/request_decode_steps", COUNT_BUCKETS),
 )
 
 

@@ -106,7 +106,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data import tokenizer as tok
-from repro.obs.trace import NULL_RECORDER
+from repro.obs.trace import NULL_RECORDER, NULL_SPAN
 from repro.serve import spec
 from repro.serve.metrics import ServeMetrics
 from repro.serve.pool import PagePool, bucket_pow2
@@ -285,9 +285,20 @@ class Scheduler:
         return list(requests)
 
     def _run_loop(self, queue, step_clock: int) -> None:
-        m, rec = self.metrics, self.rec
         while queue or any(self.slots):
-            self._step = step_clock
+            with self.rec.span("sched.iteration", step=step_clock):
+                step_clock = self._iteration(queue, step_clock)
+
+    def _iteration(self, queue, step_clock: int) -> int:
+        """One pass of the loop: arrivals and admission, a prefill chunk
+        step, page backing, the pooled decode (or verify), then the step's
+        accounting.  Returns the next step clock; the clock stays put only
+        when nothing is live or queued, which ends the loop.  Each piece of
+        host work runs inside a recorder span (``sched.*``) so a profile
+        can name the device's idle time between step programs."""
+        m, rec = self.metrics, self.rec
+        self._step = step_clock
+        with rec.span("sched.admit"):
             # a request's TTFT clock starts when it ARRIVES (its arrival
             # step is reached), not when run() starts — otherwise the load
             # generator's arrival schedule would inflate the queueing delay
@@ -308,78 +319,68 @@ class Scheduler:
             self._admit(queue, step_clock)
             m.live_slots_peak = max(
                 m.live_slots_peak, sum(s is not None for s in self.slots))
-            if not any(self.slots):
-                if queue:           # everything pending is a future arrival
-                    step_clock += 1
-                    continue
-                break
+        if not any(self.slots):
+            # everything pending is a future arrival; with nothing queued
+            # either, the unchanged clock ends the loop
+            return step_clock + 1 if queue else step_clock
 
-            cow0 = self.pool.cow_count      # step-record COW delta baseline
-            # up to prefill_slots prefilling slots advance one chunk each,
-            # batched into ONE traced call — the per-step prompt-token
-            # budget that keeps decode flowing under a long-prompt flood
-            # without serializing prompt work.  Returns the step-record
-            # info (slots + buckets) or None; truthiness = "chunks ran".
-            did_prefill = self._prefill_chunk_step(step_clock)
-            # n-gram drafts first (host-side, no pool effects), so the
-            # page-backing pass can cover each slot's whole k-token write
-            drafts = (self._propose_drafts()
-                      if self.spec_mode != "off" else {})
-            # back every live decode slot's next write position(s) (may
-            # preempt on pool exhaustion)
+        cow0 = self.pool.cow_count      # step-record COW delta baseline
+        # up to prefill_slots prefilling slots advance one chunk each,
+        # batched into ONE traced call — the per-step prompt-token
+        # budget that keeps decode flowing under a long-prompt flood
+        # without serializing prompt work.  Returns the step-record
+        # info (slots + buckets) or None; truthiness = "chunks ran".
+        did_prefill = self._prefill_chunk_step(step_clock)
+        # n-gram drafts first (host-side, no pool effects), so the
+        # page-backing pass can cover each slot's whole k-token write
+        drafts = {}
+        if self.spec_mode != "off":
+            with rec.span("sched.verify.propose"):
+                drafts = self._propose_drafts()
+        # back every live decode slot's next write position(s) (may
+        # preempt on pool exhaustion)
+        with rec.span("sched.pages"):
             self._ensure_pages(
                 queue, {i: 1 + len(d) for i, d in drafts.items()})
-            active = [i for i, s in enumerate(self.slots)
-                      if s is not None and not s.prefilling]
-            # page-backing may have preempted (or finished) a drafted slot
-            drafts = {i: d for i, d in drafts.items() if i in set(active)}
-            decode_ran = False
-            verify_k = None
-            bucket = 0
-            if active:
-                # block-sparse read budget: the longest live decoding
-                # sequence's backed page count, bucketed so each bucket
-                # compiles exactly once
-                counts = self.pool.live_page_counts()
-                bucket = self.pool.bucket_pages(max(int(counts[i])
-                                                    for i in active))
-                prefilling = [i for i, s in enumerate(self.slots)
-                              if s is not None and s.prefilling]
-                if prefilling:
-                    # mid-prefill slots sit decode out: a zeroed table row
-                    # routes the pool-wide write to scratch page 0 and its
-                    # (discarded) reads to zeros — no shape change, no
-                    # per-slot control flow
-                    table = self.pool.page_table[:, :bucket].copy()
-                    table[prefilling] = 0
-                    table = jnp.asarray(table)
-                else:
-                    # steady state: reuse the pool's cached device table
-                    table = self.pool.table()[:, :bucket]
-
-                if drafts:
-                    # speculative path: ONE verify call scores every
-                    # slot's draft block; accepted tokens emit in order
-                    verify_k = self._verify_step(active, drafts, table,
-                                                 bucket, did_prefill,
-                                                 step_clock)
-                else:
-                    # ONE jit'd decode for the whole pool, per-slot
-                    # positions inside
-                    nxt, new_kv = self.decode(
-                        jnp.asarray(self.last_tok)[:, None],
-                        self.pool.state(), table, jnp.asarray(self.pos))
-                    self.pool.adopt(new_kv)
-                    outs = np.asarray(nxt)
-                    m.decode_steps += 1
-                    m.decode_slot_steps += len(active)
-                    m.record_read(self.pool, bucket)
-                    if did_prefill:
-                        m.interleaved_steps += 1
-                    for i in active:
-                        self.pos[i] += 1
-                        self._post_token(i, int(outs[i]))
-                decode_ran = True
+        active = [i for i, s in enumerate(self.slots)
+                  if s is not None and not s.prefilling]
+        # page-backing may have preempted (or finished) a drafted slot
+        drafts = {i: d for i, d in drafts.items() if i in set(active)}
+        decode_ran = False
+        verify_k = None
+        bucket = 0
+        if drafts:
+            # speculative path: ONE verify call scores every slot's
+            # draft block; accepted tokens emit in order
+            verify_k, bucket = self._verify_step(active, drafts,
+                                                 did_prefill, step_clock)
+            decode_ran = True
+        elif active:
+            # ONE jit'd decode for the whole pool, per-slot positions
+            # inside
+            with rec.span("sched.decode.prepare"):
+                bucket, table = self._decode_table(active)
+                toks = jnp.asarray(self.last_tok)[:, None]
+                pos = jnp.asarray(self.pos)
+            with (rec.span("sched.decode.dispatch", slots=len(active),
+                           page_bucket=bucket)
+                  if rec.enabled else NULL_SPAN):
+                nxt, new_kv = self.decode(toks, self.pool.state(), table,
+                                          pos)
+                self.pool.adopt(new_kv)
+            with rec.span("sched.decode.wait"):
+                outs = np.asarray(nxt)
+            with rec.span("sched.decode.emit"):
+                m.decode_steps += 1
+                m.decode_slot_steps += len(active)
+                m.record_read(self.pool, bucket)
+                if did_prefill:
+                    m.interleaved_steps += 1
+                for i in active:
+                    self.pos[i] += 1
+                    self._post_token(i, int(outs[i]))
+            decode_ran = True
+        with rec.span("sched.account"):
             if active and not decode_ran:
                 # falsifiable stall gate: trips if a future change makes
                 # the pooled decode conditional (e.g. prefill-exclusive
@@ -402,10 +403,10 @@ class Scheduler:
                     cow=self.pool.cow_count - cow0)
             if self.quality is not None:
                 self.quality.maybe_sample_pool(self.pool, step_clock)
-            step_clock += 1
             live = {i: (int(self.pos[i]) if not s.prefilling else s.pre_pos)
                     for i, s in enumerate(self.slots) if s}
             m.sample_pool(self.pool.stats(live))
+        return step_clock + 1
 
     # -- admission -----------------------------------------------------------
 
@@ -626,6 +627,24 @@ class Scheduler:
                  if s is not None and s.prefilling]
         if not cands:
             return None
+        rec = self.rec
+        with rec.span("sched.prefill.prepare"):
+            ns, cb, pb, toks, rest = self._prefill_args(cands, step_clock)
+        with (rec.span("sched.prefill.dispatch", slots=len(ns),
+                       chunk_bucket=cb, page_bucket=pb)
+              if rec.enabled else NULL_SPAN):
+            nxt, new_kv = self.prefill(toks, self.pool.state(), *rest)
+            self.pool.adopt(new_kv)
+        with rec.span("sched.prefill.wait"):
+            outs = np.asarray(nxt)          # [n_slots, cb]
+        with rec.span("sched.prefill.emit"):
+            self._prefill_emit(ns, cb, pb, outs, step_clock)
+        return {"slots": sorted(ns), "chunk_bucket": cb, "page_bucket": pb}
+
+    def _prefill_args(self, cands, step_clock: int):
+        """The chunk step's pick and its device inputs: (slot -> valid
+        tokens, chunk bucket, page bucket, tokens, (page table, start,
+        write_lo, write_hi))."""
         chosen = self._prefill_pick(cands, step_clock)
         m = self.metrics
         # anti-starvation face: the worst age any still-prefilling prompt
@@ -660,11 +679,15 @@ class Scheduler:
             # are mapped read-only) nor the chunk's padding tail
             w_lo[j] = max(done, st.write_from)
             w_hi[j] = min(done + n, len(st.ids))
-        nxt, new_kv = self.prefill(
-            jnp.asarray(toks), self.pool.state(), jnp.asarray(tab),
-            jnp.asarray(start), jnp.asarray(w_lo), jnp.asarray(w_hi))
-        self.pool.adopt(new_kv)
-        outs = np.asarray(nxt)          # [n_slots, cb]
+        return ns, cb, pb, jnp.asarray(toks), (
+            jnp.asarray(tab), jnp.asarray(start), jnp.asarray(w_lo),
+            jnp.asarray(w_hi))
+
+    def _prefill_emit(self, ns, cb: int, pb: int, outs,
+                      step_clock: int) -> None:
+        """Account a chunk step's tokens and activate every slot whose
+        prompt it finished (their first token streams here)."""
+        m = self.metrics
         m.prefill_steps += 1
         if len(ns) > 1:
             m.prefill_multi_steps += 1
@@ -683,7 +706,6 @@ class Scheduler:
                                  done=st.pre_pos, total=len(st.ids))
             if st.pre_pos >= len(st.ids):
                 self._activate(j, int(outs[j, n - 1]), step_clock)
-        return {"slots": sorted(ns), "chunk_bucket": cb, "page_bucket": pb}
 
     def _activate(self, slot: int, sampled: Optional[int],
                   step_clock: int) -> None:
@@ -759,8 +781,26 @@ class Scheduler:
                 drafts[i] = d
         return drafts
 
-    def _verify_step(self, active, drafts, table, bucket, did_prefill,
-                     step_clock: int) -> int:
+    def _decode_table(self, active):
+        """The pooled step's page bucket and page table.  The read budget
+        is the longest live decoding sequence's backed page count,
+        bucketed so each bucket compiles exactly once (block-sparse
+        reads)."""
+        counts = self.pool.live_page_counts()
+        bucket = self.pool.bucket_pages(max(int(counts[i]) for i in active))
+        prefilling = [i for i, s in enumerate(self.slots)
+                      if s is not None and s.prefilling]
+        if not prefilling:
+            # steady state: reuse the pool's cached device table
+            return bucket, self.pool.table()[:, :bucket]
+        # mid-prefill slots sit decode out: a zeroed table row routes the
+        # pool-wide write to scratch page 0 and its (discarded) reads to
+        # zeros — no shape change, no per-slot control flow
+        table = self.pool.page_table[:, :bucket].copy()
+        table[prefilling] = 0
+        return bucket, jnp.asarray(table)
+
+    def _verify_step(self, active, drafts, did_prefill, step_clock: int):
         """ONE batched verify over the pool: every active slot's committed
         token + draft rides a ``[slot, k]`` block (k bucketed to pow2 like
         page budgets, so verify compiles once per (k, page) bucket pair);
@@ -769,51 +809,56 @@ class Scheduler:
         rollback work: per-slot ``pos`` only advances over accepted
         tokens, and the rejected page rows are overwritten when the
         position reaches them (``_ensure_pages`` already COW'd every page
-        the k-token write touches)."""
-        m = self.metrics
-        kb = bucket_pow2(1 + max(len(d) for d in drafts.values()),
-                         self.spec_k)
-        n = self.pool.n_slots
-        toks = np.zeros((n, kb), np.int32)
-        n_valid = np.zeros(n, np.int32)
-        for i in active:
-            d = drafts.get(i, [])
-            toks[i, 0] = self.last_tok[i]
-            if d:
-                toks[i, 1:1 + len(d)] = d
-            n_valid[i] = 1 + len(d)
-        nxt, new_kv = self.verify(
-            jnp.asarray(toks), self.pool.state(), table,
-            jnp.asarray(self.pos), jnp.asarray(n_valid))
-        self.pool.adopt(new_kv)
-        outs = np.asarray(nxt)                  # [n_slots, kb]
-        m.decode_steps += 1
-        m.decode_slot_steps += len(active)
-        m.spec_verify_steps += 1
-        m.record_read(self.pool, bucket)
-        if did_prefill:
-            m.interleaved_steps += 1
-        for i in active:
-            d = drafts.get(i, [])
-            acc = spec.accept_length(d, outs[i])
-            m.spec_proposed += len(d)
-            m.spec_accepted += acc
-            m.decode_steps_saved += acc
-            if d:
-                m.observe("accepted_draft_len", acc)
-                if self.rec.enabled:
-                    self.rec.instant(self._rids[id(self.slots[i].req)],
-                                     "VERIFY", "VERIFY", step_clock,
-                                     slot=i, k_bucket=kb, proposed=len(d),
-                                     accepted=acc)
-            # emitted stream = accepted draft prefix + the model's own
-            # next token after it — exactly sequential greedy decode
-            for t in outs[i, :acc + 1]:
-                self.pos[i] += 1
-                self._post_token(i, int(t))
-                if self.slots[i] is None:
-                    break                       # EOS / budget mid-block
-        return kb
+        the k-token write touches).  Returns (k bucket, page bucket)."""
+        m, rec = self.metrics, self.rec
+        with rec.span("sched.verify.prepare"):
+            bucket, table = self._decode_table(active)
+            kb = bucket_pow2(1 + max(len(d) for d in drafts.values()),
+                             self.spec_k)
+            n = self.pool.n_slots
+            toks = np.zeros((n, kb), np.int32)
+            n_valid = np.zeros(n, np.int32)
+            for i in active:
+                d = drafts.get(i, [])
+                toks[i, 0] = self.last_tok[i]
+                if d:
+                    toks[i, 1:1 + len(d)] = d
+                n_valid[i] = 1 + len(d)
+            toks, pos, n_valid = (jnp.asarray(toks), jnp.asarray(self.pos),
+                                  jnp.asarray(n_valid))
+        with (rec.span("sched.verify.dispatch", slots=len(active),
+                       page_bucket=bucket, k_bucket=kb)
+              if rec.enabled else NULL_SPAN):
+            nxt, new_kv = self.verify(toks, self.pool.state(), table, pos,
+                                      n_valid)
+            self.pool.adopt(new_kv)
+        with rec.span("sched.verify.wait"):
+            outs = np.asarray(nxt)              # [n_slots, kb]
+        with rec.span("sched.verify.emit"):
+            m.decode_steps += 1
+            m.decode_slot_steps += len(active)
+            m.spec_verify_steps += 1
+            m.record_read(self.pool, bucket)
+            if did_prefill:
+                m.interleaved_steps += 1
+            for i in active:
+                d = drafts.get(i, [])
+                acc = spec.accept_length(d, outs[i])
+                m.spec_proposed += len(d)
+                m.spec_accepted += acc
+                m.decode_steps_saved += acc
+                if d and rec.enabled:
+                    rec.instant(self._rids[id(self.slots[i].req)],
+                                "VERIFY", "VERIFY", step_clock, slot=i,
+                                k_bucket=kb, proposed=len(d), accepted=acc)
+                # emitted stream = accepted draft prefix + the model's own
+                # next token after it — exactly sequential greedy decode
+                for t in outs[i, :acc + 1]:
+                    self.pos[i] += 1
+                    self._post_token(i, int(t))
+                    if self.slots[i] is None:
+                        break                   # EOS / budget mid-block
+        return kb, bucket
 
     # -- paging / preemption --------------------------------------------------
 
@@ -922,7 +967,6 @@ class Scheduler:
         except AttributeError:
             pass
         self.metrics.observe("e2e_steps", e2e)
-        self.metrics.observe("request_decode_steps", len(req.out_tokens))
 
     def _finish(self, slot: int) -> None:
         st = self.slots[slot]

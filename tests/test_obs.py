@@ -165,6 +165,62 @@ def test_recorder_spans_pair_up():
     assert spans[1]["args"]["tokens"] == 6
 
 
+def test_null_recorder_span_is_one_shared_noop():
+    first = NULL_RECORDER.span("sched.admit")
+    assert NULL_RECORDER.span("sched.iteration", step=3) is first
+    assert NULL_RECORDER.span("sched.decode.dispatch", slots=2) is first
+    with first:
+        with NULL_RECORDER.span("sched.decode.wait"):
+            pass
+    assert NULL_RECORDER.events == []
+
+
+def test_recorder_span_pushes_host_begin_end():
+    rec = TraceRecorder()
+    with rec.span("sched.iteration", step=7):
+        with rec.span("sched.decode.dispatch", slots=2, page_bucket=4):
+            pass
+    evs = [(e["kind"], e["name"], e["step"], e["phase"]) for e in rec.events]
+    assert evs == [("B", "sched.iteration", 7, "HOST"),
+                   ("B", "sched.decode.dispatch", None, "HOST"),
+                   ("E", "sched.decode.dispatch", None, "HOST"),
+                   ("E", "sched.iteration", 7, "HOST")]
+    assert rec.events[1]["args"] == {"slots": 2, "page_bucket": 4}
+    assert rec.spans() == {}            # host spans are not request spans
+
+
+def test_recorder_span_closes_on_exception():
+    rec = TraceRecorder()
+    with pytest.raises(KeyError):
+        with rec.span("sched.decode.emit"):
+            raise KeyError("stream closed")
+    assert [e["kind"] for e in rec.events] == ["B", "E"]
+
+
+def test_recorder_span_lands_in_the_profile(tmp_path):
+    """A span opens a TraceAnnotation: a profiler session records it in
+    the host plane with its args as stats (the iteration's step as
+    ``step_num``)."""
+    import jax
+    rec = TraceRecorder()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("sched.iteration", step=5):
+            with rec.span("sched.prefill.dispatch", slots=2, chunk_bucket=32,
+                          page_bucket=8):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(str(path))
+    found = {ev.name: dict(ev.stats) for plane in data.planes
+             if plane.name.startswith("/host") for line in plane.lines
+             for ev in line.events if ev.name.startswith("sched.")}
+    assert found["sched.iteration"]["step_num"] == 5
+    assert found["sched.prefill.dispatch"] == {
+        "slots": 2, "chunk_bucket": 32, "page_bucket": 8}
+
+
 def test_recorder_ring_drops_oldest():
     rec = TraceRecorder(capacity=3)
     for i in range(5):
@@ -402,7 +458,59 @@ def traced_run():
 def test_traced_run_zero_perturbation(traced_run):
     rec, eng_on, reqs_on, eng_off, reqs_off = traced_run
     assert [r.out_tokens for r in reqs_on] == [r.out_tokens for r in reqs_off]
-    assert eng_on.metrics.decode_steps == eng_off.metrics.decode_steps
+    for name in ("decode_steps", "prefill_steps", "prefill_chunks",
+                 "tokens_out"):
+        assert getattr(eng_on.metrics, name) == getattr(eng_off.metrics, name)
+
+
+def _iterations(events):
+    """The host spans of each scheduler iteration: [(step, [child names in
+    order])], checking that iterations never nest."""
+    out, depth = [], 0
+    for e in events:
+        if e["phase"] != "HOST":
+            continue
+        if e["name"] == "sched.iteration":
+            depth += 1 if e["kind"] == "B" else -1
+            assert depth in (0, 1), "nested iterations"
+            if e["kind"] == "B":
+                out.append((e["step"], []))
+        else:
+            assert depth == 1, f"{e['name']} outside an iteration"
+            if e["kind"] == "B":
+                out[-1][1].append(e["name"])
+    assert depth == 0
+    return out
+
+
+PREFILL_SPANS = ["sched.prefill.prepare", "sched.prefill.dispatch",
+                 "sched.prefill.wait", "sched.prefill.emit"]
+DECODE_SPANS = ["sched.decode.prepare", "sched.decode.dispatch",
+                "sched.decode.wait", "sched.decode.emit"]
+
+
+def test_traced_run_iteration_spans(traced_run):
+    """One ``sched.iteration`` per pass of the loop, on the step clock,
+    holding admission, then the prefill and decode calls split into
+    prepare / dispatch / wait / emit, then the step's accounting."""
+    rec, eng_on = traced_run[0], traced_run[1]
+    its = _iterations(rec.events)
+    assert [step for step, _ in its] == list(range(len(its)))
+    for _, names in its:
+        assert names[0] == "sched.admit"
+        if len(names) == 1:
+            continue                    # nothing live: a future arrival
+        rest = names[1:]
+        if rest[:4] == PREFILL_SPANS:
+            rest = rest[4:]
+        assert rest[0] == "sched.pages" and rest[-1] == "sched.account"
+        assert rest[1:-1] in ([], DECODE_SPANS)
+    m = eng_on.metrics
+    assert sum(n[1:5] == PREFILL_SPANS for _, n in its) == m.prefill_steps
+    assert sum("sched.decode.dispatch" in n for _, n in its) == m.decode_steps
+    args = [e["args"] for e in rec.events if e["kind"] == "B"
+            and e["name"] == "sched.decode.dispatch"]
+    assert all(a["slots"] >= 1 and a["page_bucket"] >= 1 for a in args)
 
 
 def test_traced_run_lifecycle_invariants(traced_run):

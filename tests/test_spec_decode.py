@@ -259,3 +259,30 @@ def test_spec_config_validation(small_model):
                     spec_mode="medusa")
     with pytest.raises(ValueError, match="spec_k"):
         _spec_engine(cfg, params, spec_k=1).scheduler()
+
+
+def test_spec_traced_verify_spans(small_model):
+    """With a recorder, each speculative step's host work is split like
+    decode's: propose, pages, then verify prepare / dispatch / wait / emit
+    (the dispatch span carrying slots and buckets); the streams match an
+    untraced run."""
+    from repro.obs.trace import TraceRecorder
+    cfg, params = small_model
+    rec = TraceRecorder()
+    traced = [Request(p, max_new_tokens=12) for p in MIXED]
+    plain = [Request(p, max_new_tokens=12) for p in MIXED]
+    _spec_engine(cfg, params, recorder=rec).generate(traced)
+    _spec_engine(cfg, params).generate(plain)
+    assert [r.out_tokens for r in traced] == [r.out_tokens for r in plain]
+    names = [e["name"] for e in rec.events
+             if e["phase"] == "HOST" and e["kind"] == "B"]
+    verify = ["sched.verify.prepare", "sched.verify.dispatch",
+              "sched.verify.wait", "sched.verify.emit"]
+    starts = [i for i, n in enumerate(names) if n == "sched.verify.prepare"]
+    assert starts
+    for i in starts:
+        assert names[i:i + 4] == verify
+        assert names[i - 2:i] == ["sched.verify.propose", "sched.pages"]
+    args = [e["args"] for e in rec.events if e["kind"] == "B"
+            and e["name"] == "sched.verify.dispatch"]
+    assert all(a["k_bucket"] >= 2 and a["slots"] >= 1 for a in args)
