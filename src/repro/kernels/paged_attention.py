@@ -4,8 +4,8 @@ One traced step of the serving pool reads each slot's K/V *through its
 page table*: the kernel never materializes the gathered ``[b, pages*ps,
 ...]`` key range that the jnp reference builds — page ids ride a
 scalar-prefetch page table straight into the BlockSpec index maps, so the
-grid's innermost dimension streams one physical page per step from HBM
-and accumulates flash-attention-style (running max / denominator /
+grid's inner dimension streams one physical page per step from HBM and
+accumulates flash-attention-style (running max / denominator /
 un-normalized accumulator in VMEM scratch).  INT8 pages are dequantized
 in-kernel from their per-(position, head) scales — the int8 bytes are
 what crosses HBM.  INT4 pages (MUXQ'd KV, ``repro.serve.kvq``) go
@@ -15,10 +15,21 @@ magnitude-redistribution rows (``k_redist``/``v_redist`` [kvh, dh]: 2^e
 on calibrated outlier channels) — so the *packed* int4 bytes are what
 crosses HBM, half the int8 traffic.
 
-Pages are HEAD-MAJOR, ``[n_pages, kvh, ps, dh]``: one (page, head) block
-is a ``(ps, dh)`` tile whose last two dims the TPU compiler accepts (ps a
-multiple of 8, dh the whole axis).  A position-major page would put a
-size-1 head block on the second-minor axis, which it refuses.
+Pages are HEAD-MAJOR, ``[n_pages, kvh, ps, dh]``: one page of every head
+is one contiguous ``(kvh, ps, dh)`` block whose last two dims the TPU
+compiler accepts (ps a multiple of 8, dh the whole axis).  A
+position-major page would put a size-1 head block on the second-minor
+axis, which it refuses.
+
+The grid is ``(slot, page)``: one step loads a page of every kv head and
+updates every head's running sums, with dots batched over the head axis.
+A slot walks only its LIVE pages.  Its last live page is
+``(pos[b] + sq - 1) // ps``, the page of its last query row; past it the
+K/V index maps repeat that page, which Pallas does not copy again, and
+``pl.when`` skips the body — every key there is masked for every row, so
+the result is bit for bit what the full walk gives.  An idle slot (``pos`` 0, zeroed table row) thus costs
+one page, not the longest live slot's bucket.  Pages below a sliding
+window are still walked and masked.
 
 The query side is a ``[slot, sq]`` BLOCK, not a single token:
 
@@ -36,10 +47,10 @@ sequence, not the slot capacity.
 **Tensor-parallel serving** (``parallel/serve_sharding.py``) needs no code
 here: both the reference and the Pallas kernel derive ``kvh`` and the GQA
 group ``g = h // kvh`` from the array shapes, so inside a ``shard_map``
-body they see the per-shard head slice (``kvh / mesh``) and the grid's
-KV-head dimension shrinks to match — same program, fewer heads per device.
-The head merge (zero-pad + psum) happens in ``models/attention.py``, after
-the kernel returns.
+body they see the per-shard head slice (``kvh / mesh``) and each grid
+step's head block shrinks to match — same program, fewer heads per
+device.  The head merge (zero-pad + psum) happens in
+``models/attention.py``, after the kernel returns.
 
 Execution selection mirrors ``repro.kernels.dispatch``:
 
@@ -49,9 +60,9 @@ Execution selection mirrors ``repro.kernels.dispatch``:
   * ``ref``       — the jnp gather reference (bit-identical to the dense
                     full-range gather the serve tests pin against).
 
-GQA rides in the grid: programs iterate (slot, kv_head, page) and each
-program attends all ``sq * h // kvh`` query rows of its group at once, so
-the broadcast KV never materializes (same trick as ``flash_attention``).
+GQA rides in the block: each kv head attends all ``sq * h // kvh`` query
+rows of its group at once, so the broadcast KV never materializes (same
+trick as ``flash_attention``).
 """
 from __future__ import annotations
 
@@ -164,13 +175,26 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, pos, *,
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _kernel(tab_ref, pos_ref, win_ref,              # scalar prefetch
+def _dequant(x, s_ref, r_ref, mode: str):
+    """One page's block of every head, ``[kvh, ps, dh | dh//2]``, as
+    float32 ``[kvh, ps, dh]``: int8 codes times their per-(position, head)
+    scale; int4 nibbles unpacked, scaled, and times the head's inverse
+    redistribution row (``[kvh, 1, dh]``, broadcast over positions)."""
+    if mode == "int4":
+        return (unpack_int4(x).astype(jnp.float32)
+                * s_ref[0].astype(jnp.float32) * r_ref[...])
+    if mode == "int8":
+        return x.astype(jnp.float32) * s_ref[0].astype(jnp.float32)
+    return x.astype(jnp.float32)
+
+
+def _kernel(tab_ref, pos_ref, last_ref, win_ref,    # scalar prefetch
             q_ref, k_ref, v_ref, ks_ref, vs_ref,    # blocks (scales opt.)
             kr_ref, vr_ref,                         # int4 redist rows (opt.)
             o_ref, m_ref, l_ref, acc_ref, *,
             scale: float, nj: int, ps: int, g: int, mode: str,
             softcap: Optional[float]):
-    bb, j = pl.program_id(0), pl.program_id(2)
+    bb, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -178,55 +202,46 @@ def _kernel(tab_ref, pos_ref, win_ref,              # scalar prefetch
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)               # [sq*g, dh]
-    k = k_ref[0, 0]                                   # [ps, dh | dh//2]
-    v = v_ref[0, 0]
-    if mode == "int4":
-        # unpack two nibbles per byte, apply the per-(pos, head) scale and
-        # the per-head inverse redistribution row ([1, dh] bcast): only
-        # the packed int4 bytes ever crossed HBM
-        k = (unpack_int4(k).astype(jnp.float32)
-             * ks_ref[0, 0].astype(jnp.float32) * kr_ref[0])
-        v = (unpack_int4(v).astype(jnp.float32)
-             * vs_ref[0, 0].astype(jnp.float32) * vr_ref[0])
-    elif mode == "int8":
-        k = k.astype(jnp.float32) * ks_ref[0, 0].astype(jnp.float32)
-        v = v.astype(jnp.float32) * vs_ref[0, 0].astype(jnp.float32)
-    else:
-        k = k.astype(jnp.float32)
-        v = v.astype(jnp.float32)
+    # a page past the slot's last live page holds only keys that every
+    # query row masks (it would add exp(NEG_INF - m) = 0 to the sums):
+    # skip it.  Its index map repeats the last live page, so no copy
+    # is issued for it either.
+    @pl.when(j <= last_ref[bb])
+    def _attend():
+        q = q_ref[0].astype(jnp.float32)              # [kvh, sq*g, dh]
+        k = _dequant(k_ref[0], ks_ref, kr_ref, mode)  # [kvh, ps, dh]
+        v = _dequant(v_ref[0], vs_ref, vr_ref, mode)
+        # every head of the page at once: dots batched over the head axis
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale
+        if softcap is not None:
+            s = softcap * jnp.tanh(s / softcap)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
+        # logical key positions of page j: [j*ps, (j+1)*ps).  Query row r
+        # of the [sq*g] block sits at absolute position pos[bb] + r//g —
+        # the per-row causal mask that makes one kernel serve decode
+        # (sq=1), speculative verify (sq=k) and chunked prefill (sq=C,
+        # pos=start).
+        kpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        qpos = pos_ref[bb] + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1) // g
+        allow = (kpos <= qpos) & (kpos > qpos - win_ref[0])
+        s = jnp.where(allow, s, NEG_INF)              # [kvh, sq*g, ps]
 
-    # logical key positions of page j: [j*ps, (j+1)*ps).  Query row r of
-    # the [sq*g] block sits at absolute position pos[bb] + r//g — the
-    # per-row causal mask that makes one kernel serve decode (sq=1),
-    # speculative verify (sq=k) and chunked prefill (sq=C, pos=start).
-    pos = pos_ref[bb]
-    win = win_ref[0]
-    rows = q.shape[0]                                 # sq * g
-    kpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 1)
-    qpos = pos + jax.lax.broadcasted_iota(jnp.int32, (rows, ps), 0) // g
-    allow = (kpos <= qpos) & (kpos > qpos - win)
-    s = jnp.where(allow, s, NEG_INF)
-
-    m_prev = m_ref[...]                               # [sq*g, 1]
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)                            # [sq*g, ps]
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+        m_prev = m_ref[...]                           # [kvh, sq*g, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=2, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
 
     @pl.when(j == nj - 1)
     def _finalize():
         denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def paged_attention_pallas(q, k_pages, v_pages, page_table, pos, *,
@@ -237,13 +252,19 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, pos, *,
     """Pallas paged attention.  Same contract as
     :func:`paged_attention_ref`; the page table and per-slot start
     positions ride scalar prefetch so the K/V BlockSpec index maps load
-    physical pages directly (no gathered intermediate).  The whole
-    ``[sq, g]`` query block of a (slot, kv-head) program attends one page
-    per grid step with online softmax, so the verify block (sq=k) and the
-    chunked-prefill read (sq=C) cost ONE pass over the key pages — not sq
-    passes.  Int4 pages arrive nibble-packed (last dim dh//2) with
-    [kvh, dh] redistribution rows; the kernel block loads one
-    (page, head) tile of *packed* bytes and dequantizes in VMEM."""
+    physical pages directly (no gathered intermediate).
+
+    The grid is ``(slot, page)``.  One grid step loads one physical page
+    of EVERY kv head — head-major pages make that one contiguous
+    ``[kvh, ps, dh]`` block — and attends the slot's whole ``[kvh, sq*g]``
+    query block to it with online softmax, so the verify block (sq=k) and
+    the chunked-prefill read (sq=C) cost ONE pass over the key pages, not
+    sq passes.  A slot walks only its live pages: past its last live page
+    ``(pos + sq - 1) // ps`` the index maps repeat that page (no copy) and
+    the body is skipped, so an idle slot (``pos`` 0, zeroed table row)
+    costs one page, not the whole bucket.  Int4 pages arrive
+    nibble-packed (last dim dh//2) with [kvh, dh] redistribution rows; the
+    block carries the *packed* bytes and is dequantized in VMEM."""
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, None]
@@ -252,6 +273,7 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, pos, *,
     assert h % kvh == 0
     g = h // kvh
     nj = page_table.shape[1]
+    rows = sq * g
     mode = ("int4" if k_redist is not None
             else "int8" if k_scale is not None else "fp")
     assert pk_dh == (dh // 2 if mode == "int4" else dh), (pk_dh, dh, mode)
@@ -259,26 +281,29 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, pos, *,
 
     table = page_table.astype(jnp.int32)
     pos32 = pos.astype(jnp.int32)
+    # each slot's last live page: the page of its last query row's position
+    last = jnp.minimum((pos32 + sq - 1) // ps, nj - 1)
     win = jnp.full((1,), NO_WINDOW if window is None else window, jnp.int32)
-    # [b, kvh, sq*g, dh]: all of a kv head's query rows in one block
+    # [b, kvh, sq*g, dh]: every head's query rows in one block
     qg = q.reshape(b, sq, kvh, g, dh).transpose(0, 2, 1, 3, 4)
-    qg = qg.reshape(b, kvh, sq * g, dh)
+    qg = qg.reshape(b, kvh, rows, dh)
 
-    # page blocks: physical page tab[b, j], kv head hh, all ps positions
-    kv_spec = pl.BlockSpec(
-        (1, 1, ps, pk_dh),
-        lambda bb, hh, j, tab, pos_r, win_r: (tab[bb, j], hh, 0, 0))
-    sc_spec = pl.BlockSpec(
-        (1, 1, ps, 1),
-        lambda bb, hh, j, tab, pos_r, win_r: (tab[bb, j], hh, 0, 0))
-    q_spec = pl.BlockSpec(
-        (1, 1, sq * g, dh),
-        lambda bb, hh, j, tab, pos_r, win_r: (bb, hh, 0, 0))
+    def page_index(bb, j, tab, pos_r, last_r, win_r):
+        # physical page of logical page j, held at the last live page
+        return (tab[bb, jnp.minimum(j, last_r[bb])], 0, 0, 0)
+
+    def slot_index(bb, j, *_):
+        return (bb, 0, 0, 0)
+
+    def whole(*_):
+        return (0, 0, 0)
+
+    kv_spec = pl.BlockSpec((1, kvh, ps, pk_dh), page_index)
+    sc_spec = pl.BlockSpec((1, kvh, ps, 1), page_index)
+    q_spec = pl.BlockSpec((1, kvh, rows, dh), slot_index)
     # inert placeholder for operands a mode doesn't use (uniform signature)
-    def _inert_spec():
-        return pl.BlockSpec((1, 1),
-                            lambda bb, hh, j, tab, pos_r, win_r: (0, 0))
-    _inert = jnp.zeros((1, 1), jnp.float32)
+    inert_spec = pl.BlockSpec((1, 1, 1), whole)
+    inert = jnp.zeros((1, 1, 1), jnp.float32)
 
     in_specs = [q_spec, kv_spec, kv_spec]
     args = [qg, k_pages, v_pages]
@@ -286,39 +311,36 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, pos, *,
         in_specs += [sc_spec, sc_spec]
         args += [k_scale, v_scale]
     else:
-        in_specs += [_inert_spec(), _inert_spec()]
-        args += [_inert, _inert]
+        in_specs += [inert_spec, inert_spec]
+        args += [inert, inert]
     if mode == "int4":
-        # per-head inverse redistribution rows as [kvh, 1, dh]: block
-        # (1, 1, dh) at head hh keeps the tile's last two dims whole
-        rd_spec = pl.BlockSpec(
-            (1, 1, dh), lambda bb, hh, j, tab, pos_r, win_r: (hh, 0, 0))
+        # per-head inverse redistribution rows as [kvh, 1, dh], whole:
+        # they broadcast over a page's positions in the kernel
+        rd_spec = pl.BlockSpec((kvh, 1, dh), whole)
         in_specs += [rd_spec, rd_spec]
         args += [jnp.reshape(k_redist, (kvh, 1, dh)).astype(jnp.float32),
                  jnp.reshape(v_redist, (kvh, 1, dh)).astype(jnp.float32)]
     else:
-        in_specs += [_inert_spec(), _inert_spec()]
-        args += [_inert, _inert]
+        in_specs += [inert_spec, inert_spec]
+        args += [inert, inert]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, kvh, nj),
+        num_scalar_prefetch=4,
+        grid=(b, nj),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, sq * g, dh),
-            lambda bb, hh, j, tab, pos_r, win_r: (bb, hh, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((sq * g, 1), jnp.float32),
-                        pltpu.VMEM((sq * g, 1), jnp.float32),
-                        pltpu.VMEM((sq * g, dh), jnp.float32)],
+        out_specs=pl.BlockSpec((1, kvh, rows, dh), slot_index),
+        scratch_shapes=[pltpu.VMEM((kvh, rows, 1), jnp.float32),
+                        pltpu.VMEM((kvh, rows, 1), jnp.float32),
+                        pltpu.VMEM((kvh, rows, dh), jnp.float32)],
     )
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, nj=nj, ps=ps, g=g, mode=mode,
                           softcap=softcap),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, sq * g, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, rows, dh), q.dtype),
         interpret=interpret,
         name="paged_attention",
-    )(table, pos32, win, *args)
+    )(table, pos32, last, win, *args)
     out = out.reshape(b, kvh, sq, g, dh).transpose(0, 2, 1, 3, 4)
     out = out.reshape(b, sq, h, dh)
     return out[:, 0] if squeeze else out

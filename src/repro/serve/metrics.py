@@ -28,6 +28,8 @@ from __future__ import annotations
 import time
 from typing import Dict, List, Optional
 
+import numpy as np
+
 from repro.obs.registry import STEP_BUCKETS, MetricsRegistry
 
 # scalar int counters the facade routes to registry Counters (attribute
@@ -66,6 +68,11 @@ _COUNTERS = (
     # block-sparse decode read accounting
     "kv_bytes_read",       # bucketed page-budget gather (actual)
     "kv_bytes_read_dense",  # full-capacity gather (counterfactual)
+    # paged-attention grid engagement, summed over decode, verify and
+    # prefill dispatches
+    "attn_pages_bucket",   # slots x page bucket: the (slot, page) grid
+    "attn_pages_live",     # of those, pages up to each slot's last live
+                           # page: the ones the kernel reads
     # prefix sharing
     "prefix_hits",         # admissions that mapped shared pages
     "shared_pages_mapped",  # pages mapped instead of allocated
@@ -157,6 +164,17 @@ class ServeMetrics:
         self.kv_bytes_read_dense += pool.n_slots * pool.pages_per_slot * per_page
         self.decode_buckets[bucket] = self.decode_buckets.get(bucket, 0) + 1
 
+    def record_attn_pages(self, pos, sq: int, bucket: int,
+                          page_size: int) -> None:
+        """Account one paged-attention dispatch over ``len(pos)`` slots:
+        ``bucket`` pages per slot in the kernel's grid, of which it reads
+        each slot's pages up to the one holding its last query row
+        (position ``pos + sq - 1``)."""
+        pos = np.asarray(pos, np.int64)
+        self.attn_pages_bucket += len(pos) * bucket
+        self.attn_pages_live += int(np.minimum(
+            bucket, (pos + sq - 1) // page_size + 1).sum())
+
     def sample_pool(self, pool_stats: Dict[str, float]) -> None:
         self.occupancy.append(float(pool_stats.get("occupancy", 0.0)))
         frag = pool_stats.get("internal_fragmentation")
@@ -238,6 +256,8 @@ class ServeMetrics:
             "kv_bytes_read_dense": self.kv_bytes_read_dense,
             "kv_read_savings": (1.0 - self.kv_bytes_read / self.kv_bytes_read_dense
                                 if self.kv_bytes_read_dense else 0.0),
+            "attn_pages_bucket": self.attn_pages_bucket,
+            "attn_pages_live": self.attn_pages_live,
             "decode_buckets": {str(k): v for k, v in
                                sorted(self.decode_buckets.items())},
             "prefix_hits": self.prefix_hits,

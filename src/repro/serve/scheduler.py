@@ -362,6 +362,8 @@ class Scheduler:
                 bucket, table = self._decode_table(active)
                 toks = jnp.asarray(self.last_tok)[:, None]
                 pos = jnp.asarray(self.pos)
+                m.record_attn_pages(self.pos, 1, bucket,
+                                    self.pool.page_size)
             with (rec.span("sched.decode.dispatch", slots=len(active),
                            page_bucket=bucket)
                   if rec.enabled else NULL_SPAN):
@@ -679,6 +681,7 @@ class Scheduler:
             # are mapped read-only) nor the chunk's padding tail
             w_lo[j] = max(done, st.write_from)
             w_hi[j] = min(done + n, len(st.ids))
+        m.record_attn_pages(start, cb, pb, ps)
         return ns, cb, pb, jnp.asarray(toks), (
             jnp.asarray(tab), jnp.asarray(start), jnp.asarray(w_lo),
             jnp.asarray(w_hi))
@@ -826,6 +829,7 @@ class Scheduler:
                 n_valid[i] = 1 + len(d)
             toks, pos, n_valid = (jnp.asarray(toks), jnp.asarray(self.pos),
                                   jnp.asarray(n_valid))
+            m.record_attn_pages(self.pos, kb, bucket, self.pool.page_size)
         with (rec.span("sched.verify.dispatch", slots=len(active),
                        page_bucket=bucket, k_bucket=kb)
               if rec.enabled else NULL_SPAN):

@@ -15,7 +15,10 @@ Acceptance criteria covered here:
     preemption/free with refcounted pages never corrupts the sibling;
   * the Pallas paged-attention decode kernel (interpret mode) matches the
     jnp gather reference on fp, int8 and int4 (nibble-packed + redistributed)
-    pages, with sliding windows and logit softcap, under GQA (h > kvh).
+    pages, with sliding windows and logit softcap, under GQA (h > kvh);
+    over ragged and idle slots it walks only each slot's live pages (pages
+    past them, poisoned with NaN, never reach the output), and the
+    scheduler's ``attn_pages_*`` counters price that walk.
 """
 import jax
 import jax.numpy as jnp
@@ -147,6 +150,52 @@ def test_bucketing_never_retraces_within_bucket(small_model):
     eng.generate([Request("d" * 25, max_new_tokens=14)])
     assert set(eng.decode_buckets) == buckets_first
     assert eng.decode_traces == len(buckets_first)  # no retrace in-bucket
+
+
+@pytest.mark.parametrize("spec_mode", ["off", "ngram"])
+def test_attn_page_counters_match_the_dispatched_steps(small_model,
+                                                       spec_mode):
+    """``attn_pages_bucket`` / ``attn_pages_live`` equal sums over the
+    arrays each prefill, decode and verify call was handed: slots x the
+    page table's width, and per slot the pages up to its last query row
+    ``(pos + sq - 1) // ps``, capped at the width."""
+    cfg, params, _ = small_model
+    eng = ServeEngine(cfg, params, max_batch=3, s_max=64, page_size=8,
+                      kv_mode="fp", cache_dtype=jnp.float32,
+                      prefill_chunk=8, spec_mode=spec_mode, spec_k=4)
+    sched = eng.scheduler()
+    calls = []                              # (kind, sq, table, first pos)
+
+    def spy(kind, fn):
+        def call(toks, state, table, pos, *rest):
+            sq = 1 if kind == "decode" else toks.shape[1]
+            # copies: on the CPU the uploaded arrays may alias the
+            # scheduler's own, which it updates after the step
+            calls.append((kind, sq, np.array(table), np.array(pos)))
+            return fn(toks, state, table, pos, *rest)
+        return call
+
+    sched.prefill = spy("prefill", sched.prefill)
+    sched.decode = spy("decode", sched.decode)
+    sched.verify = spy("verify", sched.verify)
+    sched.run([Request(p, max_new_tokens=12) for p in
+               ("abcabcabcabcabcabc", "one two one two", "xy")])
+    kinds = {c[0] for c in calls}
+    assert {"prefill", "decode"} <= kinds
+    assert ("verify" in kinds) == (spec_mode == "ngram")
+    ps = eng.pool.page_size
+    bucket = live = 0
+    for _, sq, table, pos in calls:
+        width = table.shape[1]
+        bucket += table.shape[0] * width
+        live += sum(min(width, (int(p) + sq - 1) // ps + 1) for p in pos)
+    m = sched.metrics
+    assert m.attn_pages_bucket == bucket
+    assert m.attn_pages_live == live
+    assert 0 < live < bucket                # idle slots read one page
+    rep = m.report()
+    assert (rep["attn_pages_bucket"], rep["attn_pages_live"]) == (bucket,
+                                                                 live)
 
 
 # ---------------------------------------------------------------------------
@@ -357,6 +406,76 @@ def test_paged_kernel_interpret_matches_ref(mode, window, softcap):
                                     **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+def _ragged_case(seed, *, sq, h, kvh, mode, pos, ps=8, pages=6, dh=16):
+    """Operands of one pooled step whose slots sit at ragged ``pos``: a
+    slot at position 0 is idle (zeroed table row: every entry the scratch
+    page 0), the others own distinct scrambled pages.  The ``pages``
+    bucket is wider than every slot's live range, so each slot has pages
+    past its last live one.  Returns ``(q [b, sq, h, dh], k_pages,
+    v_pages, kw, table, pos, live slots)``."""
+    q, kp, vp, kw, table, _ = _random_paged_case(
+        seed, b=len(pos), h=h, kvh=kvh, dh=dh, ps=ps, pages=pages, mode=mode)
+    rng = np.random.default_rng(seed + 1)
+    q = jnp.asarray(rng.normal(size=(len(pos), sq, h, dh)).astype(np.float32))
+    table = np.asarray(table).copy()
+    live = [i for i, p in enumerate(pos) if p > 0]
+    table[[i for i in range(len(pos)) if i not in live]] = 0
+    assert max(p + sq - 1 for p in pos) // ps < pages - 1
+    return (q, kp, vp, kw, jnp.asarray(table),
+            jnp.asarray(pos, jnp.int32), live)
+
+
+@pytest.mark.parametrize("mode", ["fp", "int8", "int4"])
+@pytest.mark.parametrize("sq", [1, 4])
+@pytest.mark.parametrize("h,kvh", [(4, 4), (8, 4)], ids=["mha", "gqa"])
+@pytest.mark.parametrize("window,softcap", [(None, None), (6, 30.0)])
+def test_paged_kernel_walks_only_live_pages(mode, sq, h, kvh, window,
+                                            softcap):
+    """Decode (sq=1) and verify (sq=4) blocks over ragged slots: an idle
+    slot, one inside its first page, one whose rows cross a page boundary,
+    one deep in the bucket — every live slot's rows match the reference,
+    which reads the whole bucket."""
+    pos = [0, 1, 7, 3 * 8 + 2]
+    q, kp, vp, kw, table, pos, live = _ragged_case(
+        {"fp": 20, "int8": 21, "int4": 23}[mode] + sq, sq=sq, h=h, kvh=kvh,
+        mode=mode, pos=pos)
+    kw = dict(kw, window=window, softcap=softcap)
+    ref = PA.paged_attention_ref(q, kp, vp, table, pos, **kw)
+    out = PA.paged_attention_pallas(q, kp, vp, table, pos, interpret=True,
+                                    **kw)
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(ref)[live],
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("mode", ["fp", "int8", "int4"])
+def test_pages_past_the_live_range_are_never_read(mode):
+    """Every page past a slot's last live page is poisoned with NaN (the
+    K/V of fp pages, the scales of int8/int4 codes): the output stays
+    finite and equal to the reference on the same pages with those NaNs
+    zeroed.  Read, such a page's V would poison the accumulator (a masked
+    key's 0 probability times NaN)."""
+    ps, sq = 8, 1
+    q, kp, vp, kw, table, pos, live = _ragged_case(
+        30, sq=sq, h=8, kvh=4, mode=mode, pos=[0, 1, 7, 26], ps=ps)
+    last = (np.asarray(pos) + sq - 1) // ps
+    dead = np.asarray([int(table[i, j]) for i in live
+                       for j in range(last[i] + 1, table.shape[1])])
+    names = ("k", "v") if mode == "fp" else ("k_scale", "v_scale")
+    arrays = dict(kw, k=kp, v=vp)
+    poisoned = {n: arrays[n].at[dead].set(jnp.nan) for n in names}
+    zeroed = {n: arrays[n].at[dead].set(0) for n in names}
+
+    def call(fn, a, **extra):
+        a = dict(arrays, **a)
+        ops = {n: a[n] for n in kw}
+        return np.asarray(fn(q, a["k"], a["v"], table, pos, **ops, **extra))
+
+    out = call(PA.paged_attention_pallas, poisoned, interpret=True)
+    ref = call(PA.paged_attention_ref, zeroed)
+    assert np.isfinite(out[live]).all()
+    np.testing.assert_allclose(out[live], ref[live], atol=2e-5, rtol=2e-5)
 
 
 def test_paged_kernel_respects_page_table_indirection():

@@ -6,9 +6,10 @@ position).  Parity protocol follows ``test_paged_sparse``: interpret-mode
 Pallas against the jnp gather reference, over GQA (h > kvh), sliding
 windows, logit softcap, all three page modes (fp / int8 / int4
 nibble-packed + redistributed), and chunk sizes below, at, and above the
-page size — plus the model-level chunked prefill under
-``set_paged_impl('interpret')`` and a verify-block (sq=k, per-slot pos)
-sweep, since both ride the same kernel.
+page size, and a pooled step whose idle slots and wide page bucket leave
+pages past each slot's live range — plus the model-level chunked prefill
+under ``set_paged_impl('interpret')`` and a verify-block (sq=k, per-slot
+pos) sweep, since both ride the same kernel.
 """
 import jax
 import jax.numpy as jnp
@@ -86,6 +87,33 @@ def test_prefill_block_interpret_matches_ref(mode, sq, window, softcap):
     out = PA.paged_attention_pallas(q, kp, vp, table, pos, interpret=True,
                                     **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("mode", ["fp", "int8", "int4"])
+@pytest.mark.parametrize("starts", [(0, 0, 2 * PS), (0, PS + 3, 5)],
+                         ids=["on-page", "off-page"])
+@pytest.mark.parametrize("h,kvh", [(4, 4), (8, 4)], ids=["mha", "gqa"])
+@pytest.mark.parametrize("window,softcap", [(None, None), (9, 30.0)])
+def test_prefill_step_walks_only_live_pages(mode, starts, h, kvh, window,
+                                            softcap):
+    """The pooled prefill step's read, sq=32: slot 0 idle (start 0,
+    zeroed table row), the chunking slots starting on or off a page
+    boundary, and a page bucket wider than every slot's live range — each
+    chunking slot's rows match the reference, which reads the whole
+    bucket."""
+    sq, pages = 32, 10
+    q, kp, vp, kw, table, pos = _block_case(
+        {"fp": 40, "int8": 41, "int4": 43}[mode] + starts[1], b=len(starts),
+        sq=sq, h=h, kvh=kvh, pages=pages, mode=mode)
+    pos = jnp.asarray(starts, jnp.int32)
+    table = table.at[0].set(0)
+    assert (max(starts) + sq - 1) // PS < pages - 1
+    kw = dict(kw, window=window, softcap=softcap)
+    ref = PA.paged_attention_ref(q, kp, vp, table, pos, **kw)
+    out = PA.paged_attention_pallas(q, kp, vp, table, pos, interpret=True,
+                                    **kw)
+    np.testing.assert_allclose(np.asarray(out)[1:], np.asarray(ref)[1:],
                                atol=2e-5, rtol=2e-5)
 
 

@@ -5,7 +5,8 @@ topology that is described, not attached.  What it refuses here (tiles
 not aligned to (8, 128), more VMEM than a kernel may use) is what the
 chip would refuse.  Shapes are GPT-2 small's published widths
 (d 768, 12 heads, head_dim 64, d_ff 3072) at the serve step's decode and
-prefill-chunk sizes.
+prefill-chunk sizes; the paged-attention kernel also at GPT-2 large's
+(20 kv heads of 64) and the chat cells' pool.
 
 The topology is described only inside the fixture below: only one
 process at a time may load the TPU library, so doing it at import (here,
@@ -93,25 +94,39 @@ def test_fused_site_compiles(one_chip, m, k_in, k, n, precision):
                                                "muxq_gemm": 1}
 
 
+# (kv heads, q heads, head_dim, slots, page bucket, q dtype of int pages):
+# GPT-2 small at the chip smoke's pool, and GPT-2 large (20 kv heads of 64)
+# at the chat cells' 32 slots and 32-page bucket, whose MUXQ cell feeds
+# float32 queries to int8 pages
+SMALL = (KVH, H, DH, SLOTS, PAGES, jnp.bfloat16)
+LARGE = (20, 20, 64, 32, 32, jnp.float32)
+
+
 @pytest.mark.parametrize("sq", [1, CHUNK], ids=["decode", "prefill"])
-@pytest.mark.parametrize("mode", ["fp", "int8", "int4"])
-def test_paged_attention_compiles(one_chip, mode, sq):
-    """Head-major pages [n_pages, kvh, ps, dh]: one (page, head) block is
-    a (ps, dh) tile; int4 pages unpack their nibbles in the kernel."""
-    n_pages = SLOTS * PAGES + 1
-    pk = DH // 2 if mode == "int4" else DH
+@pytest.mark.parametrize("mode,widths", [
+    ("fp", SMALL), ("int8", SMALL), ("int4", SMALL),
+    ("fp", LARGE), ("int8", LARGE)],
+    ids=["fp", "int8", "int4", "gpt2-large-fp", "gpt2-large-int8"])
+def test_paged_attention_compiles(one_chip, mode, widths, sq):
+    """Head-major pages [n_pages, kvh, ps, dh]: one grid step's block is a
+    page of every kv head, ``(kvh, ps, dh)``; int4 pages unpack their
+    nibbles in the kernel."""
+    kvh, h, dh, slots, pages_per_slot, int_q_dtype = widths
+    n_pages = slots * pages_per_slot + 1
+    pk = dh // 2 if mode == "int4" else dh
     dtype = jnp.bfloat16 if mode == "fp" else jnp.int8
-    pages = _shape(one_chip, (n_pages, KVH, PS, pk), dtype)
-    q = _shape(one_chip, (SLOTS, sq, H, DH), jnp.bfloat16)
-    table = _shape(one_chip, (SLOTS, PAGES), jnp.int32)
-    pos = _shape(one_chip, (SLOTS,), jnp.int32)
+    q_dtype = jnp.bfloat16 if mode == "fp" else int_q_dtype
+    pages = _shape(one_chip, (n_pages, kvh, PS, pk), dtype)
+    q = _shape(one_chip, (slots, sq, h, dh), q_dtype)
+    table = _shape(one_chip, (slots, pages_per_slot), jnp.int32)
+    pos = _shape(one_chip, (slots,), jnp.int32)
     kw = {}
     if mode != "fp":
         sdt = jnp.bfloat16 if mode == "int4" else jnp.float32
         kw["k_scale"] = kw["v_scale"] = _shape(one_chip,
-                                               (n_pages, KVH, PS, 1), sdt)
+                                               (n_pages, kvh, PS, 1), sdt)
     if mode == "int4":
-        kw["k_redist"] = kw["v_redist"] = _shape(one_chip, (KVH, DH),
+        kw["k_redist"] = kw["v_redist"] = _shape(one_chip, (kvh, dh),
                                                  jnp.float32)
     compiled = jax.jit(
         lambda q, k, v, t, p, kw: paged_attention_pallas(q, k, v, t, p, **kw)
