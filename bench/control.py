@@ -24,7 +24,7 @@ def readings(workload: str, seeds, seconds: float, *, root=run.ROOT,
              cache=run.CACHE, require_tpu: bool = True):
     _, cell, spec, traffic = run.load_cell(root, workload)
     dev, _ = run.device_info(cell["chips"], require_tpu)
-    s = run.set_up(spec, traffic, False, cache)
+    s = run.set_up(spec, traffic, False, cache, root)
     open_after = int(traffic.get("open_after_finished", 0))
     windows = []
     for seed in seeds:

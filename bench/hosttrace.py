@@ -19,7 +19,6 @@ function returns None.
 from __future__ import annotations
 
 import glob
-import json
 import os
 from pathlib import Path
 from typing import Dict, List, Optional
@@ -36,9 +35,6 @@ POOL_SCOPE = "pool"
 STEP_PROGRAMS = ("jit_decode", "jit_prefill", "jit_verify")
 # the op metadata stat that holds the op's name scope (XLA's op_name)
 SCOPE_STAT = "tf_op"
-BENCH = Path(__file__).resolve().parent
-# bench/run.py writes a traced window's profile under TRACE_ROOT/<cell>
-TRACE_ROOT = BENCH / ".cache" / "trace"
 
 _loaded: Dict[tuple, Dict] = {}
 
@@ -165,41 +161,28 @@ def op_scopes(xspace: bytes) -> Dict[str, str]:
             for name, scope in found.items()}
 
 
-def trace_dirs(run) -> List[Path]:
-    """Where the window's profile may be: ``run.trace_dir`` if the reader
-    context has one, else the trace directory of each cell that runs the
-    context's configuration (``run.spec["name"]``; today one cell each), so
-    another configuration's profile is never read."""
-    if getattr(run, "trace_dir", None):
-        return [Path(run.trace_dir)]
-    spec = getattr(run, "spec", None) or {}
-    bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
-    return [TRACE_ROOT / w["name"] for w in bench["workloads"]
-            if w["config"] == spec.get("name")]
-
-
 def window(run) -> Optional[Dict]:
     """The traced window's spans and records, or None.  The profile is the
-    newest under one of :func:`trace_dirs`, and it counts only if its step
-    programs are the ones the harness reduced (``run.device["modules"]``)."""
+    newest under ``run.trace_dir``, the directory the harness traced the
+    window into, and it counts only if its step programs are the ones the
+    harness reduced (``run.device["modules"]``)."""
     want = run.device["modules"]
-    if not want:
+    if not want or not run.trace_dir:
         return None
-    for root in trace_dirs(run):
-        try:
-            trace = load(str(root))
-        except FileNotFoundError:
-            continue
-        got: Dict[str, List] = {}
-        for r in trace["records"]:
-            if r["line"] == tracefile.MODULES:
-                t = got.setdefault(tracefile._module(r["name"]), [0.0, 0])
-                t[0] += r["dur_ns"] * 1e-9
-                t[1] += 1
-        if got.keys() == want.keys() and all(
-                got[k][1] == n and abs(got[k][0] - t) <= 1e-9 * t
-                for k, (t, n) in want.items()):
-            return trace
+    try:
+        trace = load(str(run.trace_dir))
+    except FileNotFoundError:
+        return None
+    got: Dict[str, List] = {}
+    for r in trace["records"]:
+        if r["line"] == tracefile.MODULES:
+            t = got.setdefault(tracefile._module(r["name"]), [0.0, 0])
+            t[0] += r["dur_ns"] * 1e-9
+            t[1] += 1
+    if got.keys() == want.keys() and all(
+            got[k][1] == n and abs(got[k][0] - t) <= 1e-9 * t
+            for k, (t, n) in want.items()):
+        return trace
     return None
 
 

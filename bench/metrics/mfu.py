@@ -1,7 +1,8 @@
 """Model operations per second of the traced window over the chip's
 matmul peak for the configuration (int8 for quantized weights, bf16
-otherwise): projections for every token processed, attention over the
-live positions, the LM head for every sampled position."""
+otherwise): every projection site for the tokens that reach it,
+attention over the live positions, the LM head for every sampled
+position, as ``work.py`` counts them from the reference's description."""
 
 
 def read(run):

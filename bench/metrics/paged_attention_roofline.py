@@ -1,7 +1,9 @@
 """Share of its roofline the paged_attention kernel reaches: the least
-time for the K/V bytes of the live positions each call attends (not the
-page bucket it walks) and their operations at the bf16 peak, over the
-device time of the kernel's events in the trace."""
+time for the cached bytes of the live positions each call attends (not
+the page bucket it walks; in a windowed layer only its window) and their
+operations at the bf16 peak, as ``work.py`` counts them from the
+reference's description, over the device time of the kernel's events in
+the trace."""
 import tracefile
 import work
 

@@ -1,6 +1,8 @@
-"""KV page megabytes a decode step reads (1 MB = 1e6 bytes): ServeMetrics
-kv_bytes_read, priced from page shapes over every slot's bucketed page
-budget, over decode_steps."""
+"""KV page megabytes a decode step's bucket spans (1 MB = 1e6 bytes):
+ServeMetrics kv_bytes_read, priced from page shapes over every slot's
+whole bucketed page budget, over decode_steps.  The paged kernel stops at
+each slot's last live page, so it reads only part of this: about a fifth
+in the chat cells (``paged_attention.live_page_share``)."""
 
 
 def read(run):

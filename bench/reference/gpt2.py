@@ -1,7 +1,7 @@
 """GPT-2 as the repository serves it, written out plainly for the benchmark.
 
 A configuration file names its reference by the key ``"reference"``; the
-harness loads ``bench/reference/<name>.py`` and uses three functions:
+harness loads ``bench/reference/<name>.py`` and uses four functions:
 
   model_config(spec)             the program's ModelConfig for the file's sizes
   init_weights(spec, seed, dtype) seeded weights in the program's parameter
@@ -10,6 +10,24 @@ harness loads ``bench/reference/<name>.py`` and uses three functions:
                                  float32 logits [b, s, vocab] in plain jnp at
                                  "highest" matmul precision: no kernels, no
                                  cache, no paging, one full causal pass
+  work_model(spec)               the model's work as plain data, from which
+                                 ``bench/work.py`` counts a window's
+                                 operations and bytes (``mfu`` and the
+                                 kernels' roofline shares):
+      "linear": one entry per projection site: {"name", "k", "n" (its
+          K x N), "layers" (how many layers hold it), "token_share" (the
+          share of each layer's tokens that pass through it: 1 for a dense
+          projection, top_k * held / n_experts for routed experts held on
+          this chip), "matrices" (weight matrices one call may read: 1, or
+          the experts held), "quantized" (int8 in a MUXQ configuration)}
+      "attention": one entry per kind of attention layer: {"layers",
+          "heads" (query heads), "qk_dim" and "v_dim" (the widths a query
+          scores keys over and sums values over, per head; a latent shared
+          by all heads counts at its own width), "kv_bytes" ({kv_mode:
+          cached bytes per position and layer} for each page mode the
+          configuration may use), "window" (None, or the positions a query
+          at p sees: min(p + 1, window))}
+      "head": {"width", "vocab"}: the LM head over the vocabulary held
 
 ``bits=8`` or ``bits=4`` is the control: every linear layer's input is
 rounded per token and its weight per output channel to that many bits,
@@ -41,6 +59,29 @@ def sizes(spec):
                 dh=d // spec["n_head"], f=spec.get("n_inner") or 4 * d,
                 V=spec["vocab_size"], Vp=-(-spec["vocab_size"] // 128) * 128,
                 eps=spec["layer_norm_epsilon"])
+
+
+def work_model(spec):
+    """GPT-2's work: four dense projections in every layer (QKV fused, all
+    quantized under MUXQ), full multi-head attention over K/V pages (bf16
+    values for ``fp``; int8 values and a float32 scale per head and
+    position for ``int8``; packed int4 values and a bfloat16 scale for
+    ``int4``), and the LM head over the whole vocabulary."""
+    s = sizes(spec)
+    L, d, h, dh, f = s["L"], s["d"], s["h"], s["dh"], s["f"]
+    dense = dict(layers=L, token_share=1.0, matrices=1, quantized=True)
+    return {
+        "linear": [dict(name="attn_qkv", k=d, n=3 * d, **dense),
+                   dict(name="attn_out", k=d, n=d, **dense),
+                   dict(name="mlp_up", k=d, n=f, **dense),
+                   dict(name="mlp_down", k=f, n=d, **dense)],
+        "attention": [{"layers": L, "heads": h, "qk_dim": dh, "v_dim": dh,
+                       "kv_bytes": {"fp": 2 * h * 2 * dh,
+                                    "int8": 2 * h * (dh + 4),
+                                    "int4": 2 * h * (dh // 2 + 2)},
+                       "window": None}],
+        "head": {"width": d, "vocab": s["V"]},
+    }
 
 
 def model_config(spec):

@@ -22,7 +22,9 @@ read by the one generator in ``gen.py``).  The run:
      program;
   3. with ``--trace 1``, a profiler trace of the window and the scheduler's
      own step records, reduced by ``tracefile.py`` and read by one file per
-     per-layer metric in ``bench/metrics/``;
+     per-layer metric in ``bench/metrics/``, beside the window's operations
+     and bytes, which ``work.py`` counts from the reference's description
+     of the model (``work_model``);
   4. the check: once the window is closed, memory read and the program's
      state freed, a seeded sample of the served requests, with the
      longest, is run through the plain reference (``bench/reference/``) in
@@ -65,6 +67,7 @@ for p in (str(ROOT / "src"), str(BENCH)):
 import numpy as np  # noqa: E402
 
 import gen  # noqa: E402
+import hosttrace  # noqa: E402
 import tracefile  # noqa: E402
 import warm  # noqa: E402
 import work  # noqa: E402
@@ -86,7 +89,8 @@ REFERENCE_ROWS = 4
 # ServeMetrics counters the window's per-layer metrics read
 COUNTERS = ("decode_steps", "decode_slot_steps", "prefill_steps",
             "prefill_chunk_tokens", "prefills", "completed", "kv_bytes_read",
-            "prefix_hits", "cow_copies")
+            "prefix_hits", "cow_copies", "attn_pages_bucket",
+            "attn_pages_live")
 
 
 def log(msg: str) -> None:
@@ -226,13 +230,15 @@ class CompileCounter:
             self.compiled += 1
 
 
-def set_up(spec, traffic, trace: bool, cache: Path):
-    """The warmed engine of a configuration, with what its windows need."""
+def set_up(spec, traffic, trace: bool, cache: Path, root: Path = ROOT):
+    """The warmed engine of a configuration, with what its windows need;
+    the configuration's reference is ``bench/reference/<name>.py`` under
+    ``root``."""
     from repro.launch.compile_cache import enable_compile_cache
     from repro.obs.trace import TraceRecorder
     log(f"compile cache: {enable_compile_cache()}")
     compiles = CompileCounter()
-    ref = load_module(BENCH / "reference" / f"{spec['reference']}.py")
+    ref = load_module(root / "bench" / "reference" / f"{spec['reference']}.py")
     recorder = TraceRecorder(capacity=1 << 20) if trace else None
     eng = build_engine(spec, ref.model_config(spec), ref, recorder, cache)
     t0 = time.perf_counter()
@@ -489,9 +495,10 @@ def check(spec, judge, streams, reqs_spec, seed: int, control=False):
 # per-layer metrics of a traced window
 # ---------------------------------------------------------------------------
 
-def window_work(spec, s, w, window, reqs_spec):
-    """Operations and bytes of the window's served work (``work.py``):
-    a token after a request's first was decoded over its context."""
+def window_work(spec, model, w, window, reqs_spec):
+    """Operations and bytes of the window's served work, counted by
+    ``work.py`` from the reference's description ``model``: a token after
+    a request's first was decoded over its context."""
     import jax.numpy as jnp
     decode_keys = [reqs_spec[i]["prompt_tokens"] + k
                    for i, st in enumerate(window) for k, _, _ in st if k >= 1]
@@ -499,29 +506,42 @@ def window_work(spec, s, w, window, reqs_spec):
               if e["name"] == "CHUNK"]
     c = w.counters
     return work.window(
-        s, kv_mode=spec["serve"]["kv_mode"], quantized=bool(spec.get("quant")),
-        out_bytes=jnp.dtype(spec["dtype"]).itemsize,
+        model, kv_mode=spec["serve"]["kv_mode"],
+        quantized=bool(spec.get("quant")),
+        dtype_bytes=jnp.dtype(spec["dtype"]).itemsize,
         decode_steps=c["decode_steps"], decode_slot_steps=c["decode_slot_steps"],
         prefill_steps=c["prefill_steps"], prefill_tokens=c["prefill_chunk_tokens"],
         prefills=c["prefills"], decode_keys=decode_keys, chunks=chunks)
 
 
+def breakdown(ctx):
+    """The device ops with the most self time, and the longest idle gaps
+    between programs, each named by the program before it and by the host
+    span that covers most of it (``after jit_decode: sched.decode.wait``)
+    where the profile has the spans."""
+    gaps = [list(g) for g in ctx.device["idle_gaps"]]
+    trace = hosttrace.window(ctx)
+    if trace is not None:
+        hosttrace.label_gaps(gaps, trace)
+    return {"device_ops": tracefile.top_ops(ctx.device), "idle_gaps": gaps}
+
+
 def per_layer(spec, ref, readers, peaks, w, window, reqs_spec, trace_dir):
-    """(metrics, busy and window seconds, breakdown) of a traced window."""
+    """(metrics, busy and window seconds, breakdown) of a traced window.
+    Each reader gets one context: the window's counters and step records,
+    the reduced trace and its directory, the peaks, the configuration, and
+    the work counted from the reference's description."""
     reduced = tracefile.reduce(tracefile.extract(str(trace_dir)), w.traced_s)
-    s = ref.sizes(spec)
     ctx = types.SimpleNamespace(
         counters=w.counters, events=w.events, device=reduced, peaks=peaks,
-        spec=spec, sizes=s, n_slots=spec["serve"]["max_batch"],
-        work=window_work(spec, s, w, window, reqs_spec))
+        spec=spec, n_slots=spec["serve"]["max_batch"], trace_dir=trace_dir,
+        work=window_work(spec, ref.work_model(spec), w, window, reqs_spec))
     metrics = {}
     for name, unit, mod in readers:
         value = mod.read(ctx)
         if value is not None:
             metrics[name] = {"value": float(value), "unit": unit}
-    breakdown = {"device_ops": tracefile.top_ops(reduced),
-                 "idle_gaps": reduced["idle_gaps"]}
-    return metrics, reduced, breakdown
+    return metrics, reduced, breakdown(ctx)
 
 
 # ---------------------------------------------------------------------------
@@ -538,7 +558,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     readers = per_layer_readers(root, bench) if trace else []
     dev, peaks = device_info(cell["chips"], require_tpu)
     import jax
-    s = set_up(spec, traffic, trace, cache)
+    s = set_up(spec, traffic, trace, cache, root)
     reqs_spec = gen.requests(traffic, seed)
     trace_dir = None
     if trace:

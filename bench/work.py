@@ -1,65 +1,108 @@
-"""Operations and bytes the model needs, counted from its sizes.
+"""Operations and bytes a serving window needs, counted from the model's
+own description of its work.
 
 Counted from what the work requires, never from the operands a kernel
 happens to be handed: idle slots, chunk padding, K padding and the walk
 over a whole page bucket are not work, so they show up as lost roofline
 share.  One multiply-add is two operations.
 
-``sizes`` is :func:`bench.reference.<name>.sizes` of a configuration:
-L layers, width d, h heads of dh, MLP width f, vocabulary V.
+The description is ``work_model(spec)`` of the configuration's reference
+(``bench/reference/<name>.py``; the fields are set out in the reference
+contract at the top of ``gpt2.py``): its projection sites ("linear"), its
+kinds of attention layer ("attention") and its LM head ("head").  Nothing
+here knows a model's shape.
 """
 from __future__ import annotations
 
-from typing import Dict, Iterable, Tuple
+from typing import Dict, Iterable, Optional, Tuple
+
+# an int8 site's weights and rows: one byte a value, and a float32 scale
+INT8_BYTES, SCALE_BYTES = 1.0, 4.0
 
 
-def linear_shapes(s) -> Tuple[Tuple[int, int], ...]:
-    """(K, N) of the four projections of one layer: QKV, attention output,
-    MLP up, MLP down."""
-    d, f = s["d"], s["f"]
-    return ((d, 3 * d), (d, d), (d, f), (f, d))
+def linear_weights(sites) -> float:
+    """Weights one token passes through in ``sites`` (the description's
+    "linear" entries) across their layers, each site weighed by the share
+    of tokens that reach it."""
+    return sum(s["layers"] * s["token_share"] * s["k"] * s["n"] for s in sites)
 
 
-def linear_weights(s) -> int:
-    """Matrix weights of all layers' projections (embedding excluded)."""
-    return s["L"] * sum(k * n for k, n in linear_shapes(s))
+def linear_bytes(sites, *, calls: int, rows: int, quantized: bool,
+                 dtype_bytes: float) -> float:
+    """Bytes of ``calls`` passes through every layer of ``sites`` over
+    ``rows`` tokens in all.  Each call reads each weight matrix a site may
+    read; each row that reaches a site is read once and written once (in
+    the configuration's dtype).  In a ``quantized`` configuration a site
+    the description marks quantized is int8, with a float32 scale per
+    output channel of a weight and per row; otherwise the weights and rows
+    are in the configuration's dtype."""
+    total = 0.0
+    for s in sites:
+        wb, sb = ((INT8_BYTES, SCALE_BYTES) if quantized and s["quantized"]
+                  else (dtype_bytes, 0.0))
+        k, n = s["k"], s["n"]
+        per_call = s["matrices"] * (k * n * wb + n * sb)
+        per_row = s["token_share"] * (k * wb + n * dtype_bytes + sb)
+        total += s["layers"] * (calls * per_call + rows * per_row)
+    return total
 
 
-def linear_bytes(s, *, calls: int, rows: int, weight_bytes: float,
-                 act_bytes: float, out_bytes: float,
-                 scale_bytes: float = 0.0) -> float:
-    """Bytes of ``calls`` passes through every layer's four projections
-    over ``rows`` tokens in all: each call reads every weight and, where
-    the weights are quantized, one scale per output channel; each row is
-    read once, written once, and carries one scale."""
-    per_call = sum(k * n * weight_bytes + n * scale_bytes
-                   for k, n in linear_shapes(s))
-    per_row = sum(k * act_bytes + n * out_bytes + scale_bytes
-                  for k, n in linear_shapes(s))
-    return s["L"] * (calls * per_call + rows * per_row)
+def _kv_bytes(group, kv_mode: str) -> float:
+    if kv_mode not in group["kv_bytes"]:
+        raise ValueError(f"the model's description gives no cached bytes "
+                         f"for kv_mode {kv_mode!r}")
+    return group["kv_bytes"][kv_mode]
 
 
-def kv_bytes_per_token(s, kv_mode: str) -> float:
-    """Bytes of one cached position across all layers: K and V of every
-    head, and for int8 pages one float32 scale per head and position each."""
-    per_head = {"int8": s["dh"] + 4, "fp": 2 * s["dh"]}[kv_mode]
-    return s["L"] * 2 * s["h"] * per_head
+def kv_bytes_per_token(model, kv_mode: str) -> float:
+    """Bytes of one cached position across all attention layers."""
+    return sum(g["layers"] * _kv_bytes(g, kv_mode) for g in model["attention"])
 
 
-def attention_ops(s, keys: float) -> float:
-    """Operations of one query attending ``keys`` positions in every layer
-    (scores and the weighted sum of values)."""
-    return 4.0 * s["L"] * s["h"] * s["dh"] * keys
+def _ops_per_key(group) -> float:
+    """Operations of one query and one key in every layer of a group:
+    its score and its share of the weighted sum of values."""
+    return 2.0 * group["layers"] * group["heads"] * (group["qk_dim"]
+                                                     + group["v_dim"])
 
 
-def chunk_keys(n: int, done: int) -> float:
+def _capped(keys: float, window: Optional[int]) -> float:
+    return keys if window is None else min(keys, window)
+
+
+def attention_ops(model, keys: float) -> float:
+    """Operations of one query that has ``keys`` positions before it
+    (itself included), in every layer: a windowed layer attends at most
+    its window."""
+    return sum(_ops_per_key(g) * _capped(keys, g["window"])
+               for g in model["attention"])
+
+
+def _sum_capped(a: int, b: int, window: Optional[int]) -> float:
+    """The sum of min(j, window) over j = a .. b (a >= 1)."""
+    top = b if window is None else max(a - 1, min(b, window))
+    return (top - a + 1) * (a + top) / 2.0 + (b - top) * (window or 0)
+
+
+def chunk_keys(n: int, done: int, window: Optional[int] = None) -> float:
     """Keys attended, summed over the queries of a prefill chunk of ``n``
-    tokens that ends at position ``done`` (query at p sees p + 1 keys)."""
-    first = done - n
-    return n * first + n * (n + 1) / 2.0
+    tokens that ends at position ``done`` (query at p sees p + 1 keys, at
+    most ``window``)."""
+    return _sum_capped(done - n + 1, done, window)
 
 
-def window(s, *, kv_mode: str, quantized: bool, out_bytes: float,
+def chunk_positions(n: int, done: int, window: Optional[int] = None) -> int:
+    """Cached positions a prefill chunk of ``n`` tokens ending at ``done``
+    reads: every one before it, or only those some query's window holds."""
+    return done if window is None else min(done, n - 1 + window)
+
+
+def head_ops(model) -> float:
+    """Operations of the LM head for one sampled position."""
+    return 2.0 * model["head"]["width"] * model["head"]["vocab"]
+
+
+def window(model, *, kv_mode: str, quantized: bool, dtype_bytes: float,
            decode_steps: int, decode_slot_steps: int, prefill_steps: int,
            prefill_tokens: int, prefills: int, decode_keys: Iterable[int],
            chunks: Iterable[Tuple[int, int]]) -> Dict[str, float]:
@@ -69,30 +112,33 @@ def window(s, *, kv_mode: str, quantized: bool, out_bytes: float,
     ``chunks``: (tokens, end position) of each prefill chunk.  Linear work
     is one call per decode step over its active slots and one per prefill
     step over its valid chunk tokens; attention reads each live position's
-    K/V once per call; the LM head runs for every sampled position (each
-    decode token and the last prompt token of each finished prefill)."""
-    wb, ab, sb = (1.0, 1.0, 4.0) if quantized else (2.0, 2.0, 0.0)
+    cached bytes once per call; the LM head runs for every sampled
+    position (each decode token and the last prompt token of each
+    finished prefill).  ``linear_*`` count every site, ``quantized_*`` the
+    int8 ones (nothing in an unquantized configuration)."""
     tokens = decode_slot_steps + prefill_tokens
-    lin_bytes = sum(linear_bytes(s, calls=calls, rows=rows, weight_bytes=wb,
-                                 act_bytes=ab, out_bytes=out_bytes,
-                                 scale_bytes=sb)
-                    for calls, rows in ((decode_steps, decode_slot_steps),
-                                        (prefill_steps, prefill_tokens)))
-    bpt = kv_bytes_per_token(s, kv_mode)
     decode_keys = list(decode_keys)
     chunks = list(chunks)
-    att_keys = float(sum(decode_keys)) + sum(chunk_keys(n, e) for n, e in chunks)
-    att_bytes = bpt * (sum(decode_keys) + sum(e for _, e in chunks))
+    out = {"attention_ops": 0.0, "attention_bytes": 0.0}
+    int8 = [s for s in model["linear"] if quantized and s["quantized"]]
+    for key, sites in (("linear", model["linear"]), ("quantized", int8)):
+        out[f"{key}_ops"] = 2.0 * linear_weights(sites) * tokens
+        out[f"{key}_bytes"] = sum(
+            linear_bytes(sites, calls=calls, rows=rows, quantized=quantized,
+                         dtype_bytes=dtype_bytes)
+            for calls, rows in ((decode_steps, decode_slot_steps),
+                                (prefill_steps, prefill_tokens)))
+    for g in model["attention"]:
+        win = g["window"]
+        decode = sum(_capped(k, win) for k in decode_keys)
+        keys = decode + sum(chunk_keys(n, e, win) for n, e in chunks)
+        positions = decode + sum(chunk_positions(n, e, win) for n, e in chunks)
+        out["attention_ops"] += _ops_per_key(g) * keys
+        out["attention_bytes"] += g["layers"] * _kv_bytes(g, kv_mode) * positions
     sampled = len(decode_keys) + prefills
-    head_ops = 2.0 * s["d"] * s["V"] * sampled
-    return {
-        "linear_ops": 2.0 * linear_weights(s) * tokens,
-        "linear_bytes": lin_bytes,
-        "attention_ops": attention_ops(s, att_keys),
-        "attention_bytes": att_bytes,
-        "model_ops": (2.0 * linear_weights(s) * tokens
-                      + attention_ops(s, att_keys) + head_ops),
-    }
+    out["model_ops"] = (out["linear_ops"] + out["attention_ops"]
+                        + head_ops(model) * sampled)
+    return out
 
 
 def roofline_s(ops: float, bytes_: float, ops_peak: float, bw: float) -> float:

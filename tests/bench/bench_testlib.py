@@ -1,6 +1,7 @@
 """Shared set-up for the benchmark's own tests: a benchmark root in a
-temporary directory that holds test-size configurations and mixes beside
-the real ones, so the harness runs end to end on the CPU."""
+temporary directory that holds test-size configurations, mixes and
+references beside the real ones, so the harness runs end to end on the
+CPU."""
 from __future__ import annotations
 
 import json
@@ -25,6 +26,10 @@ def make_root(tmp: Path, cells=CELLS) -> Path:
     """A benchmark root with BENCHMARK.json's metrics and the test cells."""
     shutil.copytree(BENCH / "traffic", tmp / "bench" / "traffic")
     shutil.copytree(BENCH / "metrics", tmp / "bench" / "metrics")
+    shutil.copytree(BENCH / "reference", tmp / "bench" / "reference",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    for f in DATA.glob("*.py"):     # the test-size references
+        shutil.copy(f, tmp / "bench" / "reference" / f.name)
     (tmp / "configs").mkdir()
     for f in DATA.glob("tiny-*.json"):
         dest = "traffic" if "chat" in f.name or "docqa" in f.name else None

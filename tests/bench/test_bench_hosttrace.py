@@ -30,9 +30,14 @@ def _window_s(trace):
     return (max(ends) - min(r["start_ns"] for r in trace["records"])) * 1e-9
 
 
+# where the harness traced the window (the tests stand a recorded trace in
+# for what ``hosttrace.load`` finds there)
+TRACE_DIR = "cache/trace/chat-bf16"
+
+
 def _ctx(trace):
     return types.SimpleNamespace(
-        spec={"name": "gpt2-large-bf16"},
+        spec={"name": "gpt2-large-bf16"}, trace_dir=TRACE_DIR,
         device=tracefile.reduce(trace["records"], _window_s(trace)))
 
 
@@ -164,7 +169,7 @@ def test_crossed_clock_bounds_read_nothing(monkeypatch):
     labels = [g[0] for g in ctx.device["idle_gaps"]]
     assert _read("device.idle_named_share", ctx) is None
     assert _read("device.wait_idle_ms_per_call", ctx) is None
-    assert [g[0] for g in ctx.device["idle_gaps"]] == labels
+    assert [g[0] for g in run.breakdown(ctx)["idle_gaps"]] == labels
 
 
 def test_gap_labels_name_a_span_after_the_program():
@@ -231,8 +236,11 @@ def test_readers(loaded):
         hosttrace.idle_named_share(loaded))
     assert _read("device.wait_idle_ms_per_call", ctx) == pytest.approx(
         hosttrace.wait_idle_ms_per_call(loaded))
-    # the breakdown's gaps now carry their spans
-    assert all(": sched." in g[0] for g in ctx.device["idle_gaps"])
+    # the readers only read; the harness names the breakdown's gaps, once
+    assert not any(": sched." in g[0] for g in ctx.device["idle_gaps"])
+    gaps = run.breakdown(ctx)["idle_gaps"]
+    assert all(": sched." in g[0] for g in gaps)
+    assert [g[1] for g in gaps] == [g[1] for g in ctx.device["idle_gaps"]]
 
 
 def test_readers_on_a_program_without_spans_or_scope(monkeypatch):
@@ -248,7 +256,7 @@ def test_readers_on_a_program_without_spans_or_scope(monkeypatch):
     ctx = _ctx(trace)
     labels = [g[0] for g in ctx.device["idle_gaps"]]
     assert all(_read(name, ctx) is None for name in NEW)
-    assert [g[0] for g in ctx.device["idle_gaps"]] == labels
+    assert [g[0] for g in run.breakdown(ctx)["idle_gaps"]] == labels
 
 
 def test_a_profile_of_other_programs_is_not_read(loaded):
@@ -259,9 +267,9 @@ def test_a_profile_of_other_programs_is_not_read(loaded):
 
 
 def test_only_the_cells_own_profile_is_read(monkeypatch):
-    """Without a trace directory in the context, the profile is looked
-    for under the trace directory of the cell that runs the context's
-    configuration, never under another cell's."""
+    """The profile is looked for only in the directory the harness traced
+    the window into (the context's ``trace_dir``): without one nothing is
+    read, whatever other cells left."""
     trace = _trace()
     asked = []
 
@@ -270,13 +278,11 @@ def test_only_the_cells_own_profile_is_read(monkeypatch):
         return trace
     monkeypatch.setattr(hosttrace, "load", load)
     ctx = _ctx(trace)
-    assert hosttrace.trace_dirs(ctx) == [hosttrace.TRACE_ROOT / "chat-bf16"]
     assert hosttrace.window(ctx) is trace
-    assert asked == [str(hosttrace.TRACE_ROOT / "chat-bf16")]
-    ctx.spec = {"name": "no-such-configuration"}
+    assert asked == [TRACE_DIR]
+    ctx.trace_dir = None
     assert hosttrace.window(ctx) is None
-    del ctx.spec
-    assert hosttrace.window(ctx) is None
+    assert all(_read(name, ctx) is None for name in NEW)
     assert len(asked) == 1
 
 
