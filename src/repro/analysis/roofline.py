@@ -72,16 +72,21 @@ def param_count(cfg: ModelConfig, active_only: bool = False) -> int:
     h, kv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     attn = d * (h + 2 * kv) * dh + h * dh * d
     mlp = d * 2 * f + f * d if cfg.mlp_type == "swiglu" else 2 * d * f
+    if cfg.is_mla:
+        r, nope, rope, v = (cfg.kv_lora_rank, cfg.qk_nope_dim,
+                            cfg.qk_rope_dim, cfg.v_head_dim)
+        attn = d * (h * (nope + rope) + r + rope) + r * h * (nope + v) + h * v * d
+    fe, fs = cfg.d_expert, cfg.d_ff_shared
     n = 0
-    for kind in cfg.blocks:
+    for kind, ffn in zip(cfg.blocks, cfg.ffn_kinds):
         if kind == "mamba":
             di, ns, hs = cfg.d_inner, cfg.ssm_state, cfg.n_ssm_heads
             n += d * 2 * di + d * (2 * ns + hs) + di * d
-        elif kind == "moe":
+        elif ffn == "experts":
             e = cfg.top_k if active_only else cfg.n_experts
-            n += attn + e * (d * 2 * f + f * d)
-            if cfg.shared_expert:
-                n += d * 2 * f + f * d
+            n += attn + e * (d * 2 * fe + fe * d)
+            if fs:
+                n += d * 2 * fs + fs * d
         else:
             n += attn + mlp
     if cfg.shared_attn_every:  # zamba2 shared block counts once (weights shared)

@@ -4,7 +4,7 @@ from repro.models.common import ModelConfig
 
 CONFIG = ModelConfig(
     name="dbrx-132b", n_layers=40, d_model=6144, n_heads=48, n_kv_heads=8,
-    d_ff=10752, vocab_size=100352, block_pattern=("moe",),
+    d_ff=10752, vocab_size=100352, block_pattern=("attn",),
     n_experts=16, top_k=4, mlp_type="swiglu", norm="rmsnorm",
     tie_embeddings=False,
 )

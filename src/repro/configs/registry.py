@@ -15,6 +15,7 @@ ARCHS = {
     "whisper-tiny": "repro.configs.whisper_tiny",
     "llama4-scout-17b-a16e": "repro.configs.llama4_scout_17b",
     "dbrx-132b": "repro.configs.dbrx_132b",
+    "moonlight-16b-a3b": "repro.configs.moonlight_16b_a3b",
     "mamba2-370m": "repro.configs.mamba2_370m",
     "zamba2-1.2b": "repro.configs.zamba2_1_2b",
     "gpt2-small": "repro.configs.gpt2",

@@ -73,6 +73,18 @@ def _dense_w(w, dtype):
     return w.astype(dtype)
 
 
+def _ragged(x, w, rows):
+    """Rows of x through their expert's weight (rows past the groups: 0)."""
+    import jax
+    return jax.lax.ragged_dot(x, w, rows["group_sizes"])
+
+
+def _row_expert(rows, m: int) -> jnp.ndarray:
+    """[M] held expert of each row of a grouped layout."""
+    bm = m // rows["tile_expert"].shape[0]
+    return jnp.repeat(rows["tile_expert"], bm, total_repeat_length=m)
+
+
 def _prequant_matmul(x, w, cfg: QuantConfig, mask=None):
     """x (fp) @ pre-quantized int8 weight: per-token int8 activations,
     INT GEMM, fused dequant.  MUXQ rides as the exact int32 channel
@@ -102,6 +114,13 @@ class FpCtx:
         """Per-expert matmul: x [e, c, d] @ w [e, d, f] -> [e, c, f]."""
         return jnp.einsum("ecd,edf->ecf", x, _dense_w(w, x.dtype))
 
+    def gmm(self, name: str, x: jnp.ndarray, w, rows, mask=None,
+            smooth=None, fused=None):
+        """Grouped matmul over expert row tiles: x [M, d] whose rows are
+        sorted by expert (``rows``: :func:`repro.models.moe.expert_rows`)
+        @ w [e, d, f] -> [M, f]."""
+        return _ragged(x, _dense_w(w, x.dtype), rows)
+
 
 class CollectCtx:
     """Calibration pass: record per-channel |x| stats at every site."""
@@ -125,6 +144,16 @@ class CollectCtx:
             raise RuntimeError("CollectCtx must run eagerly (not under jit/scan)")
         self.stats.update(name, x.reshape(-1, x.shape[-1]))
         return jnp.einsum("ecd,edf->ecf", x, _dense_w(w, x.dtype))
+
+    def gmm(self, name: str, x: jnp.ndarray, w, rows, mask=None,
+            smooth=None, fused=None):
+        import jax
+        if isinstance(x, jax.core.Tracer):  # pragma: no cover - guarded misuse
+            raise RuntimeError("CollectCtx must run eagerly (not under jit/scan)")
+        # only the rows of routed tokens: padding rows are not activations
+        used = np.asarray(rows["row_token"]) < rows["pair_row"].shape[0]
+        self.stats.update(name, np.asarray(x)[used] if used.any() else x)
+        return _ragged(x, _dense_w(w, x.dtype), rows)
 
 
 class QuantCtx:
@@ -275,6 +304,40 @@ class QuantCtx:
         fn = lambda xe, we: qmatmul(xe, we.astype(x.dtype), cfg,
                                     mask=mask, smooth=hint)
         return jax.vmap(fn)(x, w)
+
+    def gmm(self, name: str, x: jnp.ndarray, w, rows, mask=None,
+            smooth=None, fused=None):
+        """Quantized grouped matmul over expert row tiles (see
+        ``FpCtx.gmm``).  Fused sites run the ``muxq_gemm`` kernel's expert
+        axis; the other backends run the per-expert :meth:`emm` on every
+        row and keep each row's own expert (per-token activation
+        quantization makes a row's result independent of the others)."""
+        cfg = self.policy.resolve(name)
+        backend = dispatch.site_backend(cfg)
+        if backend == "fp":
+            self.backend_log[name] = backend
+            return _ragged(x, _dense_w(w, x.dtype), rows)
+        if backend == "fused":
+            self.backend_log[name] = backend
+            mask, factor, _ = self._site(name, cfg, mask, smooth)
+            if cfg.method in _SMOOTH_METHODS:
+                if factor is None:
+                    raise RuntimeError(
+                        f"site {name!r}: method {cfg.method!r} on the fused "
+                        "backend needs folded smooth factors")
+                x = (x / factor).astype(x.dtype)
+                cfg = self._smooth_base(cfg)
+            self._observe(name, x, cfg, mask)
+            buf = self._fused_buffer(name, fused)
+            return dispatch.fused_gmm(x, buf, rows,
+                                      act_bits=cfg.act_bits).astype(x.dtype)
+        e = rows["group_sizes"].shape[0]
+        full = self.emm(name, jnp.broadcast_to(x, (e,) + x.shape), w,
+                        mask=mask, smooth=smooth, fused=fused)   # [e, M, f]
+        own = _row_expert(rows, x.shape[0])
+        y = jnp.take_along_axis(full, own[None, :, None], axis=0)[0]
+        used = jnp.arange(x.shape[0]) < jnp.sum(rows["group_sizes"])
+        return jnp.where(used[:, None], y, 0).astype(x.dtype)
 
 
 def as_ctx(quant) -> Tuple[object, Optional[Dict[str, jnp.ndarray]]]:

@@ -28,16 +28,17 @@ import numpy as np
 
 from repro.core import quantizers as Q
 from repro.core.policy import SitePolicy
-from repro.models.common import ModelConfig
+from repro.models.common import GROUP_SITE_PREFIX, ModelConfig
 
 # site weight leaves eligible for offline int8 (matmul right-hand sides)
 _WEIGHT_RE = re.compile(
-    r"(attn/(wqkv|wo)|cross/(wq|wkv|wo)|mlp/(wi|wo)|moe/(wi|wo)"
+    r"(attn/(wqkv|wq|wkpe|wo)|cross/(wq|wkv|wo)|mlp/(wi|wo)|moe/(wi|wo)"
     r"|ssm/(in_zx|in_bcdt|out_proj))$")
 
 # weight-path suffix -> the ctx site base name it is consumed under
 _SITE_BY_SUFFIX = {
     "attn/wqkv": "attn_qkv", "attn/wo": "attn_out",
+    "attn/wq": "attn_q", "attn/wkpe": "attn_kpe",
     "cross/wq": "cross_q", "cross/wkv": "cross_kv", "cross/wo": "cross_out",
     "mlp/wi": "mlp_up", "mlp/wo": "mlp_down",
     "moe/wi": "moe_up", "moe/wo": "moe_down",
@@ -57,14 +58,16 @@ def site_for_path(pathstr: str) -> Optional[str]:
 def _layer_prefix_format(pathstr: str) -> Optional[str]:
     """Eager site-name prefix format for a stacked leaf, e.g. 'layer{}/'.
 
-    Only the decoder stack ('layers') and encoder stack ('enc_layers') have
-    a 1:1 (stack index -> eager site prefix) mapping; the hybrid shared
-    block is executed at several positions with ONE weight, so per-instance
-    factors cannot be folded into it."""
+    Only the decoder stacks (the layer groups 'layers', 'dense_layers') and
+    the encoder stack ('enc_layers') have a 1:1 (stack index -> eager site
+    prefix) mapping; the hybrid shared block is executed at several
+    positions with ONE weight, so per-instance factors cannot be folded
+    into it."""
     if pathstr.startswith("enc_layers/"):
         return "enc{}/"
-    if pathstr.startswith("layers/"):
-        return "layer{}/"
+    for key, kind in GROUP_SITE_PREFIX.items():
+        if pathstr.startswith(key + "/"):
+            return kind + "{}/"
     return None
 
 

@@ -44,8 +44,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ops
-from repro.kernels.muxq_gemm import pick_bn
+from repro.kernels import ops, ref
+from repro.kernels.muxq_gemm import muxq_gemm_grouped, pick_bn
 
 Backend = Literal["fused", "fake", "fp"]
 FusedImpl = Literal["auto", "pallas", "interpret", "ref"]
@@ -239,3 +239,34 @@ def fused_emm(x: jnp.ndarray, buf, *, act_bits: int = 8,
         return ops.muxq_linear_ref(xe, mw, act_bits=act_bits)
 
     return jax.vmap(one)(x, buf["w_int"], buf["sw"])
+
+
+def fused_gmm(x: jnp.ndarray, buf, rows, *, act_bits: int = 8,
+              impl: Optional[str] = None) -> jnp.ndarray:
+    """Grouped fused matmul: x [M, K] rows sorted by expert in whole tiles
+    (``repro.models.moe.expert_rows``) @ a buffer with [E, ...] weight
+    leaves -> [M, N] float32.  The outlier permutation is shared by the
+    experts, so the rows are permuted and quantized per token once; the
+    int8 GEMM is the ``muxq_gemm`` kernel's expert axis (the tile's expert
+    rides scalar prefetch), or the jnp oracle as one integer
+    ``ragged_dot``.  Rows of empty tiles are left unwritten by the kernel
+    and are never read back."""
+    impl = impl or fused_impl()
+    mw = as_muxq_weights(buf)
+    m = x.shape[0]
+    bm = m // rows["tile_expert"].shape[0]
+    body = ops._permute_pad_shift(x, mw)
+    if impl == "ref":
+        x_int, sx = ref.rowwise_quantize_ref(body, act_bits)
+        chan = jnp.repeat(mw.block_scale, mw.bk)
+        acc = jax.lax.ragged_dot(x_int.astype(jnp.int32) * chan[None],
+                                 mw.w_int.astype(jnp.int32),
+                                 rows["group_sizes"])
+        own = jnp.repeat(rows["tile_expert"], bm, total_repeat_length=m)
+        return acc.astype(jnp.float32) * sx * mw.sw[own, 0]
+    interpret = impl == "interpret"
+    x_int, sx = ops.rowwise_quantize(body, bits=act_bits, bm=min(128, m),
+                                     interpret=interpret)
+    return muxq_gemm_grouped(
+        x_int, mw.w_int, mw.block_scale, sx, mw.sw, rows["tile_expert"],
+        rows["n_used"], bm=bm, bk=mw.bk, interpret=interpret)

@@ -9,6 +9,12 @@ so *2^e stays far inside int32) before accumulation.  Aux GEMM cost: zero.
 Grid: (M/bm, N/bn, K/bk), K innermost ("arbitrary") with a VMEM int32
 accumulator; dequant (row scale x col scale) fused into the final K step.
 
+Expert axis (:func:`muxq_gemm_grouped`): the rows are routed tokens sorted
+by expert in whole row tiles, the weights ``[E, K, N]``; each row tile's
+expert rides scalar prefetch into the weight index map, so one call runs
+every expert's rows.  Tiles past the ones in use repeat the last block
+index (no copy) and skip the body.
+
 VMEM budget per step (defaults bm=bn=256, bk=512):
     x tile 256x512 int8 = 128 KiB, w tile 512x256 int8 = 128 KiB,
     acc 256x256 int32 = 256 KiB, out 256x256 bf16 = 128 KiB  << 16 MiB.
@@ -27,7 +33,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, w_ref, bs_ref, sx_ref, sw_ref, o_ref, acc_ref, *, nk: int):
-    @pl.when(pl.program_id(2) == 0)
+    _accumulate(x_ref, w_ref, bs_ref, sx_ref, sw_ref, o_ref, acc_ref,
+                pl.program_id(2), nk)
+
+
+def _accumulate(x_ref, w_ref, bs_ref, sx_ref, sw_ref, o_ref, acc_ref, kk,
+                nk: int):
+    @pl.when(kk == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -42,12 +54,23 @@ def _kernel(x_ref, w_ref, bs_ref, sx_ref, sw_ref, o_ref, acc_ref, *, nk: int):
     )
     # per-K-block exponent scaling: 2^exp on outlier blocks, 1 elsewhere
     # (the whole [K/bk] scale vector sits in SMEM; a scalar read per step)
-    acc_ref[...] += prod * bs_ref[pl.program_id(2)]
+    acc_ref[...] += prod * bs_ref[kk]
 
-    @pl.when(pl.program_id(2) == nk - 1)
+    @pl.when(kk == nk - 1)
     def _finalize():
         o_ref[...] = (acc_ref[...].astype(jnp.float32)
                       * sx_ref[...] * sw_ref[...]).astype(o_ref.dtype)
+
+
+def _grouped_kernel(te_ref, nu_ref, bs_ref, x_ref, w_ref, sx_ref, sw_ref,
+                    o_ref, acc_ref, *, nk: int):
+    kk = pl.program_id(2)
+
+    # tiles past the ones in use hold no rows: nothing to compute
+    @pl.when(pl.program_id(0) < nu_ref[0])
+    def _tile():
+        _accumulate(x_ref, w_ref, bs_ref, sx_ref, sw_ref, o_ref, acc_ref, kk,
+                    nk)
 
 
 LANE = 128
@@ -111,3 +134,64 @@ def muxq_gemm(x_int: jnp.ndarray, w_int: jnp.ndarray,
         name="muxq_gemm",
     )(x_int, w_int, block_scale, sx, sw)
     return out[:m] if pad_m else out
+
+
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+def muxq_gemm_grouped(x_int: jnp.ndarray, w_int: jnp.ndarray,
+                      block_scale: jnp.ndarray, sx: jnp.ndarray,
+                      sw: jnp.ndarray, tile_expert: jnp.ndarray,
+                      n_used: jnp.ndarray, *, bm: int, bn: int = 256,
+                      bk: int = 512, interpret: bool = False) -> jnp.ndarray:
+    """The expert axis of :func:`muxq_gemm`: Y[r] = dequant(X[r] @ W[e(r)])
+    where e(r) is the expert of row r's tile.
+
+    x_int [M, K] int8 (M a multiple of ``bm``: rows sorted by expert, each
+    expert's rows padded to whole tiles), w_int [E, K, N] int8,
+    block_scale [K/bk] int32 (one outlier permutation shared by the
+    experts), sx [M, 1] f32, sw [E, 1, N] f32, tile_expert [M/bm] int32,
+    n_used [1] int32 (tiles in use; rows of the others are not written).
+    Returns [M, N] float32.  Every tile in use reads its expert's weight
+    tiles once."""
+    m, k = x_int.shape
+    e, k2, n = w_int.shape
+    assert k == k2 and k % bk == 0 and m % bm == 0, (m, k, k2, bk, bm)
+    bn = pick_bn(n, bn)
+    nt, nj, nk = m // bm, n // bn, k // bk
+
+    def live(i, nu):
+        return i < nu[0]
+
+    def row(i, nu):
+        return jnp.where(live(i, nu), i, nu[0] - 1)
+
+    def col(i, j, nu):
+        return jnp.where(live(i, nu), j, nj - 1)
+
+    def kblk(i, kk, nu):
+        return jnp.where(live(i, nu), kk, nk - 1)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(nt, nj, nk),
+        in_specs=[
+            pl.BlockSpec((bm, bk), lambda i, j, kk, te, nu, bs: (
+                row(i, nu), kblk(i, kk, nu))),
+            pl.BlockSpec((None, bk, bn), lambda i, j, kk, te, nu, bs: (
+                te[row(i, nu)], kblk(i, kk, nu), col(i, j, nu))),
+            pl.BlockSpec((bm, 1), lambda i, j, kk, te, nu, bs: (
+                row(i, nu), 0)),
+            pl.BlockSpec((None, 1, bn), lambda i, j, kk, te, nu, bs: (
+                te[row(i, nu)], 0, col(i, j, nu))),
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, te, nu, bs: (
+            row(i, nu), col(i, j, nu))),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_grouped_kernel, nk=nk),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        interpret=interpret,
+        name="muxq_gemm",
+    )(tile_expert.astype(jnp.int32), n_used.astype(jnp.int32),
+      block_scale.astype(jnp.int32), x_int, w_int, sx, sw)

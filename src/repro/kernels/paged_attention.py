@@ -63,6 +63,13 @@ Execution selection mirrors ``repro.kernels.dispatch``:
 GQA rides in the block: each kv head attends all ``sq * h // kvh`` query
 rows of its group at once, so the broadcast KV never materializes (same
 trick as ``flash_attention``).
+
+**Latent pages** (``v_pages=None``, ``v_dim`` set): absorbed latent
+attention is attention with one KV head whose values are the first
+``v_dim`` columns of the key rows — one page array (and one scale per
+position) serves as both.  ``scale`` overrides the ``dh ** -0.5`` default
+(a latent's scores equal the expanded per-head dot products, scaled by
+the expanded width).
 """
 from __future__ import annotations
 
@@ -111,7 +118,9 @@ def paged_impl() -> str:
 def paged_attention_ref(q, k_pages, v_pages, page_table, pos, *,
                         k_scale=None, v_scale=None, k_redist=None,
                         v_redist=None, window=None,
-                        softcap: Optional[float] = None):
+                        softcap: Optional[float] = None,
+                        v_dim: Optional[int] = None,
+                        scale: Optional[float] = None):
     """Gather-then-attend reference.  q [b, h, dh] (decode) or
     [b, sq, h, dh] (verify block / prefill chunk); k/v_pages
     [n_pages, kvh, ps, dh] (+ optional [n_pages, kvh, ps, 1] int8 scales;
@@ -138,19 +147,27 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, pos, *,
         gp = gp.transpose(0, 1, 3, 2, 4)              # [b, P, ps, kvh, *]
         return gp.reshape(b, -1, *gp.shape[3:])
 
-    kk, vv = gather(k_pages), gather(v_pages)
-    if k_redist is not None:
+    if v_pages is None:
+        # latent pages: the values are the keys' first v_dim columns
+        kk = gather(k_pages)
+        if k_scale is not None:
+            kk = kk.astype(jnp.float32) * gather(k_scale)
+        kk = kk.astype(q.dtype)
+        vv = kk[..., :v_dim]
+    elif k_redist is not None:
         # int4: unpack nibbles, scale, undo the MUXQ magnitude shift
-        kk = (unpack_int4(kk).astype(jnp.float32)
+        kk = (unpack_int4(gather(k_pages)).astype(jnp.float32)
               * gather(k_scale).astype(jnp.float32) * k_redist).astype(q.dtype)
-        vv = (unpack_int4(vv).astype(jnp.float32)
+        vv = (unpack_int4(gather(v_pages)).astype(jnp.float32)
               * gather(v_scale).astype(jnp.float32) * v_redist).astype(q.dtype)
     elif k_scale is not None:
-        kk = (kk.astype(jnp.float32) * gather(k_scale)).astype(q.dtype)
-        vv = (vv.astype(jnp.float32) * gather(v_scale)).astype(q.dtype)
+        kk = (gather(k_pages).astype(jnp.float32)
+              * gather(k_scale)).astype(q.dtype)
+        vv = (gather(v_pages).astype(jnp.float32)
+              * gather(v_scale)).astype(q.dtype)
     else:
-        kk = kk.astype(q.dtype)
-        vv = vv.astype(q.dtype)
+        kk = gather(k_pages).astype(q.dtype)
+        vv = gather(v_pages).astype(q.dtype)
 
     window = NO_WINDOW if window is None else window
     kpos = jnp.arange(kk.shape[1])[None, None, :]     # [1, 1, P*ps]
@@ -159,7 +176,7 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, pos, *,
     bias = jnp.where(allow, 0.0, NEG_INF)[:, None, None].astype(jnp.float32)
 
     qg = q.reshape(b, sq, kvh, g, dh)                 # [b, sq, kv, g, dh]
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
     scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, kk).astype(jnp.float32) * scale
     if softcap is not None:
         scores = (softcap * jnp.tanh(scores.astype(jnp.float32) / softcap)
@@ -167,7 +184,7 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, pos, *,
     scores = scores + bias                            # [b,1,1,sq,S] bcast
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, vv)
-    out = out.reshape(b, sq, h, dh)
+    out = out.reshape(b, sq, h, vv.shape[-1])
     return out[:, 0] if squeeze else out
 
 
@@ -193,7 +210,7 @@ def _kernel(tab_ref, pos_ref, last_ref, win_ref,    # scalar prefetch
             kr_ref, vr_ref,                         # int4 redist rows (opt.)
             o_ref, m_ref, l_ref, acc_ref, *,
             scale: float, nj: int, ps: int, g: int, mode: str,
-            softcap: Optional[float]):
+            softcap: Optional[float], v_dim: Optional[int]):
     bb, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
@@ -210,7 +227,10 @@ def _kernel(tab_ref, pos_ref, last_ref, win_ref,    # scalar prefetch
     def _attend():
         q = q_ref[0].astype(jnp.float32)              # [kvh, sq*g, dh]
         k = _dequant(k_ref[0], ks_ref, kr_ref, mode)  # [kvh, ps, dh]
-        v = _dequant(v_ref[0], vs_ref, vr_ref, mode)
+        if v_dim is None:
+            v = _dequant(v_ref[0], vs_ref, vr_ref, mode)
+        else:                                         # latent: v in k's rows
+            v = k[:, :, :v_dim]
         # every head of the page at once: dots batched over the head axis
         s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale
@@ -248,6 +268,8 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, pos, *,
                            k_scale=None, v_scale=None, k_redist=None,
                            v_redist=None, window=None,
                            softcap: Optional[float] = None,
+                           v_dim: Optional[int] = None,
+                           scale: Optional[float] = None,
                            interpret: bool = False):
     """Pallas paged attention.  Same contract as
     :func:`paged_attention_ref`; the page table and per-slot start
@@ -277,7 +299,9 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, pos, *,
     mode = ("int4" if k_redist is not None
             else "int8" if k_scale is not None else "fp")
     assert pk_dh == (dh // 2 if mode == "int4" else dh), (pk_dh, dh, mode)
-    scale = dh ** -0.5
+    scale = dh ** -0.5 if scale is None else scale
+    latent = v_pages is None
+    vd = v_dim if latent else dh
 
     table = page_table.astype(jnp.int32)
     pos32 = pos.astype(jnp.int32)
@@ -305,9 +329,18 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, pos, *,
     inert_spec = pl.BlockSpec((1, 1, 1), whole)
     inert = jnp.zeros((1, 1, 1), jnp.float32)
 
-    in_specs = [q_spec, kv_spec, kv_spec]
-    args = [qg, k_pages, v_pages]
-    if mode in ("int8", "int4"):
+    in_specs = [q_spec, kv_spec]
+    args = [qg, k_pages]
+    if latent:                  # the values are read from the key block
+        in_specs.append(inert_spec)
+        args.append(inert)
+    else:
+        in_specs.append(kv_spec)
+        args.append(v_pages)
+    if latent and mode == "int8":
+        in_specs += [sc_spec, inert_spec]
+        args += [k_scale, inert]
+    elif mode in ("int8", "int4"):
         in_specs += [sc_spec, sc_spec]
         args += [k_scale, v_scale]
     else:
@@ -328,21 +361,21 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, pos, *,
         num_scalar_prefetch=4,
         grid=(b, nj),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, kvh, rows, dh), slot_index),
+        out_specs=pl.BlockSpec((1, kvh, rows, vd), slot_index),
         scratch_shapes=[pltpu.VMEM((kvh, rows, 1), jnp.float32),
                         pltpu.VMEM((kvh, rows, 1), jnp.float32),
-                        pltpu.VMEM((kvh, rows, dh), jnp.float32)],
+                        pltpu.VMEM((kvh, rows, vd), jnp.float32)],
     )
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, nj=nj, ps=ps, g=g, mode=mode,
-                          softcap=softcap),
+                          softcap=softcap, v_dim=v_dim if latent else None),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, rows, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, rows, vd), q.dtype),
         interpret=interpret,
         name="paged_attention",
     )(table, pos32, last, win, *args)
-    out = out.reshape(b, kvh, sq, g, dh).transpose(0, 2, 1, 3, 4)
-    out = out.reshape(b, sq, h, dh)
+    out = out.reshape(b, kvh, sq, g, vd).transpose(0, 2, 1, 3, 4)
+    out = out.reshape(b, sq, h, vd)
     return out[:, 0] if squeeze else out
 
 
@@ -350,18 +383,22 @@ def paged_attention_decode(q, k_pages, v_pages, page_table, pos, *,
                            k_scale=None, v_scale=None, k_redist=None,
                            v_redist=None, window=None,
                            softcap: Optional[float] = None,
+                           v_dim: Optional[int] = None,
+                           scale: Optional[float] = None,
                            impl: Optional[str] = None):
     """Impl-dispatching entry point (see :func:`set_paged_impl`).  q may
     be [b, h, dh] (decode) or [b, sq, h, dh] (verify block / prefill
-    chunk, with ``pos`` the first query row's absolute position)."""
+    chunk, with ``pos`` the first query row's absolute position).  Latent
+    pages pass ``v_pages=None`` and ``v_dim`` (see the module docstring)."""
     if impl in (None, "auto"):
         impl = paged_impl()
     if impl == "ref":
         return paged_attention_ref(
             q, k_pages, v_pages, page_table, pos, k_scale=k_scale,
             v_scale=v_scale, k_redist=k_redist, v_redist=v_redist,
-            window=window, softcap=softcap)
+            window=window, softcap=softcap, v_dim=v_dim, scale=scale)
     return paged_attention_pallas(
         q, k_pages, v_pages, page_table, pos, k_scale=k_scale,
         v_scale=v_scale, k_redist=k_redist, v_redist=v_redist,
-        window=window, softcap=softcap, interpret=(impl == "interpret"))
+        window=window, softcap=softcap, v_dim=v_dim, scale=scale,
+        interpret=(impl == "interpret"))

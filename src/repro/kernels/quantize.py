@@ -1,9 +1,11 @@
 """Pallas TPU kernel: fused row-wise (per-token) abs-max INT8 quantization.
 
 One pass over x [M, K]: per-row abs-max -> scale -> round/clip -> int8 out +
-f32 scales out.  Whole rows sit in VMEM (K up to ~16k bf16 at bm=128 is
-~4 MiB), so no cross-block reduction is needed — the right trade for
-activation quantization where K = d_model/d_ff.
+f32 scales out.  Whole rows sit in VMEM, so no cross-block reduction is
+needed — the right trade for activation quantization where K = d_model/d_ff.
+A row block is halved until its double-buffered float32 input and int8
+output fit :data:`BLOCK_BYTES` (a 16 MiB scoped VMEM limit on v5e; at
+bm=128 a float32 block of K 11776 alone takes 6 MiB).
 """
 from __future__ import annotations
 
@@ -14,6 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 _EPS = 1e-9
+BLOCK_BYTES = 12 << 20      # VMEM for one step's blocks, double-buffered
 
 
 def _kernel(x_ref, q_ref, s_ref, *, bits: int):
@@ -32,6 +35,8 @@ def rowwise_quantize(x: jnp.ndarray, *, bits: int = 8, bm: int = 128,
     """x [M, K] -> (int8 [M, K], scales f32 [M, 1]).  Ragged M is zero-padded
     to a bm multiple internally and sliced back off the outputs."""
     m, k = x.shape
+    while bm > 32 and 2 * bm * k * (x.dtype.itemsize + 1) > BLOCK_BYTES:
+        bm //= 2
     bm = min(bm, m)
     pad_m = (-m) % bm
     if pad_m:

@@ -12,6 +12,7 @@ import sys
 import time
 import traceback
 from pathlib import Path
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -140,7 +141,8 @@ def _lower_cell(cfg, shape, mesh, quant: str, *, fsdp: bool, seq_shard: bool,
 
 def lower_paged_cell(arch: str, tp: int, *, kv_mode: str = "int8",
                      max_batch: int = 2, s_max: int = 128,
-                     page_size: int = 16) -> dict:
+                     page_size: int = 16,
+                     cut: Optional[dict] = None) -> dict:
     """Prove a production config lowers through the TENSOR-PARALLEL paged
     serving path: build a real (small) PagePool sharded over a ``tp``-device
     ("model",) serve mesh, lower the engine's shard_map'd pooled decode with
@@ -151,10 +153,11 @@ def lower_paged_cell(arch: str, tp: int, *, kv_mode: str = "int8",
 
     Unlike the roofline cells above this exercises the actual serve stack
     (``repro.serve.engine`` + pool + paged kernels), not the dense
-    ``make_serve_step`` program."""
+    ``make_serve_step`` program.  ``cut`` replaces config fields first: a
+    chip's share of the arch, such as fewer layers and the experts held."""
     from repro.serve.engine import ServeEngine
 
-    cfg = get_config(arch).replace(dtype="bfloat16")
+    cfg = get_config(arch).replace(dtype="bfloat16", **(cut or {}))
     abs_p = _abstract_params(cfg, jnp.bfloat16)
     eng = ServeEngine(cfg, abs_p, max_batch=max_batch, s_max=s_max,
                       kv_mode=kv_mode, page_size=page_size, tp=tp)

@@ -128,10 +128,10 @@ def synthetic_qparams(cfg: ModelConfig, frac: float = 0.02) -> Dict[str, jnp.nda
         sites["mlp_down"] = m(f)
     if fam == "moe":
         sites["moe_up"] = m(d)
-        sites["moe_down"] = m(f)
-        if cfg.shared_expert:
+        sites["moe_down"] = m(cfg.d_expert)
+        if cfg.d_ff_shared:
             sites["moe_shared_up"] = m(d)
-            sites["moe_shared_down"] = m(f)
+            sites["moe_shared_down"] = m(cfg.d_ff_shared)
     if fam == "encdec":
         sites["cross_q"] = m(d)
         sites["cross_kv"] = m(d)

@@ -7,6 +7,14 @@
   * full forward (train / prefill, optionally emitting a KV cache) and a
     single-token decode step against a preallocated cache
   * cross-attention (whisper decoder)
+  * latent attention (``cfg.attn_kind == "mla"``, DeepSeek-V2/V3): each
+    position caches ONE row — the RMS-normed latent ``c`` and the rotated
+    rotary key ``k_pe`` shared by every head — and every path attends it in
+    the absorbed form: the query's no-rope part is taken into latent space
+    through ``W_UK`` (``q_nope · W_UK``), the latent row serves as key and
+    its first ``kv_lora_rank`` columns as value, and ``W_UV`` maps the
+    attended latent back per head.  ``W_UK``/``W_UV`` (``wkv_b``) stay in
+    the compute dtype, under the ``mla`` name scope.
 
 All projections run through the quantization ctx (paper's c_attn / c_proj
 target set).  ``sq`` is the per-layer site-quant dict {site: outlier mask}
@@ -20,7 +28,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import paged_attention as PA
-from repro.models.common import ModelConfig, apply_rope, dense_init, softcap
+from repro.models.common import (MAIN_GROUP, ModelConfig, apply_rope,
+                                 dense_init, group_name, rmsnorm, softcap)
 from repro.parallel import serve_sharding as TP
 from repro.parallel.act_sharding import cache_update_mode
 from repro.serve import kvq
@@ -61,6 +70,8 @@ def _write_cache(cache: dict, updates: dict) -> dict:
 
 
 def init_attention(key, cfg: ModelConfig, cross: bool = False) -> dict:
+    if cfg.is_mla and not cross:
+        return _init_mla(key, cfg)
     h, kv, dh, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_model
     k1, k2, k3 = jax.random.split(key, 3)
     if cross:
@@ -99,14 +110,14 @@ def sdpa(cfg: ModelConfig, q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     kv = k.shape[2]
     g = h // kv
     qg = q.reshape(b, sq_, kv, g, dh)
-    scale = cfg.head_dim ** -0.5
-    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32) * scale
+    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32) * (
+        cfg.attn_scale)
     scores = softcap(scores, cfg.attn_softcap)
     if bias is not None:
         scores = scores + bias[:, :, None]    # [..., sq, sk] -> group-dim bcast
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
-    return out.reshape(b, sq_, h, dh)
+    return out.reshape(b, sq_, h, v.shape[-1])
 
 
 def causal_bias(sq: int, sk: int, window: int, window_flag,
@@ -131,6 +142,9 @@ def attention(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     """Full-sequence attention.  If ``cache`` is a dict of preallocated
     [b, s_max, kv, dh] buffers, writes K/V at positions [0, s) and returns
     the updated cache (prefill)."""
+    if cfg.is_mla:
+        return mla_attention(cfg, p, ctx, x, positions, window_flag=window_flag,
+                             sq=sq, cache=cache, causal=causal)
     sq = sq or {}
     b, s, d = x.shape
     qkv = ctx("attn_qkv", x, p["wqkv"], mask=sq.get("attn_qkv"),
@@ -162,18 +176,24 @@ def attention(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
 def attention_decode(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
                      cache: dict, *, window_flag=False,
                      sq: Optional[Dict] = None) -> Tuple[jnp.ndarray, dict]:
-    """One-token decode: x [b, 1, d]; cache k/v [b, s_max, kv, dh] + pos."""
+    """One-token decode: x [b, 1, d]; cache k/v [b, s_max, kv, dh] + pos
+    (latent attention: one latent row ``k`` [b, s_max, 1, width])."""
     sq = sq or {}
     b, one, d = x.shape
     pos = cache["pos"]
-    qkv = ctx("attn_qkv", x, p["wqkv"], mask=sq.get("attn_qkv"),
-              smooth=sq.get("attn_qkv@smooth"), fused=sq.get("attn_qkv@fused"))
-    if "bqkv" in p:
-        qkv = qkv + p["bqkv"].astype(x.dtype)
-    q, k, v = _split_qkv(cfg, qkv)
     positions = jnp.full((b, 1), pos, jnp.int32)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.is_mla:
+        q, lat = mla_project(cfg, p, ctx, x, positions, sq)
+        k, v = lat[:, :, None], None
+    else:
+        qkv = ctx("attn_qkv", x, p["wqkv"], mask=sq.get("attn_qkv"),
+                  smooth=sq.get("attn_qkv@smooth"),
+                  fused=sq.get("attn_qkv@fused"))
+        if "bqkv" in p:
+            qkv = qkv + p["bqkv"].astype(x.dtype)
+        q, k, v = _split_qkv(cfg, qkv)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
 
     # the cache's key set names its page mode (int8: Oaken-style scales;
     # int4: MUXQ'd nibbles + redistribution rows; fp: raw) — one quantize
@@ -198,6 +218,9 @@ def attention_decode(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     in_window = kpos > pos - cfg.window_size
     allow = (kpos <= pos) & (in_window | ~jnp.asarray(window_flag))
     bias = jnp.where(allow, 0.0, NEG_INF)[None, None, None, :].astype(jnp.float32)
+    if cfg.is_mla:
+        o = sdpa(cfg, q, kk, kk[..., :cfg.kv_lora_rank], bias)
+        return mla_output(cfg, p, ctx, o, sq), new_cache
     o = sdpa(cfg, q, kk, vv, bias)
     o = o.reshape(b, 1, cfg.n_heads * cfg.head_dim)
     out = ctx("attn_out", o, p["wo"], mask=sq.get("attn_out"),
@@ -235,6 +258,8 @@ def attention_decode_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     kernel (page-table-indexed K/V loads, int8 pages dequantized
     in-kernel), on CPU the jnp gather reference — the fp-page serve tests
     pin the reference bit-exact against the dense cache path."""
+    if cfg.is_mla:
+        return mla_paged(cfg, p, ctx, x, cache, sq=sq)
     sq = sq or {}
     b, one, d = x.shape
     pos = cache["pos"]                                      # [b]
@@ -316,6 +341,8 @@ def attention_verify_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     need no undo: per-slot ``pos`` is the source of truth and their page
     rows are simply overwritten when the slot's position reaches them
     (the scheduler COWs shared pages before the k-token write)."""
+    if cfg.is_mla:
+        return mla_paged(cfg, p, ctx, x, cache, sq=sq)
     sq = sq or {}
     b, kb, d = x.shape
     pos = cache["pos"]                                      # [b]
@@ -409,6 +436,8 @@ def attention_prefill_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     Back compat: a 1-D ``page_table`` [pages] with scalar
     ``start``/``write_lo``/``write_hi`` (the pre-multi-slot single-request
     form) is normalized to the batched shapes with b=1."""
+    if cfg.is_mla:
+        return mla_paged(cfg, p, ctx, x, cache, sq=sq)
     sq = sq or {}
     b, C, d = x.shape
     ps = cache["k"].shape[2]
@@ -507,14 +536,164 @@ def n_attn_layers(cfg: ModelConfig) -> int:
     if cfg.shared_attn_every:   # zamba2: shared weights, per-site caches
         return sum(1 for i in range(cfg.n_layers)
                    if i % cfg.shared_attn_every == cfg.shared_attn_every - 1)
-    return sum(1 for b in cfg.blocks if b in ("attn", "local", "global", "moe"))
+    return sum(1 for b in cfg.blocks if b in ("attn", "local", "global"))
+
+
+def cache_rows(cfg: ModelConfig) -> Tuple[int, int]:
+    """(heads, width) of one position's cached rows in a layer: the K/V
+    heads, or one latent row (latent + rotary key) for latent attention."""
+    if cfg.is_mla:
+        return 1, cfg.kv_lora_rank + cfg.qk_rope_dim
+    return cfg.n_kv_heads, cfg.head_dim
 
 
 def init_cache(cfg: ModelConfig, batch: int, s_max: int, dtype=jnp.bfloat16,
                layers: Optional[int] = None) -> dict:
-    """Preallocated per-layer KV cache (stacked leading layer dim for scan)."""
-    kv, dh = cfg.n_kv_heads, cfg.head_dim
-    n_attn = layers if layers is not None else n_attn_layers(cfg)
-    shape = (n_attn, batch, s_max, kv, dh)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
-            "pos": jnp.asarray(0, jnp.int32)}
+    """Preallocated per-layer KV cache (stacked leading layer dim for scan),
+    one set of arrays per layer group (``common.group_name``)."""
+    kv, dh = cache_rows(cfg)
+    if layers is None and cfg.family in ("dense", "moe"):
+        groups = cfg.layer_groups
+    else:
+        groups = ((MAIN_GROUP, 0, layers if layers is not None
+                   else n_attn_layers(cfg)),)
+    out = {"pos": jnp.asarray(0, jnp.int32)}
+    for key, lo, hi in groups:
+        shape = (hi - lo, batch, s_max, kv, dh)
+        for n in (("k",) if cfg.is_mla else ("k", "v")):
+            out[group_name(key, n)] = jnp.zeros(shape, dtype)
+    return out
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA)
+# ---------------------------------------------------------------------------
+
+MLA_SCOPE = "mla"
+LATENT_NORM_EPS = 1e-6      # the latent's RMSNorm (DeepSeek-V3's default)
+
+
+def _init_mla(key, cfg: ModelConfig) -> dict:
+    """Latent attention weights: ``wq`` [d, h*(nope+rope) + latent] — each
+    head's query and the latent ``c`` from one projection — ``wkpe``
+    [d, rope] (the rotary key shared by the heads), the latent's RMSNorm
+    gain, ``wkv_b`` [latent, h*(nope+v)] (W_UK | W_UV per head) and
+    ``wo`` [h*v, d]."""
+    h, d, r = cfg.n_heads, cfg.d_model, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    return {"wq": dense_init(k1, (d, h * (nope + rope) + r)),
+            "wkpe": dense_init(k2, (d, rope)),
+            "kv_norm": {"gain": jnp.zeros((r,), jnp.float32)},
+            "wkv_b": dense_init(k3, (r, h * (nope + vd))),
+            "wo": dense_init(k4, (h * vd, d), fan_in=h * vd)}
+
+
+def _site(ctx, name, x, w, sq):
+    return ctx(name, x, w, mask=sq.get(name), smooth=sq.get(name + "@smooth"),
+               fused=sq.get(name + "@fused"))
+
+
+def mla_project(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
+                positions: jnp.ndarray, sq: Dict):
+    """x [b, s, d] -> (absorbed queries [b, s, h, latent + rope], latent
+    rows [b, s, latent + rope]: the normed latent, then the rotated rotary
+    key)."""
+    h, r = cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    b, s, _ = x.shape
+    qc = _site(ctx, "attn_q", x, p["wq"], sq)
+    q = qc[..., :h * (nope + rope)].reshape(b, s, h, nope + rope)
+    c = rmsnorm(qc[..., h * (nope + rope):], p["kv_norm"]["gain"],
+                LATENT_NORM_EPS)
+    kpe = _site(ctx, "attn_kpe", x, p["wkpe"], sq)
+    q_pe = apply_rope(q[..., nope:], positions, cfg.rope_theta)
+    kpe = apply_rope(kpe[:, :, None], positions, cfg.rope_theta)[:, :, 0]
+    w_uk = p["wkv_b"].astype(x.dtype).reshape(r, h, nope + vd)[..., :nope]
+    with jax.named_scope(MLA_SCOPE):
+        q_lat = jnp.einsum("bshn,rhn->bshr", q[..., :nope], w_uk)
+    return (jnp.concatenate([q_lat, q_pe], axis=-1),
+            jnp.concatenate([c, kpe], axis=-1))
+
+
+def mla_output(cfg: ModelConfig, p: dict, ctx, o_lat: jnp.ndarray,
+               sq: Dict) -> jnp.ndarray:
+    """Attended latents [b, s, h, latent] -> per head through W_UV, then
+    the output projection -> [b, s, d]."""
+    h, r = cfg.n_heads, cfg.kv_lora_rank
+    nope, vd = cfg.qk_nope_dim, cfg.v_head_dim
+    b, s = o_lat.shape[:2]
+    w_uv = p["wkv_b"].astype(o_lat.dtype).reshape(r, h, nope + vd)[..., nope:]
+    with jax.named_scope(MLA_SCOPE):
+        o = jnp.einsum("bshr,rhv->bshv", o_lat, w_uv)
+    return _site(ctx, "attn_out", o.reshape(b, s, h * vd), p["wo"], sq)
+
+
+def mla_attention(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
+                  positions: jnp.ndarray, *, window_flag=False,
+                  sq: Optional[Dict] = None, cache: Optional[dict] = None,
+                  causal: bool = True):
+    """Full-sequence latent attention (absorbed form); with ``cache``
+    (``k`` [b, s_max, 1, width]) writes the latent rows at [0, s)."""
+    sq = sq or {}
+    s = x.shape[1]
+    q, lat = mla_project(cfg, p, ctx, x, positions, sq)
+    k = lat[:, :, None]                                   # [b, s, 1, width]
+    if cache is not None:
+        cache = dict(cache)
+        parts = kvq.from_cache(cache).quantize(k)
+        for n, v in parts.items():
+            cache[n] = jax.lax.dynamic_update_slice(
+                cache[n], v.astype(cache[n].dtype), (0, 0, 0, 0))
+        cache["pos"] = jnp.asarray(s, jnp.int32)
+    bias = causal_bias(s, s, cfg.window_size, window_flag) if causal else None
+    o = sdpa(cfg, q, k, k[..., :cfg.kv_lora_rank], bias)
+    return mla_output(cfg, p, ctx, o, sq), cache
+
+
+def paged_rows(cache: dict, s: int):
+    """Where a paged step's rows sit and write, from its routing state:
+    (positions [b, s], write page [b, s] (0: scratch, never read), offset
+    in the page [b, s], first query row's position [b]).  The state names
+    the step: ``write_lo`` a prefill chunk, ``n_valid`` a verify block,
+    else a one-token decode."""
+    page_table = cache["page_table"]
+    ps = cache["k"].shape[2]
+    rows = jnp.arange(s, dtype=jnp.int32)[None]
+    if "write_lo" in cache:
+        start = jnp.asarray(cache["start"], jnp.int32)
+        positions = start[:, None] + rows
+        ok = ((positions >= cache["write_lo"][:, None])
+              & (positions < cache["write_hi"][:, None]))
+    else:
+        start = cache["pos"]
+        positions = start[:, None] + rows
+        n_valid = cache.get("n_valid")
+        ok = (jnp.ones_like(positions, bool) if n_valid is None
+              else rows < n_valid[:, None])
+    logical = jnp.clip(positions // ps, 0, page_table.shape[1] - 1)
+    page = jnp.take_along_axis(page_table, logical, axis=1)
+    return positions, jnp.where(ok, page, 0), positions % ps, start
+
+
+def mla_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray, cache: dict,
+              *, sq: Optional[Dict] = None):
+    """Latent attention of a paged step (decode, verify or a prefill chunk;
+    see :func:`paged_rows`): each row's latent is written into its page
+    first, then one kernel call attends every slot's rows over its pages
+    with the latent as key and its first ``kv_lora_rank`` columns as
+    value."""
+    sq = sq or {}
+    positions, page, offset, start = paged_rows(cache, x.shape[1])
+    q, lat = mla_project(cfg, p, ctx, x, positions, sq)
+    quantizer = kvq.from_cache(cache)
+    parts = quantizer.quantize(lat[:, :, None])
+    with jax.named_scope(POOL_SCOPE):
+        new_cache = _write_cache(cache, {
+            n: cache[n].at[page, :, offset].set(parts[n].astype(cache[n].dtype))
+            for n in parts})
+    o = PA.paged_attention_decode(
+        q, new_cache["k"], None, cache["page_table"], start,
+        v_dim=cfg.kv_lora_rank, scale=cfg.attn_scale,
+        **quantizer.kernel_operands(new_cache))
+    return mla_output(cfg, p, ctx, o, sq), new_cache

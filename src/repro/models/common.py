@@ -1,10 +1,21 @@
 """Shared model substrate: config, norms, rotary embeddings, losses.
 
 One ModelConfig covers every assigned architecture (dense / MoE / SSM /
-hybrid / enc-dec / VLM-backbone).  Block composition is expressed as a
-``block_pattern`` — a short cycle of block kinds tiled over ``n_layers``
-(e.g. gemma2's ("local", "global"), zamba2's five mamba blocks then a
-shared-attention checkpoint).
+hybrid / enc-dec / VLM-backbone).  A layer has an attention kind and an
+FFN kind:
+
+  * ``block_pattern`` — a short cycle of block kinds tiled over
+    ``n_layers`` ("attn" | "local" | "global" | "mamba"; e.g. gemma2's
+    ("local", "global")), and ``attn_kind`` — how attention layers project
+    and cache: "mha" (multi-/grouped-query heads, K and V per KV head) or
+    "mla" (latent attention: one compressed latent per position, shared by
+    every head, DeepSeek-V2/V3);
+  * the FFN is "experts" in every layer past the first ``first_dense``
+    ones when ``n_experts`` is set, else "dense".
+
+Layers whose parameters share one structure form a *group*
+(:attr:`ModelConfig.layer_groups`): each group is stacked ``[n, ...]`` under
+its own key of the parameter tree and scanned on its own.
 """
 from __future__ import annotations
 
@@ -33,7 +44,8 @@ class ModelConfig:
     vocab_size: int
 
     # block composition — cycle tiled over n_layers
-    block_pattern: Tuple[str, ...] = ("attn",)   # attn|local|global|moe|mamba
+    block_pattern: Tuple[str, ...] = ("attn",)   # attn|local|global|mamba
+    attn_kind: str = "mha"                       # mha|mla
 
     # attention
     qkv_bias: bool = False
@@ -41,13 +53,26 @@ class ModelConfig:
     final_softcap: Optional[float] = None        # gemma2: 30.0
     window_size: int = 4096                      # for "local" blocks
     rope_theta: float = 10000.0
+    # latent attention (attn_kind "mla"): no query down-projection
+    kv_lora_rank: int = 0                        # latent width
+    qk_nope_dim: int = 0                         # per-head key part off rope
+    qk_rope_dim: int = 0                         # rotary key, shared by heads
+    v_head_dim: int = 0
 
     # mlp
     mlp_type: str = "swiglu"                     # swiglu|gelu
-    # moe
-    n_experts: int = 0
+    # moe: layers past the first ``first_dense`` hold routed experts
+    n_experts: int = 0                           # experts the router scores
     top_k: int = 0
-    shared_expert: bool = False                  # llama4-style shared expert
+    first_dense: int = 0                         # leading dense-FFN layers
+    d_ff_expert: int = 0                         # routed expert width (0: d_ff)
+    d_ff_shared: int = 0                         # shared expert width (0: none)
+    router_score: str = "softmax"                # softmax|sigmoid
+    router_bias: bool = False                    # selection-only score bias
+    routed_scale: float = 1.0                    # times the chosen scores
+    # the experts this chip holds: [expert_lo, expert_lo + n_held)
+    expert_lo: int = 0
+    n_held: int = 0                              # 0: every expert
     # ssm (mamba2)
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -75,6 +100,27 @@ class ModelConfig:
         return self.d_model // self.n_heads
 
     @property
+    def is_mla(self) -> bool:
+        return self.attn_kind == "mla"
+
+    @property
+    def attn_scale(self) -> float:
+        """Softmax scale: over the query-key width each head scores (MLA's
+        latent scores equal its expanded nope + rope dot products)."""
+        if self.is_mla:
+            return (self.qk_nope_dim + self.qk_rope_dim) ** -0.5
+        return self.head_dim ** -0.5
+
+    @property
+    def d_expert(self) -> int:
+        return self.d_ff_expert or self.d_ff
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first expert held, experts held)."""
+        return self.expert_lo, (self.n_held or self.n_experts)
+
+    @property
     def padded_vocab(self) -> int:
         return pad_vocab(self.vocab_size)
 
@@ -97,6 +143,24 @@ class ModelConfig:
         return tuple(pat[i % len(pat)] for i in range(self.n_layers))
 
     @property
+    def ffn_kinds(self) -> Tuple[str, ...]:
+        """Per layer: "experts" past the leading dense layers of an expert
+        model, else "dense"."""
+        return tuple("experts" if self.n_experts and i >= self.first_dense
+                     else "dense" for i in range(self.n_layers))
+
+    @property
+    def layer_groups(self) -> Tuple[Tuple[str, int, int], ...]:
+        """(parameter key, first layer, end) of each run of layers that
+        share one parameter structure, in order.  The main group is always
+        ``"layers"``; an expert model's leading dense layers form the group
+        ``"dense_layers"``."""
+        lead = min(self.first_dense, self.n_layers) if self.n_experts else 0
+        if lead:
+            return (("dense_layers", 0, lead), ("layers", lead, self.n_layers))
+        return (("layers", 0, self.n_layers),)
+
+    @property
     def is_enc_dec(self) -> bool:
         return self.n_enc_layers > 0
 
@@ -107,24 +171,35 @@ class ModelConfig:
             return "encdec"
         if self.shared_attn_every:
             return "hybrid"
-        kinds = set(self.blocks)
-        if kinds == {"mamba"}:
+        if set(self.blocks) == {"mamba"}:
             return "ssm"
-        if "moe" in kinds:
+        if self.n_experts:
             return "moe"
         return "dense"
 
-    @property
-    def is_subquadratic(self) -> bool:
-        """True iff no block kind has an unbounded dense KV cache — the
-        long_500k eligibility rule (DESIGN.md §5)."""
-        quadratic = {"attn", "global", "moe"}
-        if self.shared_attn_every:      # zamba2 shared attn: bounded by design
-            pass
-        return not any(b in quadratic for b in self.blocks)
-
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
+
+
+MAIN_GROUP = "layers"
+# eager site-name prefix of each layer group's layers (``layer{i}/``,
+# ``dense{i}/``; ``i`` counts within the group)
+GROUP_SITE_PREFIX = {"layers": "layer", "dense_layers": "dense"}
+
+
+def group_name(key: str, name: str) -> str:
+    """Flat name of one group's entry in a per-layer tree (caches, pool
+    arrays, scanned quantization state): bare in the main group, else
+    ``"<group>/<name>"``."""
+    return name if key == MAIN_GROUP else f"{key}/{name}"
+
+
+def group_items(tree: dict, key: str) -> dict:
+    """One group's entries of a flat per-layer tree, under bare names."""
+    if key == MAIN_GROUP:
+        return {n: v for n, v in tree.items() if "/" not in n}
+    pre = key + "/"
+    return {n[len(pre):]: v for n, v in tree.items() if n.startswith(pre)}
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,11 @@ Families (cfg.family):
             applied every k layers                 (zamba2)
   encdec  — encoder + decoder w/ cross-attn        (whisper)
 
+Layers whose parameters share one structure form a group
+(``cfg.layer_groups``: an expert model's leading dense layers, then the
+rest), each stacked [n, ...] under its own key; :func:`_run_groups` is the
+one layer loop every decoder-only path runs, group after group.
+
 Two execution paths share one (stacked, [L, ...]-leading) param layout:
   * ``scan=True``  — ``lax.scan`` over layers: tiny HLO, fast XLA compiles
     at 512 devices, remat-friendly.  Per-layer data (window flags, MUXQ
@@ -34,8 +39,9 @@ from repro.models import attention as A
 from repro.models import mlp as M
 from repro.models import moe as E
 from repro.models import ssm as S
-from repro.models.common import (ModelConfig, apply_norm, cross_entropy,
-                                 dense_init, init_norm, softcap)
+from repro.models.common import (GROUP_SITE_PREFIX, ModelConfig, apply_norm,
+                                 cross_entropy, dense_init, group_items,
+                                 group_name, init_norm, softcap)
 
 
 # ---------------------------------------------------------------------------
@@ -43,6 +49,8 @@ from repro.models.common import (ModelConfig, apply_norm, cross_entropy,
 # ---------------------------------------------------------------------------
 
 def _init_layer(key, cfg: ModelConfig, kind: str, decoder: bool = False) -> dict:
+    """One layer's weights; ``kind`` is its FFN kind ("dense" |
+    "experts") or "mamba"."""
     ks = jax.random.split(key, 8)
     if kind == "mamba":
         return {"ln1": init_norm(cfg, cfg.d_model), "ssm": S.init_ssm(ks[0], cfg)}
@@ -51,7 +59,7 @@ def _init_layer(key, cfg: ModelConfig, kind: str, decoder: bool = False) -> dict
     if cfg.sandwich_norm:
         p["ln1b"] = init_norm(cfg, cfg.d_model)
         p["ln2b"] = init_norm(cfg, cfg.d_model)
-    if kind == "moe":
+    if kind == "experts":
         p["moe"] = E.init_moe(ks[1], cfg)
     else:
         p["mlp"] = M.init_mlp(ks[1], cfg)
@@ -63,7 +71,7 @@ def _init_layer(key, cfg: ModelConfig, kind: str, decoder: bool = False) -> dict
 
 def _stacked_layers(key, cfg: ModelConfig, kinds, decoder: bool = False) -> dict:
     """Init each layer then stack leaves to [L, ...].  All kinds in ``kinds``
-    must share a param structure (guaranteed per family)."""
+    must share a param structure (one layer group)."""
     keys = jax.random.split(key, len(kinds))
     layers = [_init_layer(keys[i], cfg, kinds[i], decoder) for i in range(len(kinds))]
     return jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
@@ -77,14 +85,20 @@ def init_params(cfg: ModelConfig, key) -> dict:
     }
     fam = cfg.family
     if fam == "encdec":
-        params["enc_layers"] = _stacked_layers(k_enc, cfg, ["attn"] * cfg.n_enc_layers)
+        params["enc_layers"] = _stacked_layers(k_enc, cfg, ["dense"] * cfg.n_enc_layers)
         params["enc_ln_f"] = init_norm(cfg, cfg.d_model)
-        params["layers"] = _stacked_layers(k_layers, cfg, ["attn"] * cfg.n_layers, decoder=True)
+        params["layers"] = _stacked_layers(k_layers, cfg, ["dense"] * cfg.n_layers, decoder=True)
     elif fam == "hybrid":
         params["layers"] = _stacked_layers(k_layers, cfg, ["mamba"] * cfg.n_layers)
-        params["shared"] = _init_layer(k_shared, cfg, "attn")
+        params["shared"] = _init_layer(k_shared, cfg, "dense")
+    elif fam == "ssm":
+        params["layers"] = _stacked_layers(k_layers, cfg, ["mamba"] * cfg.n_layers)
     else:
-        params["layers"] = _stacked_layers(k_layers, cfg, list(cfg.blocks))
+        groups = cfg.layer_groups
+        keys = [k_layers] if len(groups) == 1 else jax.random.split(
+            k_layers, len(groups))
+        for k, (name, lo, hi) in zip(keys, groups):
+            params[name] = _stacked_layers(k, cfg, cfg.ffn_kinds[lo:hi])
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(k_head, (cfg.d_model, cfg.padded_vocab))
     return params
@@ -98,25 +112,45 @@ def layer_slice(tree, i):
 # Block bodies (single layer)
 # ---------------------------------------------------------------------------
 
-def _dense_block(cfg, lp, ctx, x, positions, window_flag, sq, cache=None,
-                 prefix: str = "", causal: bool = True, train: bool = False):
+def _ffn(cfg, lp, ctx, h, sq, *, train=False, valid=None,
+         held_rows=False):
+    """The layer's FFN: (out, stat).  ``stat`` is the MoE aux loss (f32),
+    or with ``held_rows`` the routed rows that landed on held experts
+    (int32; 0 in a dense layer)."""
+    if "moe" in lp:
+        if held_rows:
+            m, n, _ = E.moe_held(cfg, lp["moe"], ctx, h, sq, valid)
+            return m, n
+        return E.moe(cfg, lp["moe"], ctx, h, sq=sq, train=train)
+    zero = jnp.zeros((), jnp.int32 if held_rows else jnp.float32)
+    return M.mlp(cfg, lp["mlp"], ctx, h, sq=sq), zero
+
+
+def _layer(cfg, lp, ctx, x, attn, sq, **ffn_kw):
+    """One pre-norm decoder layer.  ``attn(p, ctx, h) -> (out, cache)``
+    runs the layer's attention (and its cache write); returns
+    (x, cache, FFN stat)."""
     x = constrain(x)
     h = apply_norm(cfg, lp["ln1"], x)
-    a, cache = A.attention(cfg, lp["attn"], _Named(ctx, prefix), h, positions,
-                           window_flag=window_flag, sq=sq, cache=cache, causal=causal)
+    a, cache = attn(lp["attn"], ctx, h)
     if cfg.sandwich_norm:
         a = apply_norm(cfg, lp["ln1b"], a)
     x = x + a
     h = apply_norm(cfg, lp["ln2"], x)
-    aux = jnp.float32(0)
-    if "moe" in lp:
-        m, aux = E.moe(cfg, lp["moe"], _Named(ctx, prefix), h, sq=sq,
-                       train=train)
-    else:
-        m = M.mlp(cfg, lp["mlp"], _Named(ctx, prefix), h, sq=sq)
+    m, stat = _ffn(cfg, lp, ctx, h, sq, **ffn_kw)
     if cfg.sandwich_norm:
         m = apply_norm(cfg, lp["ln2b"], m)
-    return x + m, aux, cache
+    return x + m, cache, stat
+
+
+def _dense_block(cfg, lp, ctx, x, positions, window_flag, sq, cache=None,
+                 prefix: str = "", causal: bool = True, train: bool = False):
+    def attn(p, nctx, h):
+        return A.attention(cfg, p, nctx, h, positions, window_flag=window_flag,
+                           sq=sq, cache=cache, causal=causal)
+    x, cache, aux = _layer(cfg, lp, _Named(ctx, prefix), x, attn, sq,
+                           train=train)
+    return x, aux, cache
 
 
 def _decoder_block(cfg, lp, ctx, x, positions, memory, sq, cache=None):
@@ -156,6 +190,66 @@ class _Named:
     def emm(self, name, x, w, mask=None, smooth=None, fused=None):
         return self.ctx.emm(self.prefix + name, x, w, mask=mask,
                             smooth=smooth, fused=fused)
+
+    def gmm(self, name, x, w, rows, mask=None, smooth=None, fused=None):
+        return self.ctx.gmm(self.prefix + name, x, w, rows, mask=mask,
+                            smooth=smooth, fused=fused)
+
+
+def _run_groups(cfg, params, x, ctx, qparams, cache, attn, stat, *,
+                scan=True, named=False, **ffn_kw):
+    """The one decoder layer loop: every layer group in order, each scanned
+    over its own stacked weights, quantization state and cache arrays.
+
+    ``attn(p, ctx, h, c, flag, sq) -> (out, c)`` runs one layer's
+    attention against its per-layer cache dict ``c`` (None without a
+    cache); ``cache`` is the flat per-layer tree (``common.group_name``
+    keys) or None; ``stat`` the initial FFN stat (see :func:`_ffn`).
+    ``scan=False`` unrolls the loop, with eager ``layer{i}/`` site names
+    when ``named``.  Returns (x, summed stat, new cache tree or None)."""
+    flags = _window_flags(cfg)
+    new_cache = {} if cache is not None else None
+
+    def one(x, lp, nctx, flag, sq, c):
+        return _layer(cfg, lp, nctx, x,
+                      lambda p, cx, h: attn(p, cx, h, c, flag, sq), sq,
+                      **ffn_kw)
+
+    def add(st, s_):
+        return None if st is None else st + s_
+
+    for key, lo, hi in cfg.layer_groups:
+        lps, sqs = params[key], group_items(qparams or {}, key)
+        cs = None if cache is None else group_items(cache, key)
+        fl = flags[lo:hi]
+        if scan:
+            def body(carry, xs):
+                x, st = carry
+                lp, flag, sq, c = xs
+                x, c, s_ = one(x, lp, _Named(ctx, ""), flag, sq, c)
+                return (x, add(st, s_)), c
+            if cfg.remat:
+                body = jax.checkpoint(body)
+            (x, stat), cs = jax.lax.scan(body, (x, stat), (lps, fl, sqs, cs))
+        else:
+            outs = []
+            for i in range(hi - lo):
+                name = f"{GROUP_SITE_PREFIX[key]}{i}/" if named else ""
+                x, c, s_ = one(x, layer_slice(lps, i), _Named(ctx, name),
+                               fl[i], _sq_for_layer(sqs, i),
+                               None if cs is None else layer_slice(cs, i))
+                stat = add(stat, s_)
+                outs.append(c)
+            if cs is not None:
+                cs = jax.tree.map(lambda *t: jnp.stack(t), *outs)
+        if cs is not None:
+            new_cache.update({group_name(key, n): v for n, v in cs.items()})
+    return x, stat, new_cache
+
+
+def _keep(c, new):
+    """The per-layer arrays of ``c`` from an attention's updated cache."""
+    return None if c is None else {n: new[n] for n in c}
 
 
 # ---------------------------------------------------------------------------
@@ -232,44 +326,19 @@ def forward(cfg: ModelConfig, params, tokens, ctx=None, *, extra=None,
 
 def _run_dense(cfg, params, x, positions, ctx, *, scan, cache, qparams,
                train=False):
-    flags = _window_flags(cfg)
-    if not scan:
-        aux_total = jnp.float32(0)
-        ks, vs = [], []
-        for i in range(cfg.n_layers):
-            lp = layer_slice(params["layers"], i)
-            c_i = None if cache is None else {"k": cache["k"][i], "v": cache["v"][i]}
-            x, aux, c_i = _dense_block(cfg, lp, ctx, x, positions, flags[i],
-                                       _sq_for_layer(qparams, i), cache=c_i,
-                                       prefix=f"layer{i}/", train=train)
-            aux_total = aux_total + aux
-            if c_i is not None:
-                ks.append(c_i["k"]); vs.append(c_i["v"])
-        nc = None
-        if cache is not None:
-            nc = {"k": jnp.stack(ks), "v": jnp.stack(vs),
-                  "pos": jnp.asarray(x.shape[1], jnp.int32)}
-        return x, aux_total, nc
+    layers = None if cache is None else {n: v for n, v in cache.items()
+                                         if n != "pos"}
 
-    def body(carry, xs):
-        x, aux_total = carry
-        lp, flag, sq, c_k, c_v = xs
-        c_i = None if c_k is None else {"k": c_k, "v": c_v}
-        x, aux, c_i = _dense_block(cfg, lp, ctx, x, positions, flag, sq,
-                                   cache=c_i, train=train)
-        y = (c_i["k"], c_i["v"]) if c_i is not None else (jnp.zeros(()), jnp.zeros(()))
-        return (x, aux_total + aux), y
+    def attn(p, nctx, h, c, flag, sq):
+        a, new = A.attention(cfg, p, nctx, h, positions, window_flag=flag,
+                             sq=sq, cache=c)
+        return a, _keep(c, new)
 
-    if cfg.remat:
-        body = jax.checkpoint(body)
-    sqs = qparams or {}
-    xs = (params["layers"], flags, sqs,
-          cache["k"] if cache is not None else None,
-          cache["v"] if cache is not None else None)
-    (x, aux_total), ys = jax.lax.scan(body, (x, jnp.float32(0)), xs)
-    nc = None
-    if cache is not None:
-        nc = {"k": ys[0], "v": ys[1], "pos": jnp.asarray(x.shape[1], jnp.int32)}
+    x, aux_total, nc = _run_groups(cfg, params, x, ctx, qparams, layers, attn,
+                                   jnp.float32(0), scan=scan, named=True,
+                                   train=train)
+    if nc is not None:
+        nc["pos"] = jnp.asarray(x.shape[1], jnp.int32)
     return x, aux_total, nc
 
 
@@ -409,49 +478,19 @@ def decode_step(cfg: ModelConfig, params, tokens, cache, ctx=None, *,
     pos = cache["pos"]
 
     if fam in ("dense", "moe"):
-        flags = _window_flags(cfg)
         # any per-layer cache arrays beyond k/v (int8 scales, int4 scales +
-        # redistribution rows) ride the scan xs generically and come back
-        # stacked — the attention step passes unrecognized keys through
-        extra_tree = {n: cache[n] for n in cache if n not in ("k", "v", "pos")}
+        # redistribution rows) ride the layer loop generically and come
+        # back stacked — the attention step passes unrecognized keys through
+        layers = {n: cache[n] for n in cache if n != "pos"}
 
-        def body(x, xs):
-            lp, flag, sq, c_k, c_v, c_s = xs
-            c_i = {"k": c_k, "v": c_v, "pos": pos, **c_s}
-            nctx = _Named(ctx, "")
-            h = apply_norm(cfg, lp["ln1"], x)
-            a, c_i = A.attention_decode(cfg, lp["attn"], nctx, h, c_i,
+        def attn(p, nctx, h, c, flag, sq):
+            a, new = A.attention_decode(cfg, p, nctx, h, {**c, "pos": pos},
                                         window_flag=flag, sq=sq)
-            if cfg.sandwich_norm:
-                a = apply_norm(cfg, lp["ln1b"], a)
-            x = x + a
-            h = apply_norm(cfg, lp["ln2"], x)
-            if "moe" in lp:
-                m, _ = E.moe(cfg, lp["moe"], nctx, h, sq=sq)
-            else:
-                m = M.mlp(cfg, lp["mlp"], nctx, h, sq=sq)
-            if cfg.sandwich_norm:
-                m = apply_norm(cfg, lp["ln2b"], m)
-            sc_out = {n: c_i[n] for n in extra_tree}
-            return x + m, (c_i["k"], c_i["v"], sc_out)
+            return a, _keep(c, new)
 
-        if scan:
-            xs = (params["layers"], flags, qparams or {}, cache["k"],
-                  cache["v"], extra_tree)
-            x, (ks, vs, scs) = jax.lax.scan(body, x, xs)
-        else:
-            ks_l, vs_l, sc_l = [], [], []
-            for i in range(cfg.n_layers):
-                x, (k_i, v_i, s_i) = body(x, (layer_slice(params["layers"], i),
-                                              flags[i], _sq_for_layer(qparams, i),
-                                              cache["k"][i], cache["v"][i],
-                                              jax.tree.map(lambda t: t[i], extra_tree)))
-                ks_l.append(k_i); vs_l.append(v_i); sc_l.append(s_i)
-            ks, vs = jnp.stack(ks_l), jnp.stack(vs_l)
-            scs = (jax.tree.map(lambda *t: jnp.stack(t), *sc_l)
-                   if extra_tree else {})
-        new_cache = {"k": ks, "v": vs, "pos": pos + 1}
-        new_cache.update(scs)
+        x, _, new_cache = _run_groups(cfg, params, x, ctx, qparams, layers,
+                                      attn, jnp.float32(0), scan=scan)
+        new_cache["pos"] = pos + 1
 
     elif fam == "ssm":
         state_tree = {k: cache[k] for k in ("conv_x", "conv_bc", "ssm")}
@@ -539,9 +578,48 @@ def decode_step(cfg: ModelConfig, params, tokens, cache, ctx=None, *,
     return logits, new_cache
 
 
+def _paged_step(cfg, params, tokens, kv, routing, valid, ctx, qparams,
+                attn_fn, stats):
+    """The paged steps' shared body: embed, the layer loop over the pool's
+    per-layer arrays with ``routing`` (page table and positions) beside
+    each layer's pages, final norm and LM head.  ``valid`` [b, s] marks
+    real tokens (the MoE layers route nothing else)."""
+    ctx = ctx or FpCtx()
+    if cfg.family not in ("dense", "moe"):
+        raise ValueError(f"paged steps support dense/moe, not {cfg.family}")
+    x = params["embed"][tokens].astype(cfg.compute_dtype)
+    if cfg.scale_embed:
+        x = x * math.sqrt(cfg.d_model)
+
+    def attn(p, nctx, h, c, flag, sq):
+        a, new = attn_fn(cfg, p, nctx, h, {**c, **routing}, window_flag=flag,
+                         sq=sq)
+        return a, _keep(c, new)
+
+    experts = bool(cfg.n_experts)
+    x, held, new_kv = _run_groups(cfg, params, x, ctx, qparams, kv, attn,
+                                  jnp.int32(0) if experts else None,
+                                  valid=valid, held_rows=experts)
+    x = apply_norm(cfg, params["ln_f"], x)
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    # under tensor-parallel serving each shard computes its contiguous
+    # vocab-column slice and a zero-pad psum reassembles the replicated
+    # logits (bit-exact; plain full matmul when no shard context is active)
+    logits = TP.tp_logits(x, head.astype(x.dtype))
+    logits = softcap(logits, cfg.final_softcap)
+    if stats:
+        counts = {}
+        if experts:
+            calls = sum(k == "experts" for k in cfg.ffn_kinds)
+            counts = {"moe_rows_held": held,
+                      "moe_expert_calls": jnp.int32(calls)}
+        return logits, new_kv, counts
+    return logits, new_kv
+
+
 def decode_step_paged(cfg: ModelConfig, params, tokens, kv: dict,
-                      page_table, pos, ctx=None, *, qparams=None
-                      ) -> Tuple[jnp.ndarray, dict]:
+                      page_table, pos, ctx=None, *, qparams=None,
+                      stats=False) -> Tuple[jnp.ndarray, dict]:
     """One-token decode for the WHOLE slot pool against a paged KV pool
     (``repro.serve.pool``), with a per-slot position vector.
 
@@ -558,55 +636,22 @@ def decode_step_paged(cfg: ModelConfig, params, tokens, kv: dict,
     logical capacity (block-sparse decode reads).  The only requirement is
     ``pos[b] // ps < budget`` for every live slot — the write page and all
     read pages must sit inside the sliced table.  Dense/MoE only (the
-    families ``ServeEngine`` serves)."""
-    ctx = ctx or FpCtx()
-    if cfg.family not in ("dense", "moe"):
-        raise ValueError(f"paged decode supports dense/moe, not {cfg.family}")
-    x = params["embed"][tokens].astype(cfg.compute_dtype)
-    if cfg.scale_embed:
-        x = x * math.sqrt(cfg.d_model)
+    families ``ServeEngine`` serves).
 
-    flags = _window_flags(cfg)
-    # per-layer pool arrays beyond k/v (int8/int4 scales, int4 redist rows)
-    # ride the scan xs generically and come back stacked
-    extra_tree = {n: kv[n] for n in kv if n not in ("k", "v")}
-
-    def body(x, xs):
-        lp, flag, sq, c_k, c_v, c_s = xs
-        c_i = {"k": c_k, "v": c_v, "page_table": page_table, "pos": pos, **c_s}
-        nctx = _Named(ctx, "")
-        h = apply_norm(cfg, lp["ln1"], x)
-        a, c_i = A.attention_decode_paged(cfg, lp["attn"], nctx, h, c_i,
-                                          window_flag=flag, sq=sq)
-        if cfg.sandwich_norm:
-            a = apply_norm(cfg, lp["ln1b"], a)
-        x = x + a
-        h = apply_norm(cfg, lp["ln2"], x)
-        if "moe" in lp:
-            m, _ = E.moe(cfg, lp["moe"], nctx, h, sq=sq)
-        else:
-            m = M.mlp(cfg, lp["mlp"], nctx, h, sq=sq)
-        if cfg.sandwich_norm:
-            m = apply_norm(cfg, lp["ln2b"], m)
-        sc_out = {n: c_i[n] for n in extra_tree}
-        return x + m, (c_i["k"], c_i["v"], sc_out)
-
-    xs = (params["layers"], flags, qparams or {}, kv["k"], kv["v"], extra_tree)
-    x, (ks, vs, scs) = jax.lax.scan(body, x, xs)
-    new_kv = {"k": ks, "v": vs, **scs}
-
-    x = apply_norm(cfg, params["ln_f"], x)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    # under tensor-parallel serving each shard computes its contiguous
-    # vocab-column slice and a zero-pad psum reassembles the replicated
-    # logits (bit-exact; plain full matmul when no shard context is active)
-    logits = TP.tp_logits(x, head.astype(x.dtype))
-    logits = softcap(logits, cfg.final_softcap)
-    return logits, new_kv
+    With ``stats=True`` returns (logits, kv, counts); an expert model's
+    counts are ``moe_rows_held``, the routed rows of live slots that
+    landed on held experts summed over the expert layers, and
+    ``moe_expert_calls``, the expert layers run (the same for the verify
+    and prefill steps)."""
+    routing = {"page_table": page_table, "pos": pos}
+    valid = page_table[:, :1] > 0        # idle and prefilling slots: scratch
+    return _paged_step(cfg, params, tokens, kv, routing, valid, ctx, qparams,
+                       A.attention_decode_paged, stats)
 
 
 def decode_verify_paged(cfg: ModelConfig, params, tokens, kv: dict,
-                        page_table, pos, n_valid, ctx=None, *, qparams=None
+                        page_table, pos, n_valid, ctx=None, *,
+                        qparams=None, stats=False
                         ) -> Tuple[jnp.ndarray, dict]:
     """Speculative-decoding VERIFY step: score a ``[slot, k]`` block of
     draft tokens for the whole pool in ONE traced call
@@ -627,56 +672,15 @@ def decode_verify_paged(cfg: ModelConfig, params, tokens, kv: dict,
     they are overwritten when the position reaches them.  Shapes are
     static per (k bucket, page bucket) pair — the scheduler buckets both
     — so verify compiles once per pair, never per draft length."""
-    ctx = ctx or FpCtx()
-    if cfg.family not in ("dense", "moe"):
-        raise ValueError(f"paged verify supports dense/moe, not {cfg.family}")
-    x = params["embed"][tokens].astype(cfg.compute_dtype)
-    if cfg.scale_embed:
-        x = x * math.sqrt(cfg.d_model)
-
-    flags = _window_flags(cfg)
-    # per-layer pool arrays beyond k/v (int8/int4 scales, int4 redist rows)
-    # ride the scan xs generically and come back stacked
-    extra_tree = {n: kv[n] for n in kv if n not in ("k", "v")}
-
-    def body(x, xs):
-        lp, flag, sq, c_k, c_v, c_s = xs
-        c_i = {"k": c_k, "v": c_v, "page_table": page_table, "pos": pos,
-               "n_valid": n_valid, **c_s}
-        nctx = _Named(ctx, "")
-        h = apply_norm(cfg, lp["ln1"], x)
-        a, c_i = A.attention_verify_paged(cfg, lp["attn"], nctx, h, c_i,
-                                          window_flag=flag, sq=sq)
-        if cfg.sandwich_norm:
-            a = apply_norm(cfg, lp["ln1b"], a)
-        x = x + a
-        h = apply_norm(cfg, lp["ln2"], x)
-        if "moe" in lp:
-            m, _ = E.moe(cfg, lp["moe"], nctx, h, sq=sq)
-        else:
-            m = M.mlp(cfg, lp["mlp"], nctx, h, sq=sq)
-        if cfg.sandwich_norm:
-            m = apply_norm(cfg, lp["ln2b"], m)
-        sc_out = {n: c_i[n] for n in extra_tree}
-        return x + m, (c_i["k"], c_i["v"], sc_out)
-
-    xs = (params["layers"], flags, qparams or {}, kv["k"], kv["v"], extra_tree)
-    x, (ks, vs, scs) = jax.lax.scan(body, x, xs)
-    new_kv = {"k": ks, "v": vs, **scs}
-
-    x = apply_norm(cfg, params["ln_f"], x)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    # under tensor-parallel serving each shard computes its contiguous
-    # vocab-column slice and a zero-pad psum reassembles the replicated
-    # logits (bit-exact; plain full matmul when no shard context is active)
-    logits = TP.tp_logits(x, head.astype(x.dtype))
-    logits = softcap(logits, cfg.final_softcap)
-    return logits, new_kv
+    routing = {"page_table": page_table, "pos": pos, "n_valid": n_valid}
+    valid = jnp.arange(tokens.shape[1])[None] < n_valid[:, None]
+    return _paged_step(cfg, params, tokens, kv, routing, valid, ctx, qparams,
+                       A.attention_verify_paged, stats)
 
 
 def prefill_chunk_paged(cfg: ModelConfig, params, tokens, kv: dict,
                         page_table, start, write_lo, write_hi, ctx=None, *,
-                        qparams=None) -> Tuple[jnp.ndarray, dict]:
+                        qparams=None, stats=False) -> Tuple[jnp.ndarray, dict]:
     """One chunk per prefilling slot, for SEVERAL slots at once, prefilled
     straight into the paged KV pool in ONE traced call (``repro.serve``) —
     the serving engine's only prefill path; there is no dense ``[1, T]``
@@ -703,51 +707,14 @@ def prefill_chunk_paged(cfg: ModelConfig, params, tokens, kv: dict,
     bucket) pair, so the step compiles once per pair, never per prompt
     length or per number of advancing slots.  Dense/MoE only (the families
     ``ServeEngine`` serves)."""
-    ctx = ctx or FpCtx()
-    if cfg.family not in ("dense", "moe"):
-        raise ValueError(f"paged prefill supports dense/moe, not {cfg.family}")
-    x = params["embed"][tokens].astype(cfg.compute_dtype)
-    if cfg.scale_embed:
-        x = x * math.sqrt(cfg.d_model)
-
-    flags = _window_flags(cfg)
-    # per-layer pool arrays beyond k/v (int8/int4 scales, int4 redist rows)
-    # ride the scan xs generically and come back stacked
-    extra_tree = {n: kv[n] for n in kv if n not in ("k", "v")}
-
-    def body(x, xs):
-        lp, flag, sq, c_k, c_v, c_s = xs
-        c_i = {"k": c_k, "v": c_v, "page_table": page_table, "start": start,
-               "write_lo": write_lo, "write_hi": write_hi, **c_s}
-        nctx = _Named(ctx, "")
-        h = apply_norm(cfg, lp["ln1"], x)
-        a, c_i = A.attention_prefill_paged(cfg, lp["attn"], nctx, h, c_i,
-                                           window_flag=flag, sq=sq)
-        if cfg.sandwich_norm:
-            a = apply_norm(cfg, lp["ln1b"], a)
-        x = x + a
-        h = apply_norm(cfg, lp["ln2"], x)
-        if "moe" in lp:
-            m, _ = E.moe(cfg, lp["moe"], nctx, h, sq=sq)
-        else:
-            m = M.mlp(cfg, lp["mlp"], nctx, h, sq=sq)
-        if cfg.sandwich_norm:
-            m = apply_norm(cfg, lp["ln2b"], m)
-        sc_out = {n: c_i[n] for n in extra_tree}
-        return x + m, (c_i["k"], c_i["v"], sc_out)
-
-    xs = (params["layers"], flags, qparams or {}, kv["k"], kv["v"], extra_tree)
-    x, (ks, vs, scs) = jax.lax.scan(body, x, xs)
-    new_kv = {"k": ks, "v": vs, **scs}
-
-    x = apply_norm(cfg, params["ln_f"], x)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    # under tensor-parallel serving each shard computes its contiguous
-    # vocab-column slice and a zero-pad psum reassembles the replicated
-    # logits (bit-exact; plain full matmul when no shard context is active)
-    logits = TP.tp_logits(x, head.astype(x.dtype))
-    logits = softcap(logits, cfg.final_softcap)
-    return logits, new_kv
+    routing = {"page_table": page_table, "start": start,
+               "write_lo": write_lo, "write_hi": write_hi}
+    c = tokens.shape[1]
+    valid = (jnp.asarray(start, jnp.int32).reshape(-1, 1)
+             + jnp.arange(c)[None]) < jnp.asarray(write_hi,
+                                                   jnp.int32).reshape(-1, 1)
+    return _paged_step(cfg, params, tokens, kv, routing, valid, ctx, qparams,
+                       A.attention_prefill_paged, stats)
 
 
 # ---------------------------------------------------------------------------

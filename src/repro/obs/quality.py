@@ -139,6 +139,8 @@ class QualityObserver:
             return
         self.pool_samples += 1
         for side in ("k", "v"):
+            if side not in pool.kv:
+                continue                    # latent pages: one row, no v
             # [L, pages, kvh, ps, dh(/2)] int8 -> live pages only
             q = np.asarray(pool.kv[side])[:, live]
             if pool.mode == "int4":

@@ -38,6 +38,7 @@ from repro.core.outliers import CalibrationStats
 from repro.core.policy import SitePolicy, as_policy
 from repro.core.prequant import prequantize_params
 from repro.kernels import dispatch
+from repro.models.common import GROUP_SITE_PREFIX, group_name
 
 _SMOOTH_METHODS = ("smoothquant", "muxq_smooth")
 # v1: no kernel_buffers group, policy configs without a backend field.
@@ -61,6 +62,7 @@ PACK_TARGETS = ("both", "fused", "tree")
 # moe/shared/.
 _SITE_WEIGHT_PATH = {
     "attn_qkv": ("attn", "wqkv"), "attn_out": ("attn", "wo"),
+    "attn_q": ("attn", "wq"), "attn_kpe": ("attn", "wkpe"),
     "cross_q": ("cross", "wq"), "cross_kv": ("cross", "wkv"),
     "cross_out": ("cross", "wo"),
     "mlp_up": ("mlp", "wi"), "mlp_down": ("mlp", "wo"),
@@ -72,7 +74,10 @@ _SITE_WEIGHT_FALLBACK = {
     "mlp_up": ("moe", "shared", "wi"), "mlp_down": ("moe", "shared", "wo"),
 }
 
-_SITE_RE = re.compile(r"^(layer|enc|shared)(\d+)/(.+)$")
+_SITE_RE = re.compile(r"^(layer|dense|enc|shared)(\d+)/(.+)$")
+# eager site kind -> the parameter tree's stack of those layers
+_SITE_ROOT = {"layer": "layers", "dense": "dense_layers", "enc": "enc_layers",
+              "shared": "shared"}
 
 
 def split_site(site: str):
@@ -91,7 +96,7 @@ def _site_leaf(params, site: str) -> Optional[jnp.ndarray]:
     path = _SITE_WEIGHT_PATH.get(base)
     if path is None:
         return None
-    root = {"layer": "layers", "enc": "enc_layers", "shared": "shared"}.get(kind)
+    root = _SITE_ROOT.get(kind)
     if root is None:
         return None
     leaf = None
@@ -235,7 +240,7 @@ def _stacked_leaf_ref(params, site: str):
     from the leaf shape, so coverage cannot be verified artifact-side."""
     kind, _, base = split_site(site)
     path = _SITE_WEIGHT_PATH.get(base)
-    root = {"layer": "layers", "enc": "enc_layers"}.get(kind)
+    root = _SITE_ROOT.get(kind) if kind != "shared" else None
     if path is None or root is None:
         return None
     for candidate in (path, _SITE_WEIGHT_FALLBACK.get(base)):
@@ -313,7 +318,7 @@ def apply_pack_target(artifact: "QuantArtifact",
     seen = set()
     for site in artifact.kernel_buffers:
         kind, _, base = split_site(site)
-        if (kind, base) in seen or kind not in ("layer", "enc"):
+        if (kind, base) in seen or kind not in ("layer", "dense", "enc"):
             continue
         seen.add((kind, base))
         ref = _stacked_leaf_ref(params, site)
@@ -357,45 +362,61 @@ def _run_calibration(cfg, params, batches, forward):
     return stats, kvq.build_kv_calib(collector)
 
 
-def _scan_key(cfg, base: str) -> str:
-    """Bare qparams key the scanned model looks up for one eager site base.
+def _scan_key(cfg, kind: str, base: str) -> str:
+    """qparams key the scanned model looks up for one eager site base of a
+    layer group (``kind``: the group's eager prefix, see
+    ``common.GROUP_SITE_PREFIX``).
 
-    In MoE layers the shared expert runs through mlp() — its eager sites are
-    'layer{i}/mlp_up|down' but moe() routes the scanned sq under
-    'moe_shared_up|down'."""
-    if getattr(cfg, "family", None) == "moe" and base in ("mlp_up", "mlp_down"):
-        return "moe_shared_" + base.split("_", 1)[1]
-    return base
+    In expert layers the shared expert runs through mlp() — its eager
+    sites are 'layer{i}/mlp_up|down' but moe() routes the scanned sq under
+    'moe_shared_up|down'.  A group other than the main one keys its
+    entries '<group>/<site>'."""
+    group = {v: k for k, v in GROUP_SITE_PREFIX.items()}[kind]
+    if (kind == "layer" and getattr(cfg, "family", None) == "moe"
+            and "experts" in cfg.ffn_kinds and base in ("mlp_up", "mlp_down")):
+        base = "moe_shared_" + base.split("_", 1)[1]
+    return group_name(group, base)
+
+
+def _groups(cfg):
+    """(eager prefix kind, layer count) of each stacked decoder group."""
+    if getattr(cfg, "family", None) in ("dense", "moe"):
+        return [(GROUP_SITE_PREFIX[k], hi - lo) for k, lo, hi in cfg.layer_groups]
+    return [("layer", cfg.n_layers)]
 
 
 def _stack_qparams(cfg, masks: Dict[str, np.ndarray],
                    factors: Dict[str, np.ndarray],
                    buffers: Optional[Dict[str, dict]] = None
                    ) -> Dict[str, Any]:
-    """{bare site: [L, ch]} stacked state for scanned layer loops, built from
-    eager 'layer{i}/...' entries that cover every decoder layer.  Kernel
-    buffers stack field-wise under '{site}@fused' (layers whose packed
-    widths differ are first padded to a uniform K_pad with inert blocks)."""
+    """{site: [L, ch]} stacked state for scanned layer loops, built per
+    layer group from eager '<kind>{i}/...' entries that cover every layer
+    of the group.  Kernel buffers stack field-wise under '{site}@fused'
+    (layers whose packed widths differ are first padded to a uniform K_pad
+    with inert blocks)."""
     out: Dict[str, Any] = {}
-    for source, suffix in ((masks, ""), (factors, "@smooth")):
-        bases = {split_site(s)[2] for s in source
-                 if split_site(s)[0] == "layer"}
+    buffers = buffers or {}
+    for kind, n in _groups(cfg):
+        for source, suffix in ((masks, ""), (factors, "@smooth")):
+            bases = {split_site(s)[2] for s in source
+                     if split_site(s)[0] == kind}
+            for base in sorted(bases):
+                vals = [source.get(f"{kind}{i}/{base}") for i in range(n)]
+                if any(v is None for v in vals):
+                    continue            # partial coverage: eager path only
+                out[_scan_key(cfg, kind, base) + suffix] = np.stack(
+                    [np.asarray(v) for v in vals])
+        bases = {split_site(s)[2] for s in buffers
+                 if split_site(s)[0] == kind}
         for base in sorted(bases):
-            vals = [source.get(f"layer{i}/{base}") for i in range(cfg.n_layers)]
+            vals = [buffers.get(f"{kind}{i}/{base}") for i in range(n)]
             if any(v is None for v in vals):
                 continue                # partial coverage: eager path only
-            out[_scan_key(cfg, base) + suffix] = np.stack(
-                [np.asarray(v) for v in vals])
-    buffers = buffers or {}
-    bases = {split_site(s)[2] for s in buffers if split_site(s)[0] == "layer"}
-    for base in sorted(bases):
-        vals = [buffers.get(f"layer{i}/{base}") for i in range(cfg.n_layers)]
-        if any(v is None for v in vals):
-            continue                    # partial coverage: eager path only
-        k_pad = max(dispatch.buffer_k_pad(v) for v in vals)
-        vals = [dispatch.pad_buffer_to(v, k_pad) for v in vals]
-        out[_scan_key(cfg, base) + "@fused"] = {
-            f: np.stack([v[f] for v in vals]) for f in dispatch.BUFFER_FIELDS}
+            k_pad = max(dispatch.buffer_k_pad(v) for v in vals)
+            vals = [dispatch.pad_buffer_to(v, k_pad) for v in vals]
+            out[_scan_key(cfg, kind, base) + "@fused"] = {
+                f: np.stack([v[f] for v in vals])
+                for f in dispatch.BUFFER_FIELDS}
     return out
 
 
@@ -407,7 +428,7 @@ def _fused_sites(cfg, params, policy: SitePolicy):
     shared block packs one buffer per execution instance (``shared{i}/``
     sites share the weight but carry per-instance masks)."""
     k_every = getattr(cfg, "shared_attn_every", 0) or 0
-    stacks = (("layer", cfg.n_layers),
+    stacks = (*_groups(cfg),
               ("enc", getattr(cfg, "n_enc_layers", 0) or 0),
               ("shared", sum(1 for i in range(cfg.n_layers)
                              if i % k_every == k_every - 1) if k_every else 0))

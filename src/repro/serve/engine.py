@@ -159,7 +159,8 @@ class ServeEngine:
         # packed kernel buffers an artifact built with prequantize=True
         # carries (rules whose patterns match no site here stay inert)
         if isinstance(self.ctx, QuantCtx):
-            bases = ["attn_qkv", "attn_out", "mlp_up", "mlp_down"]
+            bases = ["attn_qkv", "attn_q", "attn_kpe", "attn_out", "mlp_up",
+                     "mlp_down"]
             if cfg.family == "moe":
                 bases += ["moe_up", "moe_down"]
             names = bases + [f"layer{i}/{b}" for i in range(cfg.n_layers)
@@ -252,14 +253,25 @@ class ServeEngine:
                 in_specs=(P(), P(), kv_specs) + (P(),) * n_rest,
                 out_specs=(P(), kv_specs), check_vma=False)
 
+        # an expert model's steps also count the routed rows that land on
+        # held experts; they come back beside the step's tokens, in the
+        # same host fetch (``Scheduler`` adds them to ServeMetrics)
+        stats_kw = {"stats": True} if cfg.n_experts else {}
+
+        def out(step_out, last: bool):
+            logits, new_kv = step_out[:2]
+            counts = step_out[2] if len(step_out) > 2 else {}
+            nxt = jnp.argmax((logits[:, -1] if last else logits)
+                             [..., : cfg.vocab_size], axis=-1)
+            return (nxt.astype(jnp.int32), counts), new_kv
+
         def decode_body(weights, tokens, kv, page_table, pos):
             params, qparams = weights
             with SS.head_sharding(shard):
-                logits, new_kv = T.decode_step_paged(cfg, params, tokens, kv,
-                                                     page_table, pos, self.ctx,
-                                                     qparams=qparams)
-            nxt = jnp.argmax(logits[:, -1, : cfg.vocab_size], axis=-1)
-            return nxt.astype(jnp.int32), new_kv
+                return out(T.decode_step_paged(cfg, params, tokens, kv,
+                                               page_table, pos, self.ctx,
+                                               qparams=qparams, **stats_kw),
+                           last=True)
 
         decode_step = tp_wrap(decode_body, 2)
 
@@ -278,11 +290,10 @@ class ServeEngine:
                          write_hi):
             params, qparams = weights
             with SS.head_sharding(shard):
-                logits, new_kv = T.prefill_chunk_paged(
+                return out(T.prefill_chunk_paged(
                     cfg, params, tokens, kv, page_table, start, write_lo,
-                    write_hi, self.ctx, qparams=qparams)
-            nxt = jnp.argmax(logits[:, :, : cfg.vocab_size], axis=-1)
-            return nxt.astype(jnp.int32), new_kv
+                    write_hi, self.ctx, qparams=qparams, **stats_kw),
+                    last=False)
 
         prefill_step = tp_wrap(prefill_body, 4)
 
@@ -300,11 +311,9 @@ class ServeEngine:
         def verify_body(weights, tokens, kv, page_table, pos, n_valid):
             params, qparams = weights
             with SS.head_sharding(shard):
-                logits, new_kv = T.decode_verify_paged(
+                return out(T.decode_verify_paged(
                     cfg, params, tokens, kv, page_table, pos, n_valid,
-                    self.ctx, qparams=qparams)
-            nxt = jnp.argmax(logits[:, :, : cfg.vocab_size], axis=-1)
-            return nxt.astype(jnp.int32), new_kv
+                    self.ctx, qparams=qparams, **stats_kw), last=False)
 
         verify_step = tp_wrap(verify_body, 3)
 

@@ -16,17 +16,19 @@ from repro.models.common import ModelConfig
 from repro.models.attention import init_cache, n_attn_layers  # noqa: F401
 
 
+def quantize_rows(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """[..., dh] -> int8 [..., dh] + f32 abs-max scale [..., 1] per row."""
+    amax = jnp.maximum(jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1,
+                               keepdims=True), 1e-6)
+    s = amax / 127.0
+    xi = jnp.clip(jnp.round(x.astype(jnp.float32) / s), -127, 127)
+    return xi.astype(jnp.int8), s.astype(jnp.float32)
+
+
 def quantize_kv(k: jnp.ndarray, v: jnp.ndarray) -> Dict[str, jnp.ndarray]:
     """[..., kv, dh] bf16 -> int8 + f32 scales over the head_dim axis."""
-    def q(x):
-        amax = jnp.maximum(jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1,
-                                   keepdims=True), 1e-6)
-        s = amax / 127.0
-        xi = jnp.clip(jnp.round(x.astype(jnp.float32) / s), -127, 127)
-        return xi.astype(jnp.int8), s.astype(jnp.float32)
-
-    ki, ks = q(k)
-    vi, vs = q(v)
+    ki, ks = quantize_rows(k)
+    vi, vs = quantize_rows(v)
     return {"k": ki, "k_scale": ks, "v": vi, "v_scale": vs}
 
 

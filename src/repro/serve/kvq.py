@@ -32,11 +32,17 @@ V.  The pooled stats persist as the ``kv_calib`` section of the
 ``QuantArtifact`` bundle and flow into :class:`Int4KVQuantizer` at pool
 construction (``ServeEngine`` -> ``PagePool``).
 
+**Latent pages** (``latent=True``, latent attention): one row per
+position holds the normed latent and the rotated rotary key, shared by
+every head, under the single key ``k`` (``[..., 1, ps, width]``); fp and
+int8 modes only, int8 with one float32 scale per position.
+
 Inside traced model code the mode is discovered from the cache dict's key
 set (:func:`from_cache`): int4 pages carry per-layer ``k_redist``/
 ``v_redist`` rows, int8 pages carry ``k_scale`` without them, fp pages
-carry neither — the same sentinel convention the scan bodies in
-``models/transformer.py`` thread through ``lax.scan``.
+carry neither, and latent pages have no ``v`` — the same sentinel
+convention the scan bodies in ``models/transformer.py`` thread through
+``lax.scan``.
 
 **Head-locality.**  Every quantity here is local to one (position, head)
 cell (int8 scales) or one head row (int4 redist rows + masks) — nothing
@@ -108,6 +114,7 @@ class KVQuantizer:
     key set (possibly gathered, with extra leading dims)."""
 
     mode: str = "fp"
+    latent: bool = False        # one shared latent row per position, no V
     # symmetric integer ceiling of the mode's codes (None for fp pages);
     # the quality observer (repro.obs.quality) reads this to count
     # saturated codes when it samples live pool pages
@@ -145,22 +152,29 @@ class KVQuantizer:
 class FpKVQuantizer(KVQuantizer):
     mode = "fp"
 
-    def __init__(self, dtype=jnp.bfloat16):
-        self.dtype = dtype
+    def __init__(self, dtype=jnp.bfloat16, latent: bool = False):
+        self.dtype, self.latent = dtype, latent
 
-    def quantize(self, k, v):
+    def quantize(self, k, v=None):
+        if self.latent:
+            return {"k": k.astype(self.dtype)}
         return {"k": k.astype(self.dtype), "v": v.astype(self.dtype)}
 
     def dequantize(self, parts, dtype):
+        if self.latent:
+            return parts["k"].astype(dtype), None
         return parts["k"].astype(dtype), parts["v"].astype(dtype)
 
     def page_arrays(self, L, n_pages, ps, kvh, dh):
         shape = (L, n_pages, kvh, ps, dh)
-        return {"k": jnp.zeros(shape, self.dtype),
-                "v": jnp.zeros(shape, self.dtype)}
+        out = {"k": jnp.zeros(shape, self.dtype)}
+        if not self.latent:
+            out["v"] = jnp.zeros(shape, self.dtype)
+        return out
 
     def bytes_per_token(self, kvh, dh):
-        return 2 * kvh * dh * jnp.dtype(self.dtype).itemsize
+        return (1 if self.latent else 2) * kvh * dh * jnp.dtype(
+            self.dtype).itemsize
 
 
 class Int8KVQuantizer(KVQuantizer):
@@ -170,26 +184,38 @@ class Int8KVQuantizer(KVQuantizer):
     mode = "int8"
     qmax = 127
 
-    def quantize(self, k, v):
-        from repro.serve.kvcache import quantize_kv
+    def __init__(self, latent: bool = False):
+        self.latent = latent
+
+    def quantize(self, k, v=None):
+        from repro.serve.kvcache import quantize_kv, quantize_rows
+        if self.latent:
+            ki, ks = quantize_rows(k)
+            return {"k": ki, "k_scale": ks}
         return quantize_kv(k, v)
 
     def dequantize(self, parts, dtype):
         k = (parts["k"].astype(jnp.float32) * parts["k_scale"]).astype(dtype)
+        if self.latent:
+            return k, None
         v = (parts["v"].astype(jnp.float32) * parts["v_scale"]).astype(dtype)
         return k, v
 
     def page_arrays(self, L, n_pages, ps, kvh, dh):
         shape = (L, n_pages, kvh, ps, dh)
-        return {"k": jnp.zeros(shape, jnp.int8),
-                "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32),
-                "v_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32)}
+        names = ("k",) if self.latent else ("k", "v")
+        out = {n: jnp.zeros(shape, jnp.int8) for n in names}
+        out.update({f"{n}_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32)
+                    for n in names})
+        return out
 
     def bytes_per_token(self, kvh, dh):
-        return 2 * kvh * (dh + 4)          # int8 payload + f32 scale
+        # int8 payload + f32 scale, for K and V (a latent row: once)
+        return (1 if self.latent else 2) * kvh * (dh + 4)
 
     def kernel_operands(self, cache):
+        if self.latent:
+            return {"k_scale": cache["k_scale"]}
         return {"k_scale": cache["k_scale"], "v_scale": cache["v_scale"]}
 
 
@@ -265,15 +291,20 @@ def redist_from_mask(mask, exp_factor: int = DEFAULT_EXP_FACTOR) -> np.ndarray:
 
 
 def make_quantizer(mode: str, *, kvh: int, dh: int, dtype=jnp.bfloat16,
-                   calib: Optional[Dict[str, np.ndarray]] = None) -> KVQuantizer:
+                   calib: Optional[Dict[str, np.ndarray]] = None,
+                   latent: bool = False) -> KVQuantizer:
     """Quantizer for a pool mode.  ``calib`` is the artifact's ``kv_calib``
     section (see :func:`build_kv_calib`); int4 without calibration runs with
     identity redistribution (plain symmetric int4) — lossier, but the mode
-    stays usable for fp-weight serving and uncalibrated tests."""
+    stays usable for fp-weight serving and uncalibrated tests.  ``latent``
+    pages (fp or int8) hold one shared row per position."""
     if mode == "fp":
-        return FpKVQuantizer(dtype)
+        return FpKVQuantizer(dtype, latent)
     if mode == "int8":
-        return Int8KVQuantizer()
+        return Int8KVQuantizer(latent)
+    if mode == "int4" and latent:
+        raise ValueError("int4 pages hold per-head K and V rows; latent "
+                         "pages take kv_mode 'fp' or 'int8'")
     if mode == "int4":
         e = int(calib["exp_factor"]) if calib and "exp_factor" in calib \
             else DEFAULT_EXP_FACTOR
@@ -292,9 +323,10 @@ def from_cache(cache: Dict[str, jnp.ndarray]) -> KVQuantizer:
     redistribution rows mean int4, bare scales mean int8, else fp."""
     if "k_redist" in cache:
         return Int4KVQuantizer(cache["k_redist"], cache["v_redist"])
+    latent = "v" not in cache
     if "k_scale" in cache:
-        return Int8KVQuantizer()
-    return FpKVQuantizer(cache["k"].dtype)
+        return Int8KVQuantizer(latent)
+    return FpKVQuantizer(cache["k"].dtype, latent)
 
 
 # ---------------------------------------------------------------------------

@@ -73,6 +73,10 @@ _COUNTERS = (
     "attn_pages_bucket",   # slots x page bucket: the (slot, page) grid
     "attn_pages_live",     # of those, pages up to each slot's last live
                            # page: the ones the kernel reads
+    # expert layers holding a share of the experts: routed rows of live
+    # tokens that landed on a held expert, and expert-layer calls
+    "moe_rows_held",
+    "moe_expert_calls",
     # prefix sharing
     "prefix_hits",         # admissions that mapped shared pages
     "shared_pages_mapped",  # pages mapped instead of allocated

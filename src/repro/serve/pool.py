@@ -30,8 +30,15 @@ the paged-attention kernel loads whole):
   k/v_redist [L, kvh, dh] f32                     (int4 only; NOT pages —
                                                    per-head channel
                                                    redistribution rows)
+  latent attention: k [L, n_pages, 1, page_size, latent + rope] (+ int8
+                    k_scale), one shared row per position, no v
   page_table [n_slots, pages_per_slot] int32   host-side, 0 = unallocated
   refcount   [n_pages] int32                   host-side page sharing state
+
+A model whose layers form several groups (``cfg.layer_groups``: an expert
+model's leading dense layers) holds one set of these arrays per group, the
+main group's under the bare names and the others' as ``<group>/<name>``
+(``common.group_name``), so each group's layer scan takes its own.
 
 Page 0 is a reserved scratch page: inactive slots' decode writes land
 there and are never read back, which keeps the pooled step shape-stable
@@ -72,8 +79,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.common import ModelConfig
-from repro.models.attention import n_attn_layers
+from repro.models.common import ModelConfig, group_name
+from repro.models.attention import cache_rows
 from repro.parallel import serve_sharding as SS
 from repro.serve import kvq
 from repro.serve.kvcache import cache_bytes
@@ -109,17 +116,24 @@ class PagePool:
         if self.n_pages < 2:
             raise ValueError("pool needs at least one allocatable page")
 
-        L, kvh, dh = n_attn_layers(cfg), cfg.n_kv_heads, cfg.head_dim
+        kvh, dh = cache_rows(cfg)
         self.quantizer = kvq.make_quantizer(mode, kvh=kvh, dh=dh,
-                                            dtype=dtype, calib=kv_calib)
-        self.kv: Dict[str, jnp.ndarray] = self.quantizer.page_arrays(
-            L, self.n_pages, page_size, kvh, dh)
+                                            dtype=dtype, calib=kv_calib,
+                                            latent=cfg.is_mla)
+        self.kv: Dict[str, jnp.ndarray] = {}
+        state: Dict[str, jnp.ndarray] = {}
+        for key, lo, hi in cfg.layer_groups:
+            pages = self.quantizer.page_arrays(hi - lo, self.n_pages,
+                                               page_size, kvh, dh)
+            self.kv.update({group_name(key, n): a for n, a in pages.items()})
+            state.update({group_name(key, n): a for n, a in
+                          self.quantizer.pool_state(hi - lo, kvh, dh).items()})
         # keys whose second axis indexes pages (COW copies / prefill
         # scatters / read-bytes pricing touch these ONLY); the rest of
         # self.kv is per-pool state like the int4 redistribution rows,
         # stacked [L, ...] so it rides the same scan xs as the pages
         self._page_keys = tuple(self.kv)
-        self.kv.update(self.quantizer.pool_state(L, kvh, dh))
+        self.kv.update(state)
         # tensor-parallel placement: on a ("model",) mesh the pages, scales
         # and int4 redistribution rows shard on the kvh axis via the
         # parallel/serve_sharding spec builder (kvh % mesh -> replicated

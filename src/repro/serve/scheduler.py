@@ -102,6 +102,7 @@ import math
 import time
 from typing import Callable, List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -185,6 +186,7 @@ class Scheduler:
         self.pool = pool
         self.prefill = prefill_fn
         self.decode = decode_fn
+        self._counts: dict = {}       # device counters of this iteration
         self.verify = verify_fn
         self.eos = eos
         self.metrics = metrics if metrics is not None else ServeMetrics()
@@ -325,6 +327,7 @@ class Scheduler:
             return step_clock + 1 if queue else step_clock
 
         cow0 = self.pool.cow_count      # step-record COW delta baseline
+        self._counts = {}               # the iteration's device counters
         # up to prefill_slots prefilling slots advance one chunk each,
         # batched into ONE traced call — the per-step prompt-token
         # budget that keeps decode flowing under a long-prompt flood
@@ -371,7 +374,7 @@ class Scheduler:
                                           pos)
                 self.pool.adopt(new_kv)
             with rec.span("sched.decode.wait"):
-                outs = np.asarray(nxt)
+                outs = self._fetch(nxt)
             with rec.span("sched.decode.emit"):
                 m.decode_steps += 1
                 m.decode_slot_steps += len(active)
@@ -402,13 +405,24 @@ class Scheduler:
                     prefill_slot=pf_slots[0] if pf_slots else None,
                     chunk_bucket=pf.get("chunk_bucket", 0),
                     prefill_page_bucket=pf.get("page_bucket", 0),
-                    cow=self.pool.cow_count - cow0)
+                    cow=self.pool.cow_count - cow0, **self._counts)
             if self.quality is not None:
                 self.quality.maybe_sample_pool(self.pool, step_clock)
             live = {i: (int(self.pos[i]) if not s.prefilling else s.pre_pos)
                     for i, s in enumerate(self.slots) if s}
             m.sample_pool(self.pool.stats(live))
         return step_clock + 1
+
+    def _fetch(self, out) -> np.ndarray:
+        """A step's tokens on the host.  A step returns (tokens, counts):
+        its device counters (an expert model's ``moe_*`` rows) come back
+        in the same fetch and add to ServeMetrics and the iteration's
+        step record."""
+        toks, counts = jax.device_get(out)
+        for name, v in counts.items():
+            setattr(self.metrics, name, getattr(self.metrics, name) + int(v))
+            self._counts[name] = self._counts.get(name, 0) + int(v)
+        return toks
 
     # -- admission -----------------------------------------------------------
 
@@ -638,7 +652,7 @@ class Scheduler:
             nxt, new_kv = self.prefill(toks, self.pool.state(), *rest)
             self.pool.adopt(new_kv)
         with rec.span("sched.prefill.wait"):
-            outs = np.asarray(nxt)          # [n_slots, cb]
+            outs = self._fetch(nxt)         # [n_slots, cb]
         with rec.span("sched.prefill.emit"):
             self._prefill_emit(ns, cb, pb, outs, step_clock)
         return {"slots": sorted(ns), "chunk_bucket": cb, "page_bucket": pb}
@@ -837,7 +851,7 @@ class Scheduler:
                                       n_valid)
             self.pool.adopt(new_kv)
         with rec.span("sched.verify.wait"):
-            outs = np.asarray(nxt)              # [n_slots, kb]
+            outs = self._fetch(nxt)             # [n_slots, kb]
         with rec.span("sched.verify.emit"):
             m.decode_steps += 1
             m.decode_slot_steps += len(active)

@@ -494,3 +494,62 @@ def test_fused_hybrid_shared_block():
     assert bool(jnp.all(jnp.isfinite(lg)))
     assert ctx.backend_log["shared0/attn_qkv"] == "fused"
     assert ctx.backend_log["layer0/ssm_in_zx"] == "fused"
+
+
+# ---------------------------------------------------------------------------
+# The grouped (expert-axis) muxq_gemm
+# ---------------------------------------------------------------------------
+
+def _grouped_case(experts, n_held=4, k=96, n=64, seed=0):
+    """Rows of ``experts`` [t, top_k] (held-local ids; out of range: held
+    elsewhere) laid out by ``moe.expert_rows``, and a packed [E, K, N]
+    buffer with a calibrated outlier channel."""
+    from repro.models.moe import expert_rows, row_tile
+    rng = np.random.default_rng(seed)
+    experts = jnp.asarray(experts, jnp.int32)
+    t, top = experts.shape
+    keep = (experts >= 0) & (experts < n_held)
+    rows = expert_rows(experts, keep, n_held, row_tile(t * top))
+    x = rng.standard_normal((t + 1, k)).astype(np.float32)
+    x[:, 5] *= 20.0                                   # an outlier channel
+    x[t] = 0.0                                        # the padding row
+    w = rng.standard_normal((n_held, k, n)).astype(np.float32) / np.sqrt(k)
+    mask = np.zeros(k, bool)
+    mask[5] = True
+    buf = dispatch.pack_site_buffer(jnp.asarray(w), mask, FUSED)
+    return jnp.asarray(x)[rows["row_token"]], buf, rows, w, keep
+
+
+@pytest.mark.parametrize("case", ["spread", "one_empty", "all_to_one",
+                                  "ragged"])
+def test_grouped_muxq_gemm_interpret_vs_oracle(case):
+    """The kernel's expert axis against the jnp oracle (one integer
+    ragged_dot) on every row a tile in use holds: rows spread over the
+    experts, one held expert with no rows (its tile is streamed, its rows
+    are padding), every row to one expert, and a row count that is no
+    multiple of the 32-row tile."""
+    rng = np.random.default_rng(1)
+    t = {"spread": 16, "one_empty": 16, "all_to_one": 24, "ragged": 37}[case]
+    experts = rng.integers(-2, 6, (t, 3))
+    if case == "one_empty":
+        experts = np.where(experts == 2, 3, experts)
+    if case == "all_to_one":
+        experts = np.stack([np.full(t, 1), np.full(t, 7), np.full(t, -1)], 1)
+    x, buf, rows, w, keep = _grouped_case(experts)
+    used = int(rows["n_used"][0]) * (x.shape[0] // rows["tile_expert"].shape[0])
+    got = dispatch.fused_gmm(x, buf, rows, impl="interpret")
+    ref = dispatch.fused_gmm(x, buf, rows, impl="ref")
+    np.testing.assert_allclose(np.asarray(got)[:used], np.asarray(ref)[:used],
+                               rtol=1e-6, atol=1e-6)
+    # every kept pair's row holds its token through its own expert's weight
+    pair = np.asarray(rows["pair_row"])
+    own = np.repeat(np.asarray(rows["tile_expert"]),
+                    x.shape[0] // rows["tile_expert"].shape[0])
+    for ti, j in zip(*np.nonzero(np.asarray(keep))):
+        r = pair[ti, j]
+        assert own[r] == experts[ti, j]
+        want = np.asarray(x)[r] @ w[experts[ti, j]]
+        np.testing.assert_allclose(np.asarray(got)[r], want, rtol=0.05,
+                                   atol=0.05 * np.abs(want).max())
+    if case == "one_empty":
+        assert int(np.asarray(rows["group_sizes"])[2]) == 32   # one tile

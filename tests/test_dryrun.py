@@ -132,3 +132,17 @@ def test_moe_param_count_active_vs_total():
     from repro.configs import get_config
     cfg = get_config("dbrx-132b")
     assert param_count(cfg, active_only=True) < param_count(cfg)
+
+
+def test_moonlight_share_lowers_through_the_paged_path():
+    """Moonlight-16B-A3B at one chip's cut sizes (the dense layer and 12
+    expert layers, 8 of 64 experts held, a 20480-id vocabulary) lowers
+    through the engine's pooled decode: latent attention on int8 latent
+    pages, the leading dense group and the held-expert layer."""
+    from repro.launch.dryrun import lower_paged_cell
+    cell = lower_paged_cell("moonlight-16b-a3b", 1, kv_mode="int8", cut={
+        "n_layers": 13, "n_held": 8, "vocab_size": 20480})
+    assert cell["lowered"]
+    # one 576-wide int8 row and a float32 scale per position and layer:
+    # 13 layers x (2 slots x 8 pages + the scratch page) x 16 positions
+    assert cell["cache_bytes"] == 13 * (2 * 8 + 1) * 16 * 580

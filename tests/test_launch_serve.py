@@ -285,3 +285,15 @@ def test_compile_cache_placement(monkeypatch, backend, env, want):
         assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
     else:
         assert updates == []
+
+
+def test_moonlight_reduced_serves_end_to_end(capsys):
+    """``--arch moonlight-16b-a3b --reduced``: calibrate, MUXQ-quantize and
+    serve the latent-attention expert model (a leading dense layer, 4 of
+    16 experts held) through the real engine, scheduler and int8 latent
+    pages on the CPU."""
+    assert L.main(["--arch", "moonlight-16b-a3b", "--reduced", "--max-new",
+                   "3", "--prompts", "latent pages", "held experts"]) == 0
+    out = capsys.readouterr().out
+    assert out.count("(3 tokens)") == 2
+    assert "kv pages [int8]" in out

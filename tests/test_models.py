@@ -142,12 +142,23 @@ def test_gemma2_features():
 
 
 def test_moe_capacity_dropless_at_inference():
-    """Inference dispatch must be dropless (decode parity depends on it);
-    training keeps the classic capacity factor + drops."""
-    from repro.models.moe import _capacity
+    """Inference dispatch must be dropless (decode parity depends on it):
+    the held experts' row tiles give every kept pair its own row, even when
+    every token picks the same experts; training keeps the classic
+    capacity factor + drops."""
+    from repro.models.moe import _capacity, expert_rows
     cfg = get_config("dbrx-132b", reduced=True)
+    e, k = cfg.n_experts, cfg.top_k
     for t in (3, 9, 64, 1000):
-        assert _capacity(cfg, t, factor=None) >= t      # can never drop
+        for experts in (np.stack([np.arange(k)] * t),          # all the same
+                        np.random.default_rng(t).integers(0, e, (t, k))):
+            keep = np.ones((t, k), bool)
+            lay = expert_rows(jnp.asarray(experts), jnp.asarray(keep), e, 32)
+            rows = np.asarray(lay["pair_row"]).reshape(-1)
+            assert len(set(rows.tolist())) == t * k           # none shared
+            assert rows.max() < np.asarray(lay["row_token"]).shape[0]
+            tok = np.asarray(lay["row_token"])[rows]
+            assert (tok == np.repeat(np.arange(t), k)).all()
     # capacity-factor sizing really is smaller (drops possible) at scale
     full = get_config("dbrx-132b")
     assert _capacity(full, 4096, factor=1.25) < 4096

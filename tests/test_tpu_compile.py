@@ -132,3 +132,83 @@ def test_paged_attention_compiles(one_chip, mode, widths, sq):
         lambda q, k, v, t, p, kw: paged_attention_pallas(q, k, v, t, p, **kw)
     ).lower(q, pages, pages, table, pos, kw).compile()
     assert tpu_kernels(compiled.as_text()) == {"paged_attention": 1}
+
+
+# Moonlight-16B-A3B's share (bench/configs/moonlight-16b-a3b-muxq.json):
+# the expert sites (8 experts held, width 1408: up K 2048 -> N 2816, down K
+# 1408 packed to 1536 -> N 2048) as the grouped kernel's expert axis, at
+# a decode batch of 32 slots (192 routed pairs, tiles of 32 rows) and a
+# prefill chunk step of 32 x 32 rows (6144 pairs, tiles of 128)
+MOON_EXPERTS, MOON_TOPK = 8, 6
+MOON_SITES = [(2048, 2048, 2816), (1408, 1536, 2048)]
+
+
+@pytest.mark.parametrize("tokens", [SLOTS * 4, SLOTS * 4 * CHUNK],
+                         ids=["decode", "prefill"])
+@pytest.mark.parametrize("k_in,k,n", MOON_SITES, ids=["moe_up", "moe_down"])
+def test_grouped_expert_site_compiles(one_chip, tokens, k_in, k, n):
+    """Rows sorted into expert tiles + rowwise_quantize + the muxq_gemm
+    kernel's expert axis (tile experts and tiles in use by scalar
+    prefetch), at the layout ``moe.expert_rows`` gives."""
+    from repro.models.moe import row_tile
+    pairs = tokens * MOON_TOPK
+    bm = row_tile(pairs)
+    tiles = -(-pairs // bm) + MOON_EXPERTS
+    buf = {"w_int": _shape(one_chip, (MOON_EXPERTS, k, n), jnp.int8),
+           "sw": _shape(one_chip, (MOON_EXPERTS, 1, n), jnp.float32),
+           "block_scale": _shape(one_chip, (k // BK,), jnp.int32),
+           "gather_idx": _shape(one_chip, (k,), jnp.int32),
+           "in_scale": _shape(one_chip, (k,), jnp.float32)}
+    rows = {"group_sizes": _shape(one_chip, (MOON_EXPERTS,), jnp.int32),
+            "tile_expert": _shape(one_chip, (tiles,), jnp.int32),
+            "n_used": _shape(one_chip, (1,), jnp.int32)}
+    x = _shape(one_chip, (tiles * bm, k_in), jnp.float32)
+    compiled = jax.jit(
+        lambda x, b, r: dispatch.fused_gmm(x, b, r, impl="pallas")
+    ).lower(x, buf, rows).compile()
+    assert tpu_kernels(compiled.as_text()) == {"rowwise_quantize": 1,
+                                               "muxq_gemm": 1}
+
+
+@pytest.mark.parametrize("m", [SLOTS * 4, SLOTS * 4 * CHUNK],
+                         ids=["decode", "prefill"])
+def test_wide_dense_site_compiles(one_chip, m):
+    """Moonlight's dense layer's down projection, K 11264 packed with an
+    outlier block to 11776: its float32 rows halve rowwise_quantize's row
+    block to stay inside the 16 MiB of scoped VMEM."""
+    k, n = 11776, 2048
+    buf = {"w_int": _shape(one_chip, (k, n), jnp.int8),
+           "sw": _shape(one_chip, (1, n), jnp.float32),
+           "block_scale": _shape(one_chip, (k // BK,), jnp.int32),
+           "gather_idx": _shape(one_chip, (k,), jnp.int32),
+           "in_scale": _shape(one_chip, (k,), jnp.float32)}
+    x = _shape(one_chip, (m, 11264), jnp.float32)
+    compiled = jax.jit(
+        lambda x, b: dispatch.fused_matmul(x, b, impl="pallas")
+    ).lower(x, buf).compile()
+    assert tpu_kernels(compiled.as_text()) == {"rowwise_quantize": 1,
+                                               "muxq_gemm": 1}
+
+
+@pytest.mark.parametrize("sq", [1, CHUNK], ids=["decode", "prefill"])
+@pytest.mark.parametrize("mode", ["fp", "int8"])
+def test_latent_paged_attention_compiles(one_chip, mode, sq):
+    """Moonlight's latent pages: one 576-wide row per position (512 latent
+    + 64 rotary) read as key, its first 512 columns as value, 16 query
+    heads, float32 queries, at 32 slots and a 256-page bucket (4096
+    positions)."""
+    slots, pages_per_slot, h, width, v_dim = 32, 256, 16, 576, 512
+    n_pages = slots * pages_per_slot + 1
+    dtype = jnp.bfloat16 if mode == "fp" else jnp.int8
+    pages = _shape(one_chip, (n_pages, 1, PS, width), dtype)
+    q = _shape(one_chip, (slots, sq, h, width), jnp.float32)
+    table = _shape(one_chip, (slots, pages_per_slot), jnp.int32)
+    pos = _shape(one_chip, (slots,), jnp.int32)
+    kw = {}
+    if mode == "int8":
+        kw["k_scale"] = _shape(one_chip, (n_pages, 1, PS, 1), jnp.float32)
+    compiled = jax.jit(
+        lambda q, k, t, p, kw: paged_attention_pallas(
+            q, k, None, t, p, v_dim=v_dim, scale=192 ** -0.5, **kw)
+    ).lower(q, pages, table, pos, kw).compile()
+    assert tpu_kernels(compiled.as_text()) == {"paged_attention": 1}
