@@ -5,6 +5,7 @@ interpret-mode Pallas is NOT timed; we time the jnp int8/fp32 paths and
 derive the analytic TPU speedup from the roofline constants)."""
 from __future__ import annotations
 
+import functools
 import time
 
 import jax
@@ -88,14 +89,16 @@ def run(emit=True):
     # steps it replaces; the prefill row prices one chunked-prefill read
     # through the page table.
     from repro.kernels import paged_attention as PA
+    from repro.serve import kvq
     kvh, dh, ps, npg = 4, 64, 16, 16
     rng = jax.random.PRNGKey(2)
-    kp = jax.random.normal(rng, (npg, kvh, ps, dh))
-    vp = jax.random.normal(jax.random.PRNGKey(3), (npg, kvh, ps, dh))
+    shape = kvq.page_shape(1, npg, ps, kvh, dh)        # the pool's layout
+    kp = jax.random.normal(rng, shape)
+    vp = jax.random.normal(jax.random.PRNGKey(3), shape)
     bsl, pages = 4, 4                                  # 4 slots x 4 pages
     tab = jnp.arange(bsl * pages, dtype=jnp.int32).reshape(bsl, pages)
     pos = jnp.full((bsl,), pages * ps - 8, jnp.int32)
-    f_pa = jax.jit(PA.paged_attention_ref)
+    f_pa = jax.jit(functools.partial(PA.paged_attention_ref, page_size=ps))
     q1 = jax.random.normal(jax.random.PRNGKey(4), (bsl, kvh, dh))
     us1 = _time(f_pa, q1, kp, vp, tab, pos)
     sk = 4

@@ -51,12 +51,11 @@ def set_kv_observer(fn) -> None:
 
 
 _ROUTING_KEYS = ("pos", "page_table", "start", "write_lo", "write_hi",
-                 "n_valid")
+                 "n_valid", "layer")
 
-# name scope of the paged steps' page writes.  XLA names the relayout of a
-# layer's written pages into the paged kernel's operand layout after the
-# write, so a profile finds both under this scope (the scan's own per-layer
-# slice and stack of the pool carry the scan's name).
+# name scope of the paged steps' pool traffic: each layer's in-place page
+# write and the gather of its scales for the kernel.  The pool rides the
+# layer loop's carry whole, so nothing else in a step touches it.
 POOL_SCOPE = "pool"
 
 
@@ -228,43 +227,53 @@ def attention_decode(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     return out, new_cache
 
 
-def attention_decode_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
-                           cache: dict, *, window_flag=False,
-                           sq: Optional[Dict] = None) -> Tuple[jnp.ndarray, dict]:
-    """Pool-wide one-token decode against a *paged* KV pool with a per-slot
-    position vector (the continuous-batching step — ``repro.serve``).
+def attention_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
+                    cache: dict, *, window_flag=False,
+                    sq: Optional[Dict] = None) -> Tuple[jnp.ndarray, dict]:
+    """One paged step's attention for the whole slot pool (the
+    continuous-batching steps of ``repro.serve``): per slot a one-token
+    decode, a speculative verify block or a chunk of its prompt, the
+    routing state naming which (:func:`paged_rows`).
 
-    x [b, 1, d].  ``cache`` holds one layer's page pool plus the pool-wide
+    x [b, s, d].  ``cache`` holds the layer group's whole stored pool
+    (``repro.serve.kvq``'s page layout: k/v ``[L, n_pages, R, lanes]``,
+    int8/int4 scales ``kvq.scale_shape``, int4 redistribution rows
+    ``[L, kvh, dh]``), the index ``layer`` of this layer in it, and the
     routing state:
 
-      k/v          [n_pages, kvh, page_size, dh]  (int8 pages carry
-      k/v_scale    [n_pages, kvh, page_size, 1]   per-(pos, head) scales)
-      page_table   [b, pages_per_slot] int32 — physical page per logical
-                   page; 0 is the reserved scratch page (inactive slots /
-                   unallocated tail)
-      pos          [b] int32 — per-slot sequence position (may differ per
-                   slot: misaligned sequences still batch into ONE step)
+      page_table   [b, pages] int32 — physical page per logical page,
+                   sliced to the step's bucketed read budget; 0 is the
+                   reserved scratch page (idle slots, unallocated tail)
+      pos          [b] int32 — decode / verify: each slot's first row's
+                   position (rows may differ per slot: misaligned
+                   sequences still batch into ONE step)
+      n_valid      [b] int32 — verify: real rows per slot (1 committed +
+                   its drafts; 0 parks a slot)
+      start, write_lo, write_hi  [b] int32 — prefill: the chunk's first
+                   position and the window of positions whose K/V lands in
+                   table pages
 
-    The new K/V is scattered into page ``page_table[b, pos//ps]`` at offset
-    ``pos % ps``; attention reads the slot's logical key range via a page
-    gather and masks per slot with ``kpos <= pos[b]`` (+ sliding window), so
-    no alignment between slots is ever required.
+    Every row's K/V is written into its page FIRST, in place (rows outside
+    a write window — chunk padding, prefix-shared positions, verify rows
+    past ``n_valid``, idle slots — route to scratch page 0, never read
+    back: one shape-stable scatter, no per-slot control flow), then ONE
+    kernel call attends each slot's ``[slot, s]`` query block over its
+    pages with a per-row causal mask (row i sits at position start + i),
+    so a query sees exactly the keys a sequential decode would, including
+    rows written this step.  Slots' write windows are disjoint, so
+    batching slots is bit-identical to running them one at a time;
+    rejected draft rows need no undo (per-slot ``pos`` is the source of
+    truth; the scheduler COWs shared pages before writing).
 
-    **Block-sparse reads**: the read budget is the page table's width — the
-    scheduler passes ``page_table[:, :bucket]`` where ``bucket`` covers the
-    longest live sequence's ``ceil(pos/ps)`` pages, so a short sequence in
-    a deep pool never gathers its slot's full logical capacity.  The read
-    side lives in :mod:`repro.kernels.paged_attention`: on TPU the Pallas
-    kernel (page-table-indexed K/V loads, int8 pages dequantized
-    in-kernel), on CPU the jnp gather reference — the fp-page serve tests
-    pin the reference bit-exact against the dense cache path."""
+    The read side lives in :mod:`repro.kernels.paged_attention`: on TPU
+    the Pallas kernel (page-table-indexed loads of the stored pool at this
+    layer, int8 / int4 pages dequantized in-kernel), on CPU the jnp gather
+    reference — the fp-page serve tests pin the reference bit-exact
+    against the dense cache path."""
     if cfg.is_mla:
         return mla_paged(cfg, p, ctx, x, cache, sq=sq)
     sq = sq or {}
-    b, one, d = x.shape
-    pos = cache["pos"]                                      # [b]
-    page_table = cache["page_table"]                        # [b, P]
-    ps = cache["k"].shape[2]
+    b, s, d = x.shape
     qkv = ctx("attn_qkv", x, p["wqkv"], mask=sq.get("attn_qkv"),
               smooth=sq.get("attn_qkv@smooth"), fused=sq.get("attn_qkv@fused"))
     if "bqkv" in p:
@@ -278,236 +287,48 @@ def attention_decode_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     shard = TP.active()
     if shard is not None:
         q, k, v = (TP.slice_heads(t, shard) for t in (q, k, v))
-    positions = pos[:, None].astype(jnp.int32)              # [b, 1]
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-
     # one quantize entry point for every page mode (fp/int8/int4) — the
     # same kvq seam the pool's prefill writes go through
-    quantizer = kvq.from_cache(cache)
-    parts = quantizer.quantize(k, v)
-
-    # scatter the new token's K/V into each slot's current page.  Inactive
-    # slots all route to scratch page 0 (never read back): duplicate indices
-    # there are harmless.
-    page_idx = jnp.take_along_axis(page_table, (pos // ps)[:, None], 1)[:, 0]
-    offset = pos % ps
-    with jax.named_scope(POOL_SCOPE):
-        new_cache = _write_cache(cache, {
-            n: cache[n].at[page_idx, :, offset].set(
-                parts[n][:, 0].astype(cache[n].dtype)) for n in parts})
-
-    # read path: the jnp gather reference on CPU, the Pallas kernel
-    # (page-table-indexed loads, in-kernel int8 dequant / int4 nibble
-    # unpack + inverse redistribution) on TPU/interpret — both in
-    # repro.kernels.paged_attention.  The traced per-layer window flag
-    # folds into an effective-window scalar either way.
+    quantizer = kvq.from_cache(cache, cache["layer"])
+    ps = kvq.page_size(cache["k"], k.shape[2],
+                       quantizer.stored_width(cfg.head_dim))
+    positions, page, offset, start = paged_rows(cache, s, ps)
+    q = apply_rope(q, positions, cfg.rope_theta)
+    k = apply_rope(k, positions, cfg.rope_theta)
+    # the traced per-layer window flag folds into an effective-window
+    # scalar for either read path
     win = jnp.where(jnp.asarray(window_flag), cfg.window_size,
                     PA.NO_WINDOW).astype(jnp.int32)
-    o = PA.paged_attention_decode(
-        q[:, 0], new_cache["k"], new_cache["v"], page_table, pos,
-        window=win, softcap=cfg.attn_softcap,
-        **quantizer.kernel_operands(new_cache))
+    o, new_cache = _paged_attend(cache, quantizer, quantizer.quantize(k, v),
+                                 page, offset, q, start, ps, window=win,
+                                 softcap=cfg.attn_softcap)
     if shard is not None:
         # zero-pad psum gather back to the full head axis (bit-exact:
         # every element = one shard's value + M-1 exact zeros), so the
         # attn_out projection sees the full per-token channel vector the
         # MUXQ per-token act-quant requires
         o = TP.all_heads(o, cfg.n_heads, shard)
-    o = o.reshape(b, 1, cfg.n_heads * cfg.head_dim)
+    o = o.reshape(b, s, cfg.n_heads * cfg.head_dim)
     out = ctx("attn_out", o, p["wo"], mask=sq.get("attn_out"),
               smooth=sq.get("attn_out@smooth"), fused=sq.get("attn_out@fused"))
     return out, new_cache
 
 
-def attention_verify_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
-                           cache: dict, *, window_flag=False,
-                           sq: Optional[Dict] = None) -> Tuple[jnp.ndarray, dict]:
-    """Pool-wide MULTI-token decode against a paged KV pool — the
-    speculative-decoding verify step (``repro.serve.scheduler``).
-
-    x [b, k, d]: per slot, the last committed token followed by up to
-    ``k - 1`` draft tokens (the scheduler's n-gram proposals).  ``cache``
-    is the pooled-decode routing state of :func:`attention_decode_paged`
-    plus ``n_valid`` [b] int32 — how many of the k rows are real for each
-    slot (1 committed + its draft length; 0 parks an inactive slot).
-
-    Row j of slot b sits at absolute position ``pos[b] + j``.  All k rows'
-    K/V scatter into the slot's pages FIRST (rows >= n_valid route to the
-    reserved scratch page 0), then ONE kernel call attends the whole
-    ``[slot, k]`` query block with a per-row causal mask — so row j reads
-    exactly the keys a sequential decode at position ``pos[b] + j`` would
-    see, including the rows written this step.  Rejected draft positions
-    need no undo: per-slot ``pos`` is the source of truth and their page
-    rows are simply overwritten when the slot's position reaches them
-    (the scheduler COWs shared pages before the k-token write)."""
-    if cfg.is_mla:
-        return mla_paged(cfg, p, ctx, x, cache, sq=sq)
-    sq = sq or {}
-    b, kb, d = x.shape
-    pos = cache["pos"]                                      # [b]
-    n_valid = cache["n_valid"]                              # [b]
-    page_table = cache["page_table"]                        # [b, P]
-    ps = cache["k"].shape[2]
-    qkv = ctx("attn_qkv", x, p["wqkv"], mask=sq.get("attn_qkv"),
-              smooth=sq.get("attn_qkv@smooth"), fused=sq.get("attn_qkv@fused"))
-    if "bqkv" in p:
-        qkv = qkv + p["bqkv"].astype(x.dtype)
-    q, k, v = _split_qkv(cfg, qkv)
-    # per-shard head slice under tensor-parallel serving (see
-    # attention_decode_paged — same contiguous GQA cut, same psum below)
-    shard = TP.active()
-    if shard is not None:
-        q, k, v = (TP.slice_heads(t, shard) for t in (q, k, v))
-    positions = pos[:, None] + jnp.arange(kb, dtype=jnp.int32)[None]  # [b, k]
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-
-    quantizer = kvq.from_cache(cache)
-    parts = quantizer.quantize(k, v)
-
-    # scatter all k rows into the slot's pages; rows past a slot's valid
-    # count (draft padding, parked slots) route to scratch page 0 — one
-    # shape-stable scatter, no per-slot control flow
-    logical = jnp.clip(positions // ps, 0, page_table.shape[1] - 1)
-    page = jnp.take_along_axis(page_table, logical, axis=1)           # [b, k]
-    valid = jnp.arange(kb, dtype=jnp.int32)[None] < n_valid[:, None]
-    page_idx = jnp.where(valid, page, 0)
-    offset = positions % ps
+def _paged_attend(cache: dict, quantizer, parts: dict, page, offset, q,
+                  start, ps: int, **kw):
+    """Write ``parts`` (quantized rows) into the pool in place at (this
+    layer, ``page``, ``offset``), then attend ``q`` over the table's pages
+    of this layer.  Returns (attention output, the new cache)."""
+    layer, table = cache["layer"], cache["page_table"]
+    kvh = parts["k"].shape[-2]
     with jax.named_scope(POOL_SCOPE):
-        new_cache = _write_cache(cache, {
-            n: cache[n].at[page_idx, :, offset].set(
-                parts[n].astype(cache[n].dtype)) for n in parts})
-
-    win = jnp.where(jnp.asarray(window_flag), cfg.window_size,
-                    PA.NO_WINDOW).astype(jnp.int32)
+        new_cache = _write_cache(cache, kvq.write_pages(
+            cache, parts, layer, page, offset, ps))
+        operands = quantizer.kernel_operands(new_cache, layer, table, kvh, ps)
     o = PA.paged_attention_decode(
-        q, new_cache["k"], new_cache["v"], page_table, pos,
-        window=win, softcap=cfg.attn_softcap,
-        **quantizer.kernel_operands(new_cache))
-    if shard is not None:
-        o = TP.all_heads(o, cfg.n_heads, shard)
-    o = o.reshape(b, kb, cfg.n_heads * cfg.head_dim)
-    out = ctx("attn_out", o, p["wo"], mask=sq.get("attn_out"),
-              smooth=sq.get("attn_out@smooth"), fused=sq.get("attn_out@fused"))
-    return out, new_cache
-
-
-def attention_prefill_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
-                            cache: dict, *, window_flag=False,
-                            sq: Optional[Dict] = None) -> Tuple[jnp.ndarray, dict]:
-    """Chunk-of-prompt prefill straight into a *paged* KV pool — the
-    chunked counterpart of :func:`attention` + :func:`attention_decode_paged`
-    (``repro.serve``'s admission path; there is no dense ``[1, T]`` prefill
-    cache anymore).
-
-    x [b, C, d] — per prefilling slot, one chunk of that slot's prompt (C
-    is the scheduler's bucketed chunk shape; the tail beyond a slot's valid
-    tokens is padding, and slots not advancing this step are all-padding
-    rows).  ``cache`` holds one layer's page pool plus routing state:
-
-      k/v          [n_pages, kvh, ps, dh]  (int8 pages carry
-      k/v_scale    [n_pages, kvh, ps, 1]   per-(pos, head) scales)
-      page_table   [b, pages] int32 — the prefilling slots' page-table
-                   rows, sliced to the step's bucketed page budget (rows
-                   of idle slots are all scratch page 0)
-      start        [b] int32 — absolute position of each slot's chunk's
-                   first token
-      write_lo/hi  [b] int32 — per-slot absolute position window whose K/V
-                   lands in table pages; everything else (chunk padding,
-                   positions already covered by prefix-shared pages, idle
-                   slots with an empty ``write_lo == write_hi`` window)
-                   routes to the reserved scratch page 0 and is never
-                   read back
-
-    Each slot's chunk K/V is scattered into its pages FIRST (one
-    shape-stable ``[slot, C]`` scatter — the same query-block trick as
-    :func:`attention_verify_paged`), then ONE kernel call attends every
-    slot's whole logical key range through the page table with a per-slot
-    start-offset causal mask — so a query only ever sees keys at positions
-    <= its own, which earlier chunks (or the shared prefix) already wrote.
-    Slots' write windows are disjoint (each covers only pages that slot
-    exclusively owns), so batching N slots into one call is bit-identical
-    to running them sequentially.  Masked lanes underflow to exactly 0 in
-    the softmax, so fp pages at the compute dtype reproduce the old
-    full-prompt dense prefill bit for bit (the parity oracle the serve
-    tests pin).
-
-    Back compat: a 1-D ``page_table`` [pages] with scalar
-    ``start``/``write_lo``/``write_hi`` (the pre-multi-slot single-request
-    form) is normalized to the batched shapes with b=1."""
-    if cfg.is_mla:
-        return mla_paged(cfg, p, ctx, x, cache, sq=sq)
-    sq = sq or {}
-    b, C, d = x.shape
-    ps = cache["k"].shape[2]
-    start = jnp.asarray(cache["start"], jnp.int32)
-    write_lo = jnp.asarray(cache["write_lo"], jnp.int32)
-    write_hi = jnp.asarray(cache["write_hi"], jnp.int32)
-    page_table = cache["page_table"]
-    if page_table.ndim == 1:                                # legacy [P] form
-        page_table = page_table[None]
-    if start.ndim == 0:
-        start = jnp.reshape(start, (1,))
-        write_lo = jnp.reshape(write_lo, (1,))
-        write_hi = jnp.reshape(write_hi, (1,))
-    n_pages_budget = page_table.shape[1]
-    qkv = ctx("attn_qkv", x, p["wqkv"], mask=sq.get("attn_qkv"),
-              smooth=sq.get("attn_qkv@smooth"), fused=sq.get("attn_qkv@fused"))
-    if "bqkv" in p:
-        qkv = qkv + p["bqkv"].astype(x.dtype)
-    q, k, v = _split_qkv(cfg, qkv)
-    # per-shard head slice under tensor-parallel serving (see
-    # attention_decode_paged — same contiguous GQA cut, same psum below)
-    shard = TP.active()
-    if shard is not None:
-        q, k, v = (TP.slice_heads(t, shard) for t in (q, k, v))
-    p_abs = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None]   # [b, C]
-    positions = p_abs
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-
-    quantizer = kvq.from_cache(cache)
-    parts = quantizer.quantize(k, v)
-
-    # scatter every slot's chunk K/V into its pages.  Positions outside a
-    # slot's write window (chunk tail padding past the prompt, prefix-shared
-    # positions whose pages are mapped read-only, idle slots' empty windows)
-    # route to scratch page 0, which is never read back — same trick as the
-    # pooled decode's inactive slots, so the write is one shape-stable
-    # [slot, C] scatter with no control flow.
-    writable = (p_abs >= write_lo[:, None]) & (p_abs < write_hi[:, None])
-    logical = jnp.clip(p_abs // ps, 0, n_pages_budget - 1)
-    page = jnp.take_along_axis(page_table, logical, axis=1)         # [b, C]
-    page_idx = jnp.where(writable, page, 0)
-    offset = p_abs % ps
-    with jax.named_scope(POOL_SCOPE):
-        new_cache = _write_cache(cache, {
-            n: cache[n].at[page_idx, :, offset].set(
-                parts[n].astype(cache[n].dtype)) for n in parts})
-
-    # read every slot's whole logical key range [0, pages*ps) through the
-    # page table with the per-slot start-offset causal mask — the same
-    # [slot, sq] query-block kernel as decode/verify, with sq=C and
-    # pos=start [b].  On CPU the jnp gather reference reproduces the old
-    # gather→dequantize→sdpa op sequence exactly (extra gathered keys past
-    # a query's position are NEG_INF-masked and underflow to exactly 0, so
-    # fp pages stay bit-exact); on TPU/interpret the flash-style Pallas
-    # kernel streams key pages through scalar prefetch with online softmax
-    # and in-kernel int8 / int4-nibble dequant + inverse outlier
-    # redistribution.
-    win = jnp.where(jnp.asarray(window_flag), cfg.window_size,
-                    PA.NO_WINDOW).astype(jnp.int32)
-    o = PA.paged_attention_decode(
-        q, new_cache["k"], new_cache["v"], page_table, start,
-        window=win, softcap=cfg.attn_softcap,
-        **quantizer.kernel_operands(new_cache))
-    if shard is not None:
-        o = TP.all_heads(o, cfg.n_heads, shard)
-    o = o.reshape(b, C, cfg.n_heads * cfg.head_dim)
-    out = ctx("attn_out", o, p["wo"], mask=sq.get("attn_out"),
-              smooth=sq.get("attn_out@smooth"), fused=sq.get("attn_out@fused"))
-    return out, new_cache
+        q, new_cache["k"], new_cache.get("v"), table, start, page_size=ps,
+        layer=layer, **operands, **kw)
+    return o, new_cache
 
 
 def cross_attention(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
@@ -651,14 +472,13 @@ def mla_attention(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray,
     return mla_output(cfg, p, ctx, o, sq), cache
 
 
-def paged_rows(cache: dict, s: int):
-    """Where a paged step's rows sit and write, from its routing state:
-    (positions [b, s], write page [b, s] (0: scratch, never read), offset
-    in the page [b, s], first query row's position [b]).  The state names
-    the step: ``write_lo`` a prefill chunk, ``n_valid`` a verify block,
-    else a one-token decode."""
+def paged_rows(cache: dict, s: int, ps: int):
+    """Where a paged step's rows sit and write, from its routing state and
+    the page size ``ps``: (positions [b, s], write page [b, s] (0:
+    scratch, never read), offset in the page [b, s], first query row's
+    position [b]).  The state names the step: ``write_lo`` a prefill
+    chunk, ``n_valid`` a verify block, else a one-token decode."""
     page_table = cache["page_table"]
-    ps = cache["k"].shape[2]
     rows = jnp.arange(s, dtype=jnp.int32)[None]
     if "write_lo" in cache:
         start = jnp.asarray(cache["start"], jnp.int32)
@@ -684,16 +504,13 @@ def mla_paged(cfg: ModelConfig, p: dict, ctx, x: jnp.ndarray, cache: dict,
     with the latent as key and its first ``kv_lora_rank`` columns as
     value."""
     sq = sq or {}
-    positions, page, offset, start = paged_rows(cache, x.shape[1])
+    _, width = cache_rows(cfg)
+    ps = kvq.page_size(cache["k"], 1, width)
+    positions, page, offset, start = paged_rows(cache, x.shape[1], ps)
     q, lat = mla_project(cfg, p, ctx, x, positions, sq)
     quantizer = kvq.from_cache(cache)
-    parts = quantizer.quantize(lat[:, :, None])
-    with jax.named_scope(POOL_SCOPE):
-        new_cache = _write_cache(cache, {
-            n: cache[n].at[page, :, offset].set(parts[n].astype(cache[n].dtype))
-            for n in parts})
-    o = PA.paged_attention_decode(
-        q, new_cache["k"], None, cache["page_table"], start,
-        v_dim=cfg.kv_lora_rank, scale=cfg.attn_scale,
-        **quantizer.kernel_operands(new_cache))
+    o, new_cache = _paged_attend(cache, quantizer,
+                                 quantizer.quantize(lat[:, :, None]), page,
+                                 offset, q, start, ps, v_dim=cfg.kv_lora_rank,
+                                 scale=cfg.attn_scale)
     return mla_output(cfg, p, ctx, o, sq), new_cache

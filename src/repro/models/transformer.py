@@ -197,7 +197,7 @@ class _Named:
 
 
 def _run_groups(cfg, params, x, ctx, qparams, cache, attn, stat, *,
-                scan=True, named=False, **ffn_kw):
+                scan=True, named=False, pool=False, **ffn_kw):
     """The one decoder layer loop: every layer group in order, each scanned
     over its own stacked weights, quantization state and cache arrays.
 
@@ -206,7 +206,12 @@ def _run_groups(cfg, params, x, ctx, qparams, cache, attn, stat, *,
     cache); ``cache`` is the flat per-layer tree (``common.group_name``
     keys) or None; ``stat`` the initial FFN stat (see :func:`_ffn`).
     ``scan=False`` unrolls the loop, with eager ``layer{i}/`` site names
-    when ``named``.  Returns (x, summed stat, new cache tree or None)."""
+    when ``named``.  With ``pool`` the cache is a page pool: each group's
+    arrays ride the scan's carry whole, and ``c`` is that whole group with
+    the layer's index under ``"layer"`` — each layer writes its rows in
+    place and the paged kernel reads the stacked pages at that index, so
+    no layer slice or restack of the pool exists.  Returns (x, summed
+    stat, new cache tree or None)."""
     flags = _window_flags(cfg)
     new_cache = {} if cache is not None else None
 
@@ -222,7 +227,17 @@ def _run_groups(cfg, params, x, ctx, qparams, cache, attn, stat, *,
         lps, sqs = params[key], group_items(qparams or {}, key)
         cs = None if cache is None else group_items(cache, key)
         fl = flags[lo:hi]
-        if scan:
+        if pool:
+            def body(carry, xs):
+                x, st, c = carry
+                lp, flag, sq, i = xs
+                x, c, s_ = one(x, lp, _Named(ctx, ""), flag, sq,
+                               {**c, "layer": i})
+                return (x, add(st, s_), c), None
+            layers = jnp.arange(hi - lo, dtype=jnp.int32)
+            (x, stat, cs), _ = jax.lax.scan(body, (x, stat, cs),
+                                            (lps, fl, sqs, layers))
+        elif scan:
             def body(carry, xs):
                 x, st = carry
                 lp, flag, sq, c = xs
@@ -248,8 +263,9 @@ def _run_groups(cfg, params, x, ctx, qparams, cache, attn, stat, *,
 
 
 def _keep(c, new):
-    """The per-layer arrays of ``c`` from an attention's updated cache."""
-    return None if c is None else {n: new[n] for n in c}
+    """The cache arrays of ``c`` from an attention's updated cache (not
+    its routing state)."""
+    return None if c is None else {n: v for n, v in new.items() if n in c}
 
 
 # ---------------------------------------------------------------------------
@@ -579,11 +595,12 @@ def decode_step(cfg: ModelConfig, params, tokens, cache, ctx=None, *,
 
 
 def _paged_step(cfg, params, tokens, kv, routing, valid, ctx, qparams,
-                attn_fn, stats):
-    """The paged steps' shared body: embed, the layer loop over the pool's
-    per-layer arrays with ``routing`` (page table and positions) beside
-    each layer's pages, final norm and LM head.  ``valid`` [b, s] marks
-    real tokens (the MoE layers route nothing else)."""
+                stats):
+    """The paged steps' shared body: embed, the layer loop carrying the
+    pool with ``routing`` (page table and positions; it names the step,
+    see :func:`repro.models.attention.paged_rows`) beside it, final norm
+    and LM head.  ``valid`` [b, s] marks real tokens (the MoE layers route
+    nothing else)."""
     ctx = ctx or FpCtx()
     if cfg.family not in ("dense", "moe"):
         raise ValueError(f"paged steps support dense/moe, not {cfg.family}")
@@ -592,14 +609,14 @@ def _paged_step(cfg, params, tokens, kv, routing, valid, ctx, qparams,
         x = x * math.sqrt(cfg.d_model)
 
     def attn(p, nctx, h, c, flag, sq):
-        a, new = attn_fn(cfg, p, nctx, h, {**c, **routing}, window_flag=flag,
-                         sq=sq)
+        a, new = A.attention_paged(cfg, p, nctx, h, {**c, **routing},
+                                   window_flag=flag, sq=sq)
         return a, _keep(c, new)
 
     experts = bool(cfg.n_experts)
     x, held, new_kv = _run_groups(cfg, params, x, ctx, qparams, kv, attn,
                                   jnp.int32(0) if experts else None,
-                                  valid=valid, held_rows=experts)
+                                  pool=True, valid=valid, held_rows=experts)
     x = apply_norm(cfg, params["ln_f"], x)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     # under tensor-parallel serving each shard computes its contiguous
@@ -623,8 +640,9 @@ def decode_step_paged(cfg: ModelConfig, params, tokens, kv: dict,
     """One-token decode for the WHOLE slot pool against a paged KV pool
     (``repro.serve.pool``), with a per-slot position vector.
 
-    tokens [b, 1]; ``kv`` = {"k"/"v": [L, n_pages, kvh, ps, dh]} (int8 pages
-    add "k_scale"/"v_scale" [L, n_pages, kvh, ps, 1]); ``page_table``
+    tokens [b, 1]; ``kv`` the pool's arrays in ``repro.serve.kvq``'s page
+    layout ({"k"/"v": [L, n_pages, R, lanes]}; int8 pages add
+    "k_scale"/"v_scale", ``kvq.scale_shape``); ``page_table``
     [b, page_budget] int32; ``pos`` [b] int32.  Returns
     (logits [b, 1, V], updated kv dict).  Unlike :func:`decode_step` the
     position is per slot, so misaligned sequences decode in ONE traced step
@@ -646,7 +664,7 @@ def decode_step_paged(cfg: ModelConfig, params, tokens, kv: dict,
     routing = {"page_table": page_table, "pos": pos}
     valid = page_table[:, :1] > 0        # idle and prefilling slots: scratch
     return _paged_step(cfg, params, tokens, kv, routing, valid, ctx, qparams,
-                       A.attention_decode_paged, stats)
+                       stats)
 
 
 def decode_verify_paged(cfg: ModelConfig, params, tokens, kv: dict,
@@ -675,7 +693,7 @@ def decode_verify_paged(cfg: ModelConfig, params, tokens, kv: dict,
     routing = {"page_table": page_table, "pos": pos, "n_valid": n_valid}
     valid = jnp.arange(tokens.shape[1])[None] < n_valid[:, None]
     return _paged_step(cfg, params, tokens, kv, routing, valid, ctx, qparams,
-                       A.attention_verify_paged, stats)
+                       stats)
 
 
 def prefill_chunk_paged(cfg: ModelConfig, params, tokens, kv: dict,
@@ -688,14 +706,13 @@ def prefill_chunk_paged(cfg: ModelConfig, params, tokens, kv: dict,
 
     tokens [b, C] (C = the scheduler's bucketed chunk shape; ids past a
     slot's valid tokens are padding, and slots not advancing this step are
-    all-padding rows); ``kv`` = {"k"/"v": [L, n_pages, kvh, ps, dh]} (int8
-    pages add "k_scale"/"v_scale"); ``page_table`` [b, pages] int32 is the
-    prefilling slots' table rows sliced to the bucketed page budget;
-    ``start`` / ``write_lo`` / ``write_hi`` are traced int32 [b] vectors
-    (per-slot chunk start position and the absolute position window whose
-    K/V is written to pages — idle slots carry an empty window; see
-    :func:`repro.models.attention.attention_prefill_paged`, which also
-    keeps the legacy 1-slot scalar/1-D form working).
+    all-padding rows); ``kv`` as in :func:`decode_step_paged`;
+    ``page_table`` [b, pages] int32 is the prefilling slots' table rows
+    sliced to the bucketed page budget; ``start`` / ``write_lo`` /
+    ``write_hi`` are traced int32 [b] vectors (per-slot chunk start
+    position and the absolute position window whose K/V is written to
+    pages — idle slots carry an empty window; see
+    :func:`repro.models.attention.attention_paged`).
 
     Returns (logits [b, C, V], updated kv dict).  Because a chunk's queries
     only attend to positions <= their own — already in pages from earlier
@@ -710,11 +727,9 @@ def prefill_chunk_paged(cfg: ModelConfig, params, tokens, kv: dict,
     routing = {"page_table": page_table, "start": start,
                "write_lo": write_lo, "write_hi": write_hi}
     c = tokens.shape[1]
-    valid = (jnp.asarray(start, jnp.int32).reshape(-1, 1)
-             + jnp.arange(c)[None]) < jnp.asarray(write_hi,
-                                                   jnp.int32).reshape(-1, 1)
+    valid = start[:, None] + jnp.arange(c)[None] < write_hi[:, None]
     return _paged_step(cfg, params, tokens, kv, routing, valid, ctx, qparams,
-                       A.attention_prefill_paged, stats)
+                       stats)
 
 
 # ---------------------------------------------------------------------------

@@ -35,6 +35,8 @@ from typing import Dict, Optional
 
 import numpy as np
 
+from repro.serve import kvq
+
 DEFAULT_OUTLIER_RATIO = 4.0     # channel amax > ratio * median => "hot now"
 _FLOOR = 1e-6
 
@@ -141,12 +143,12 @@ class QualityObserver:
         for side in ("k", "v"):
             if side not in pool.kv:
                 continue                    # latent pages: one row, no v
-            # [L, pages, kvh, ps, dh(/2)] int8 -> live pages only
-            q = np.asarray(pool.kv[side])[:, live]
+            # live pages as [L, pages, kvh, ps, dh(/2)] int8
+            q = kvq.head_major(np.asarray(pool.kv[side])[:, live],
+                               pool.kv_heads, pool.page_size)
             if pool.mode == "int4":
                 import jax.numpy as jnp
-                from repro.serve.kvq import unpack_int4
-                q = np.asarray(unpack_int4(jnp.asarray(q)))
+                q = np.asarray(kvq.unpack_int4(jnp.asarray(q)))
             st = self._site(f"kv/{side}")
             st.calls += 1
             st.elements += q.size
@@ -155,8 +157,10 @@ class QualityObserver:
             # 2^e redistribution (which exists to DE-hot the outliers in
             # the stored ints) doesn't hide them from the drift comparison
             sc = pool.kv.get(f"{side}_scale")
-            scale = (np.asarray(sc, np.float32)[:, live]
-                     if sc is not None else np.float32(1.0))
+            scale = (kvq.scale_view(
+                np.asarray(sc, np.float32), pool.kv_heads, pool.page_size,
+                pool.kv_shards)[:, live].reshape(q.shape[:-1] + (1,))
+                if sc is not None else np.float32(1.0))
             deq = np.abs(q.astype(np.float32)) * scale
             redist = pool.kv.get(f"{side}_redist")
             if redist is not None:

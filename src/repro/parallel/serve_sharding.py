@@ -1,9 +1,11 @@
 """Sharding specs + scoped shard context for tensor-parallel paged serving.
 
 The serve stack shards by KV-head over a 1-D ``("model",)`` mesh: K/V
-pages ``[L, n_pages, kvh, ps, dh]`` (and their int8/int4 scales) split on
-the kvh axis, the int4 outlier-redistribution rows ``[L, kvh, dh]`` split
-on the same axis, while page tables, positions and tokens stay replicated
+pages ``[L, n_pages, R, lanes]`` split on the lane axis, whose heads are
+contiguous runs (``repro.serve.kvq``'s page layout), their int8/int4
+scales ``[L, rows, X]`` on the lane axis too (one block per head shard),
+the int4 outlier-redistribution rows ``[L, kvh, dh]`` on the head axis,
+while page tables, positions and tokens stay replicated
 and the scheduler stays host-side and mesh-oblivious.  This is the
 MUXQ-native cut: per-(position, head) page scales and the per-head
 redistribution rows are head-local, so int8/int4 page quantize/dequantize
@@ -99,29 +101,27 @@ def mesh_size(mesh: Mesh) -> int:
     return int(mesh.shape[SERVE_AXIS])
 
 
-def pool_specs(mesh: Mesh, kv: Dict[str, jnp.ndarray]) -> Dict[str, P]:
+def pool_specs(mesh: Mesh, kv: Dict[str, jnp.ndarray], kvh: int
+               ) -> Dict[str, P]:
     """PartitionSpec per pool array, sharding the KV-head axis on "model".
 
-    Page arrays ``[L, n_pages, kvh, ps, dh]`` (K/V and their scales) carry
-    kvh on axis 2; pool-state rows ``[L, kvh, dh]`` (int4 redistribution)
-    carry it on axis 1.  Everything goes through ``fit_spec``, so a kvh the
-    mesh doesn't divide drops the axis — the whole-pool replicated
-    fallback the engine detects via :func:`heads_sharded`."""
+    Pages ``[L, n_pages, R, lanes]`` carry the heads as contiguous lane
+    runs on axis 3, scales ``[L, rows, X]`` one block per head shard on
+    axis 2, pool-state rows ``[L, kvh, dh]`` (int4 redistribution) the
+    heads on axis 1.  A kvh the
+    mesh doesn't divide drops the axis from every array — the whole-pool
+    replicated fallback the engine detects via :func:`heads_sharded`
+    (``fit_spec`` alone would still cut a lane or scale axis the mesh
+    divides between heads)."""
+    split = kvh % mesh_size(mesh) == 0
     specs: Dict[str, P] = {}
     for name, arr in kv.items():
-        if arr.ndim == 5:       # pages / scales: [L, np, kvh, ps, dh|1]
-            wanted = [None, None, SERVE_AXIS, None, None]
-        elif arr.ndim == 3:     # per-head pool state: [L, kvh, dh]
-            wanted = [None, SERVE_AXIS, None]
-        else:                   # anything else: replicated
-            wanted = [None] * arr.ndim
+        wanted = [None] * arr.ndim
+        if split:               # lanes of pages and scales, else heads
+            lanes = arr.ndim == 4 or name.endswith("_scale")
+            wanted[-1 if lanes else 1] = SERVE_AXIS
         specs[name] = fit_spec(mesh, arr.shape, wanted)
     return specs
-
-
-def pool_shardings(mesh: Mesh, kv: Dict[str, jnp.ndarray]
-                   ) -> Dict[str, NamedSharding]:
-    return {n: NamedSharding(mesh, s) for n, s in pool_specs(mesh, kv).items()}
 
 
 def heads_sharded(specs: Dict[str, P]) -> bool:

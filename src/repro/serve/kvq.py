@@ -34,8 +34,31 @@ construction (``ServeEngine`` -> ``PagePool``).
 
 **Latent pages** (``latent=True``, latent attention): one row per
 position holds the normed latent and the rotated rotary key, shared by
-every head, under the single key ``k`` (``[..., 1, ps, width]``); fp and
-int8 modes only, int8 with one float32 scale per position.
+every head (one "head" of ``latent + rope`` channels), under the single
+key ``k``; fp and int8 modes only, int8 with one float32 scale per
+position.
+
+**Page layout** (:func:`page_shape`, :func:`scale_shape`).  A position's
+row is its heads side by side, ``kvh * w`` wide (``w`` the stored head
+width: ``dh``, or ``dh // 2`` nibble-packed).  A page stores ``r`` such
+rows side by side in one stored row, ``r`` the fewest positions that fill
+whole 128-lane vectors (:func:`page_fold`; 1 for GPT-2's 20 heads of 64,
+2 for a 576-wide latent row), so the pages of ``L`` layers are one
+row-major array ``[L, n_pages, ps // r, r * kvh * w]`` that the TPU stores
+unpadded and as laid out.  Position ``o`` of a page sits in stored row
+``o // r``, block ``s = o % r``; head ``h``'s channels in it start at lane
+``(h * r + s) * w``, so each head's lanes are one contiguous run
+(tensor-parallel serving shards that axis by heads).
+Scales, one value per (position, head), are too narrow for a lane-dense
+page of their own (``kvh * ps`` of them: 320 for GPT-2 large), so ``g``
+pages share a stored row, ``g`` the fewest that fill whole 128-lane
+vectors (:func:`scale_fold`: 2 for 320, 8 for a latent page's 16):
+``[L, rows, g * kvh * ps]`` (``rows`` covers ``n_pages`` in whole
+8-row tiles), page ``p``'s scales at lanes
+``(p % g) * kvh * ps + h * ps + o`` of row ``p // g``; under
+tensor-parallel serving the row is one such block per head shard.  A
+step writes pages and scales in place (:func:`write_pages`) and gathers
+only the rows of the pages its table names (:func:`gather_scales`).
 
 Inside traced model code the mode is discovered from the cache dict's key
 set (:func:`from_cache`): int4 pages carry per-layer ``k_redist``/
@@ -59,6 +82,7 @@ without an import cycle.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -66,6 +90,8 @@ import jax.numpy as jnp
 import numpy as np
 
 KV_MODES = ("fp", "int8", "int4")
+
+LANES = 128                    # a stored page row fills whole TPU vectors
 
 INT4_MAX = 7                   # symmetric [-7, 7]: amax maps to +/-7
 DEFAULT_EXP_FACTOR = 2         # MUXQ 2^e magnitude shift (core.muxq default)
@@ -104,6 +130,166 @@ def unpack_int4(p: jnp.ndarray) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
+# Page layout: one row-major array per pool array, written in place
+# ---------------------------------------------------------------------------
+
+def _lanes(n: int) -> int:
+    return -(-n // LANES) * LANES
+
+
+def page_fold(ps: int, width: int) -> int:
+    """Positions per stored page row: the fewest (a divisor of ``ps``)
+    whose rows of ``width`` fill whole 128-lane vectors, else the whole
+    page in one row."""
+    return next((r for r in range(1, ps + 1)
+                 if ps % r == 0 and r * width % LANES == 0), ps)
+
+
+def page_shape(L: int, n_pages: int, ps: int, kvh: int, w: int) -> tuple:
+    """Stored page array of ``kvh`` heads of stored width ``w``."""
+    r = page_fold(ps, kvh * w)
+    return (L, n_pages, ps // r, r * kvh * w)
+
+
+def scale_fold(n: int) -> int:
+    """Pages per stored scale row: the fewest whose ``n`` scales each fill
+    whole 128-lane vectors."""
+    return LANES // math.gcd(n, LANES)
+
+
+def scale_shape(L: int, n_pages: int, ps: int, kvh: int,
+                shards: int = 1) -> tuple:
+    """Stored scales: per layer, ``g`` pages' scales to a row, one such
+    block per head shard (see the module docstring)."""
+    n = (kvh // shards) * ps
+    g = scale_fold(n)
+    return (L, -(-n_pages // (8 * g)) * 8, shards * g * n)
+
+
+def page_size(pages: jnp.ndarray, kvh: int, w: int) -> int:
+    """Positions per page of a stored page array of ``kvh`` heads of
+    stored width ``w`` (``kvh`` is a shard's heads under tensor-parallel
+    serving)."""
+    rows, lanes = pages.shape[-2:]
+    return rows * (lanes // (kvh * w))
+
+
+def _i32(a):
+    return jnp.asarray(a, jnp.int32)
+
+
+def _write_rows(pages, rows, layer, page, offset):
+    """Write ``rows`` [..., n, kvh, w] into ``pages`` [L, n_pages, R,
+    lanes] at (layer, page, offset), which broadcast to the rows' leading
+    shape; positions run consecutively along the last leading axis.  One
+    scatter of whole stored rows: where a stored row holds ``r > 1``
+    positions, each is assembled from this write's rows and, for the
+    positions it does not write, the row's current content (rows that
+    share a stored row then write the same values)."""
+    kvh, w = rows.shape[-2:]
+    r = pages.shape[-1] // (kvh * w)
+    lead = rows.shape[:-2]
+    layer, page, offset = (jnp.broadcast_to(_i32(a), lead)
+                           for a in (layer, page, offset))
+    rows = rows.astype(pages.dtype)
+    if r > 1:
+        old = pages[layer, page, offset // r].reshape(lead + (kvh, r, w))
+        j = jnp.arange(lead[-1])
+        blocks = []
+        for s in range(r):
+            # the row holding block s: the same stored row, this write's
+            # rows for the same page, else the current content
+            jj = j + s - offset % r
+            src = jnp.clip(jj, 0, lead[-1] - 1)
+            ok = ((jj >= 0) & (jj < lead[-1])
+                  & (jnp.take_along_axis(page, src, axis=-1) == page))
+            mine = jnp.take_along_axis(rows, src[..., None, None], axis=-3)
+            blocks.append(jnp.where(ok[..., None, None], mine,
+                                    old[..., s, :]))
+        rows = jnp.stack(blocks, axis=-2)
+    return pages.at[layer, page, offset // r].set(
+        rows.reshape(lead + (kvh * r * w,)))
+
+
+def _write_scales(scales, vals, layer, page, offset, ps: int):
+    """Write per-(position, head) ``vals`` [..., kvh, 1] into ``scales``
+    [L, rows, g * kvh * ps] (a shard's view)."""
+    n = vals.shape[-2] * ps
+    g = scales.shape[-1] // n
+    layer, page, offset = (_i32(a)[..., None] for a in (layer, page, offset))
+    lane = (page % g) * n + jnp.arange(vals.shape[-2]) * ps + offset
+    return scales.at[layer, page // g, lane].set(
+        vals[..., 0].astype(scales.dtype))
+
+
+def write_pages(pool: Dict[str, jnp.ndarray], parts: Dict[str, jnp.ndarray],
+                layer, page, offset, ps: int) -> Dict[str, jnp.ndarray]:
+    """The pool arrays ``parts`` names (:meth:`KVQuantizer.quantize`'s
+    ``[..., kvh, w]`` rows and ``[..., kvh, 1]`` scales), each with the
+    rows written at (layer, page, offset in the page), which broadcast to
+    the rows' leading shape — one scatter per array, in place when the
+    array is a loop carry or donated.  ``ps``: positions per page."""
+    return {n: (_write_scales(pool[n], v, layer, page, offset, ps)
+                if n.endswith("_scale") else
+                _write_rows(pool[n], v, layer, page, offset))
+            for n, v in parts.items()}
+
+
+def gather_scales(scales: jnp.ndarray, layer, table: jnp.ndarray, kvh: int,
+                  ps: int, rows: int) -> jnp.ndarray:
+    """The scales of layer ``layer`` on the pages of ``table`` [b, P], as
+    the paged kernel reads them next to a stored page of ``rows`` rows:
+    ``[b, P, rows, kvh * r]``, a page row's positions on the sublanes and
+    (head, block) on the lanes.  One gather of whole stored rows, then the
+    page's part of each."""
+    n = kvh * ps
+    g = scales.shape[-1] // n
+    table = _i32(table)
+    got = scales[layer, table // g].reshape(table.shape + (g, n))
+    got = jnp.take_along_axis(got, (table % g)[..., None, None], axis=-2)
+    got = got.reshape(table.shape + (kvh, rows, n // (kvh * rows)))
+    return got.swapaxes(-2, -3).reshape(table.shape + (rows, -1))
+
+
+def copy_page(arrays: Dict[str, jnp.ndarray], old: int, new: int, kvh: int,
+              ps: int, shards: int = 1) -> Dict[str, jnp.ndarray]:
+    """The stored pool arrays, each with page ``new`` (in every layer) a
+    copy of page ``old``."""
+    out = {}
+    for n, a in arrays.items():
+        if n.endswith("_scale"):        # page p: row p // g, block p % g
+            v = a.reshape(a.shape[:2] + (shards, -1, kvh // shards * ps))
+            g = v.shape[3]
+            v = v.at[:, new // g, :, new % g].set(v[:, old // g, :, old % g])
+            out[n] = v.reshape(a.shape)
+        else:
+            out[n] = a.at[:, new].set(a[:, old])
+    return out
+
+
+def scale_view(scales, kvh: int, ps: int, shards: int = 1):
+    """Stored scales ``[L, rows, X]`` (numpy or jnp) as ``[L, rows * g,
+    shards, kvh // shards, ps]``: indexed by page (pages past ``n_pages``
+    are padding)."""
+    L, rows, X = scales.shape
+    kl = kvh // shards
+    g = X // (shards * kl * ps)
+    x = scales.reshape(L, rows, shards, g, kl, ps).transpose(0, 1, 3, 2, 4, 5)
+    return x.reshape(L, rows * g, shards, kl, ps)
+
+
+def head_major(pages, kvh: int, ps: int):
+    """A stored page array ``[..., n, R, lanes]`` (numpy or jnp) as
+    ``[..., n, kvh, ps, w]``; for host-side inspection and tests."""
+    *lead, R, lanes = pages.shape
+    r = ps // R
+    x = pages.reshape(*lead, R, kvh, r, lanes // (kvh * r))
+    nd = len(lead)
+    x = x.transpose(*range(nd), nd + 1, nd, nd + 2, nd + 3)
+    return x.reshape(*lead, kvh, ps, -1)
+
+
+# ---------------------------------------------------------------------------
 # The quantizer seam
 # ---------------------------------------------------------------------------
 
@@ -115,6 +301,8 @@ class KVQuantizer:
 
     mode: str = "fp"
     latent: bool = False        # one shared latent row per position, no V
+    page_dtype = None           # stored codes (None: the pool dtype)
+    scale_dtype = None          # per-(position, head) scales (None: none)
     # symmetric integer ceiling of the mode's codes (None for fp pages);
     # the quality observer (repro.obs.quality) reads this to count
     # saturated codes when it samples live pool pages
@@ -127,26 +315,44 @@ class KVQuantizer:
                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         raise NotImplementedError
 
-    def page_arrays(self, L: int, n_pages: int, ps: int, kvh: int, dh: int
-                    ) -> Dict[str, jnp.ndarray]:
-        """Zero-initialized pool arrays, all laid out head-major
-        [L, n_pages, kvh, ps, ...] (a (page, head) block is a (ps, dh) tile
-        the paged-attention kernel loads whole)."""
-        raise NotImplementedError
+    def stored_width(self, dh: int) -> int:
+        """Stored channels of one head's row (nibble-packed: ``dh // 2``)."""
+        return dh
+
+    def page_arrays(self, L: int, n_pages: int, ps: int, kvh: int, dh: int,
+                    shards: int = 1) -> Dict[str, jnp.ndarray]:
+        """Zero-initialized pool arrays in the page layout (module
+        docstring): pages :func:`page_shape`, scales :func:`scale_shape`
+        in ``shards`` head blocks."""
+        names = ("k",) if self.latent else ("k", "v")
+        pages = page_shape(L, n_pages, ps, kvh, self.stored_width(dh))
+        out = {n: jnp.zeros(pages, self.page_dtype) for n in names}
+        if self.scale_dtype is not None:
+            out.update({f"{n}_scale": jnp.zeros(
+                scale_shape(L, n_pages, ps, kvh, shards), self.scale_dtype)
+                for n in names})
+        return out
 
     def pool_state(self, L: int, kvh: int, dh: int) -> Dict[str, jnp.ndarray]:
-        """Non-page pool state stacked [L, ...] so it rides the same
-        ``lax.scan`` xs as the page arrays (int4: redistribution rows)."""
+        """Non-page pool state stacked [L, ...] beside the page arrays
+        (int4: redistribution rows)."""
         return {}
 
     def bytes_per_token(self, kvh: int, dh: int) -> int:
         """Page bytes one token position costs across K and V (one layer)."""
         raise NotImplementedError
 
-    def kernel_operands(self, cache: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
+    def kernel_operands(self, cache: Dict[str, jnp.ndarray], layer,
+                        table: jnp.ndarray, kvh: int, ps: int
+                        ) -> Dict[str, jnp.ndarray]:
         """Extra keyword operands for ``paged_attention_decode`` beyond the
-        packed pages themselves (scales, redistribution rows)."""
-        return {}
+        stored pages themselves: the scales of ``table``'s pages in layer
+        ``layer`` (:func:`gather_scales`; ``kvh`` heads, ``ps`` positions
+        a page) and the layer's redistribution rows."""
+        rows = cache["k"].shape[-2]
+        return {f"{n}_scale": gather_scales(cache[f"{n}_scale"], layer,
+                                            table, kvh, ps, rows)
+                for n in ("k", "v") if f"{n}_scale" in cache}
 
 
 class FpKVQuantizer(KVQuantizer):
@@ -154,6 +360,7 @@ class FpKVQuantizer(KVQuantizer):
 
     def __init__(self, dtype=jnp.bfloat16, latent: bool = False):
         self.dtype, self.latent = dtype, latent
+        self.page_dtype = dtype
 
     def quantize(self, k, v=None):
         if self.latent:
@@ -164,13 +371,6 @@ class FpKVQuantizer(KVQuantizer):
         if self.latent:
             return parts["k"].astype(dtype), None
         return parts["k"].astype(dtype), parts["v"].astype(dtype)
-
-    def page_arrays(self, L, n_pages, ps, kvh, dh):
-        shape = (L, n_pages, kvh, ps, dh)
-        out = {"k": jnp.zeros(shape, self.dtype)}
-        if not self.latent:
-            out["v"] = jnp.zeros(shape, self.dtype)
-        return out
 
     def bytes_per_token(self, kvh, dh):
         return (1 if self.latent else 2) * kvh * dh * jnp.dtype(
@@ -183,6 +383,8 @@ class Int8KVQuantizer(KVQuantizer):
 
     mode = "int8"
     qmax = 127
+    page_dtype = jnp.int8
+    scale_dtype = jnp.float32
 
     def __init__(self, latent: bool = False):
         self.latent = latent
@@ -201,22 +403,9 @@ class Int8KVQuantizer(KVQuantizer):
         v = (parts["v"].astype(jnp.float32) * parts["v_scale"]).astype(dtype)
         return k, v
 
-    def page_arrays(self, L, n_pages, ps, kvh, dh):
-        shape = (L, n_pages, kvh, ps, dh)
-        names = ("k",) if self.latent else ("k", "v")
-        out = {n: jnp.zeros(shape, jnp.int8) for n in names}
-        out.update({f"{n}_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32)
-                    for n in names})
-        return out
-
     def bytes_per_token(self, kvh, dh):
         # int8 payload + f32 scale, for K and V (a latent row: once)
         return (1 if self.latent else 2) * kvh * (dh + 4)
-
-    def kernel_operands(self, cache):
-        if self.latent:
-            return {"k_scale": cache["k_scale"]}
-        return {"k_scale": cache["k_scale"], "v_scale": cache["v_scale"]}
 
 
 class Int4KVQuantizer(KVQuantizer):
@@ -232,6 +421,7 @@ class Int4KVQuantizer(KVQuantizer):
 
     mode = "int4"
     qmax = INT4_MAX
+    page_dtype = jnp.int8
     scale_dtype = jnp.bfloat16
 
     def __init__(self, k_redist, v_redist):
@@ -260,13 +450,9 @@ class Int4KVQuantizer(KVQuantizer):
         return (self._dq(parts["k"], parts["k_scale"], self.k_redist, dtype),
                 self._dq(parts["v"], parts["v_scale"], self.v_redist, dtype))
 
-    def page_arrays(self, L, n_pages, ps, kvh, dh):
+    def stored_width(self, dh):
         assert dh % 2 == 0, f"int4 pages need an even head_dim, got {dh}"
-        shape = (L, n_pages, kvh, ps, dh // 2)
-        return {"k": jnp.zeros(shape, jnp.int8),
-                "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(shape[:-1] + (1,), self.scale_dtype),
-                "v_scale": jnp.zeros(shape[:-1] + (1,), self.scale_dtype)}
+        return dh // 2
 
     def pool_state(self, L, kvh, dh):
         def stack(r):
@@ -279,9 +465,9 @@ class Int4KVQuantizer(KVQuantizer):
     def bytes_per_token(self, kvh, dh):
         return 2 * kvh * (dh // 2 + 2)     # nibble payload + bf16 scale
 
-    def kernel_operands(self, cache):
-        return {"k_scale": cache["k_scale"], "v_scale": cache["v_scale"],
-                "k_redist": cache["k_redist"], "v_redist": cache["v_redist"]}
+    def kernel_operands(self, cache, layer, table, kvh, ps):
+        return {**super().kernel_operands(cache, layer, table, kvh, ps),
+                "k_redist": self.k_redist, "v_redist": self.v_redist}
 
 
 def redist_from_mask(mask, exp_factor: int = DEFAULT_EXP_FACTOR) -> np.ndarray:
@@ -317,12 +503,16 @@ def make_quantizer(mode: str, *, kvh: int, dh: int, dtype=jnp.bfloat16,
     raise ValueError(f"unknown kv mode {mode!r} (expected one of {KV_MODES})")
 
 
-def from_cache(cache: Dict[str, jnp.ndarray]) -> KVQuantizer:
+def from_cache(cache: Dict[str, jnp.ndarray], layer=None) -> KVQuantizer:
     """Classify a (possibly per-layer, traced) cache dict by its key set —
     the single mode sentinel shared by the scan bodies and attention paths:
-    redistribution rows mean int4, bare scales mean int8, else fp."""
+    redistribution rows mean int4, bare scales mean int8, else fp.  A
+    stacked pool's int4 rows are taken at ``layer``."""
     if "k_redist" in cache:
-        return Int4KVQuantizer(cache["k_redist"], cache["v_redist"])
+        kr, vr = cache["k_redist"], cache["v_redist"]
+        if layer is not None:
+            kr, vr = kr[layer], vr[layer]
+        return Int4KVQuantizer(kr, vr)
     latent = "v" not in cache
     if "k_scale" in cache:
         return Int8KVQuantizer(latent)

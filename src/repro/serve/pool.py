@@ -21,17 +21,21 @@ quantize/dequantize seam shared with the attention write/read paths):
   * ``fp``   — pages in ``dtype`` (default bf16), the parity-testing mode
     (bit-exact against the dense cache path).
 
-Layout (``L`` = attention layers, leading so the pool rides ``lax.scan``;
-head-major pages, so one (page, head) block is a ``(page_size, dh)`` tile
-the paged-attention kernel loads whole):
+Layout (``L`` = attention layers, leading: a step's layer loop carries
+the whole pool and each layer writes its rows in place; the page layout
+is :mod:`repro.serve.kvq`'s, one row-major array per pool array that the
+TPU stores unpadded and the paged kernel reads at (layer, page)):
 
-  k/v        [L, n_pages, kvh, page_size, dh]     (int4: [..., dh//2] int8)
-  k/v_scale  [L, n_pages, kvh, page_size, 1]      (int8: f32; int4: bf16)
+  k/v        [L, n_pages, ps // r, r * kvh * w]   a page's positions, r
+                                                  to a stored row (w = dh;
+                                                  int4: dh // 2 packed)
+  k/v_scale  [L, ceil(n_pages / g), g*kvh*ps]    (int8: f32; int4: bf16)
+                                                  g pages to a stored row
   k/v_redist [L, kvh, dh] f32                     (int4 only; NOT pages —
                                                    per-head channel
                                                    redistribution rows)
-  latent attention: k [L, n_pages, 1, page_size, latent + rope] (+ int8
-                    k_scale), one shared row per position, no v
+  latent attention: k with one head of latent + rope (r = 2 for 576),
+                    + int8 k_scale; no v
   page_table [n_slots, pages_per_slot] int32   host-side, 0 = unallocated
   refcount   [n_pages] int32                   host-side page sharing state
 
@@ -117,21 +121,29 @@ class PagePool:
             raise ValueError("pool needs at least one allocatable page")
 
         kvh, dh = cache_rows(cfg)
+        self.kv_heads = kvh
+        # head shards of a tensor-parallel mesh (1: unsharded, or a kvh the
+        # mesh doesn't divide); the scale rows hold one block per shard
+        size = SS.mesh_size(mesh) if mesh is not None else 1
+        self.kv_shards = size if kvh % size == 0 else 1
         self.quantizer = kvq.make_quantizer(mode, kvh=kvh, dh=dh,
                                             dtype=dtype, calib=kv_calib,
                                             latent=cfg.is_mla)
         self.kv: Dict[str, jnp.ndarray] = {}
         state: Dict[str, jnp.ndarray] = {}
+        self._page_bytes = 0
         for key, lo, hi in cfg.layer_groups:
             pages = self.quantizer.page_arrays(hi - lo, self.n_pages,
-                                               page_size, kvh, dh)
+                                               page_size, kvh, dh,
+                                               self.kv_shards)
+            self._page_bytes += ((hi - lo) * page_size
+                                 * self.quantizer.bytes_per_token(kvh, dh))
             self.kv.update({group_name(key, n): a for n, a in pages.items()})
             state.update({group_name(key, n): a for n, a in
                           self.quantizer.pool_state(hi - lo, kvh, dh).items()})
-        # keys whose second axis indexes pages (COW copies / prefill
-        # scatters / read-bytes pricing touch these ONLY); the rest of
-        # self.kv is per-pool state like the int4 redistribution rows,
-        # stacked [L, ...] so it rides the same scan xs as the pages
+        # keys with a page axis (COW copies / prefill scatters touch these
+        # ONLY); the rest of self.kv is per-pool state like the int4
+        # redistribution rows, stacked [L, ...]
         self._page_keys = tuple(self.kv)
         self.kv.update(state)
         # tensor-parallel placement: on a ("model",) mesh the pages, scales
@@ -141,18 +153,17 @@ class PagePool:
         # COW / release logic below is numpy and never sees the mesh
         self.mesh = mesh
         if mesh is not None:
-            self.kv_pspecs = SS.pool_specs(mesh, self.kv)
+            self.kv_pspecs = SS.pool_specs(mesh, self.kv, kvh)
             self._shardings = {n: jax.sharding.NamedSharding(
                 mesh, self.kv_pspecs[n]) for n in self.kv}
             self.kv = {n: jax.device_put(a, self._shardings[n])
                        for n, a in self.kv.items()}
             self.heads_sharded = SS.heads_sharded(self.kv_pspecs)
-            self.kv_shards = (SS.mesh_size(mesh) if self.heads_sharded else 1)
+            assert self.heads_sharded == (self.kv_shards > 1)
         else:
             self.kv_pspecs = None
             self._shardings = None
             self.heads_sharded = False
-            self.kv_shards = 1
         self.page_table = np.zeros((n_slots, self.pages_per_slot), np.int32)
         self.refcount = np.zeros(self.n_pages, np.int32)
         self._free = list(range(self.n_pages - 1, 0, -1))  # pop() -> page 1 first
@@ -251,9 +262,11 @@ class PagePool:
         new = self._free.pop()
         # device-side page copy across every page-indexed array (all layers
         # at once; pool state like the int4 redist rows has no page axis)
-        for name in self._page_keys:
-            upd = self.kv[name].at[:, new].set(self.kv[name][:, old])
-            self.kv[name] = self._constrain(name, upd)
+        copied = kvq.copy_page({n: self.kv[n] for n in self._page_keys},
+                               old, new, self.kv_heads, self.page_size,
+                               self.kv_shards)
+        for name, a in copied.items():
+            self.kv[name] = self._constrain(name, a)
         self.refcount[old] -= 1
         self.refcount[new] = 1
         self.page_table[slot, page_idx] = new
@@ -390,10 +403,10 @@ class PagePool:
     def page_read_bytes(self) -> int:
         """Bytes one page costs to read across ALL attention layers (K + V
         + scales; int4 counts true packed nibble bytes) — the unit for the
-        decode bytes-read metrics.  Only page-indexed arrays count: the
-        int4 redistribution rows are per-pool constants, not page traffic."""
-        return sum(self.kv[n].size * self.kv[n].dtype.itemsize
-                   for n in self._page_keys) // self.n_pages
+        decode bytes-read metrics.  Only page data counts: the scale rows'
+        lane padding and the int4 redistribution rows (per-pool
+        constants) are not page traffic."""
+        return self._page_bytes
 
     # -- prefill write -------------------------------------------------------
 
@@ -407,10 +420,10 @@ class PagePool:
         read-only; writing them would corrupt the sibling slot) and must be
         page-aligned when anything remains to write.
 
-        One indexed scatter per pool array (the tail of the slot's last
-        page zero-pads): each eager ``.at[].set`` copies the whole pool
-        array, so a per-page loop would cost O(pages) pool copies per
-        admitted request."""
+        One scatter per pool array over every layer and position
+        (:func:`repro.serve.kvq.write_pages`): each eager scatter copies
+        the whole pool array, so a per-page loop would cost O(pages) pool
+        copies per admitted request."""
         s = k.shape[1]
         if start_pos >= s:
             return                      # fully covered by shared pages
@@ -426,32 +439,35 @@ class PagePool:
         assert np.all(pids > 0), (slot, "prefill write into unallocated page")
         assert np.all(self.refcount[pids] == 1), (
             slot, "prefill write into a shared page (needs copy-on-write)")
-        pad = n * self.page_size - s
-        for name, arr in parts.items():
-            a = arr.astype(self.kv[name].dtype)
-            if pad:
-                a = jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
-            a = a.reshape(a.shape[0], n, self.page_size, *a.shape[2:])
-            a = jnp.swapaxes(a, 2, 3)           # head-major [L, n, kvh, ps, *]
-            self.kv[name] = self._constrain(
-                name, self.kv[name].at[:, jnp.asarray(pids)].set(a))
+        at = np.arange(s)
+        layer = np.arange(k.shape[0])[:, None]
+        written = kvq.write_pages(self.kv, parts, layer,
+                                  pids[at // self.page_size],
+                                  at % self.page_size, self.page_size)
+        for name, a in written.items():
+            self.kv[name] = self._constrain(name, a)
 
     # -- accounting ----------------------------------------------------------
 
     def cache_bytes(self) -> int:
-        """GLOBAL bytes held by the page pool (all pages, live or free,
-        summed across every shard — ``jax`` keeps array sizes global under
-        a mesh, so this number is mesh-invariant by construction and the
+        """GLOBAL bytes of the page pool's data (all pages, live or free,
+        summed across every shard, plus pool state like the int4
+        redistribution rows) — mesh-invariant by construction, so the
         CI-gated ``kv_bytes_read`` / ``bytes_per_token`` comparisons stay
-        comparable across mesh sizes)."""
-        return cache_bytes(self.kv)
+        comparable across mesh sizes.  The scale rows' lane padding is not
+        page data and not counted (under 1% at serving sizes)."""
+        return self.n_pages * self._page_bytes + cache_bytes(self._state())
 
     def cache_bytes_per_shard(self) -> int:
-        """Bytes ONE mesh shard holds (== :meth:`cache_bytes` unsharded):
-        the per-device HBM footprint — the number that actually has to fit,
-        and the capacity-scaling win the KV-head sharding exists to
-        deliver (~ global / mesh_size when kvh divides)."""
-        return sum(SS.local_bytes(a) for a in self.kv.values())
+        """Bytes of page data ONE mesh shard holds (== :meth:`cache_bytes`
+        unsharded): the per-device footprint — the number that actually
+        has to fit, and the capacity-scaling win the KV-head sharding
+        exists to deliver (global / mesh_size when kvh divides)."""
+        return (self.n_pages * self._page_bytes // self.kv_shards
+                + sum(SS.local_bytes(a) for a in self._state().values()))
+
+    def _state(self) -> Dict[str, jnp.ndarray]:
+        return {n: a for n, a in self.kv.items() if n not in self._page_keys}
 
     def stats(self, slot_lens: Optional[Dict[int, int]] = None) -> Dict[str, float]:
         """Occupancy + fragmentation + sharing counters.  ``slot_lens``

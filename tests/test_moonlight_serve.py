@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import paged_testlib as PT
 from repro.core.context import FpCtx
 from repro.core.muxq import QuantConfig
 from repro.core.policy import SitePolicy
@@ -276,9 +277,9 @@ def test_latent_kernel_matches_reference(mode):
     pos = jnp.asarray([0, 9, 28], jnp.int32)
     q = jnp.asarray(rng.standard_normal((slots, 3, h, width)), jnp.float32)
     args = (q, jnp.asarray(rows), None, table, pos)
-    ref = PA.paged_attention_ref(*args, v_dim=vd, scale=0.3, **kw)
-    got = PA.paged_attention_pallas(*args, v_dim=vd, scale=0.3,
-                                    interpret=True, **kw)
+    ref = PT.run(PA.paged_attention_ref, *args, v_dim=vd, scale=0.3, **kw)
+    got = PT.run(PA.paged_attention_pallas, *args, v_dim=vd, scale=0.3,
+                 interpret=True, **kw)
     assert got.shape == (slots, 3, h, vd)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
 

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import paged_testlib as PT
 from repro.configs import get_config
 from repro.core.muxq import QuantConfig
 from repro.core.policy import SitePolicy
@@ -344,54 +345,17 @@ def test_share_detection_prefers_longest_prefix(small_model):
 
 def _random_paged_case(seed, *, b=3, h=8, kvh=4, dh=16, ps=8, pages=4,
                        mode="fp"):
-    """Random paged-attention operands for one page mode.  Returns
-    ``(q, k_pages, v_pages, kw, table, pos)`` where ``kw`` carries the
-    mode's scale/redistribution operands (h > kvh exercises GQA)."""
-    from repro.serve import kvq
-
+    """Random paged-attention operands for one page mode, head-major (see
+    ``paged_testlib``).  Returns ``(q, k_pages, v_pages, kw, table, pos)``
+    where ``kw`` carries the mode's scale/redistribution operands (h > kvh
+    exercises GQA)."""
     rng = np.random.default_rng(seed)
     n_pages = 1 + b * pages                           # + scratch page 0
     q = jnp.asarray(rng.normal(size=(b, h, dh)).astype(np.float32))
-    kw = {}
-    if mode == "int8":
-        kp = jnp.asarray(rng.integers(-127, 128, (n_pages, kvh, ps, dh)),
-                         dtype=jnp.int8)
-        vp = jnp.asarray(rng.integers(-127, 128, (n_pages, kvh, ps, dh)),
-                         dtype=jnp.int8)
-        kw["k_scale"] = jnp.asarray(rng.uniform(1e-3, 2e-2,
-                                                (n_pages, kvh, ps, 1))
-                                    .astype(np.float32))
-        kw["v_scale"] = jnp.asarray(rng.uniform(1e-3, 2e-2,
-                                                (n_pages, kvh, ps, 1))
-                                    .astype(np.float32))
-    elif mode == "int4":
-        ki = rng.integers(-7, 8, (n_pages, kvh, ps, dh)).astype(np.int8)
-        vi = rng.integers(-7, 8, (n_pages, kvh, ps, dh)).astype(np.int8)
-        kp = kvq.pack_int4(jnp.asarray(ki))          # [..., dh//2] nibbles
-        vp = kvq.pack_int4(jnp.asarray(vi))
-        kw["k_scale"] = jnp.asarray(rng.uniform(1e-3, 2e-2,
-                                                (n_pages, kvh, ps, 1))
-                                    .astype(np.float32)).astype(jnp.bfloat16)
-        kw["v_scale"] = jnp.asarray(rng.uniform(1e-3, 2e-2,
-                                                (n_pages, kvh, ps, 1))
-                                    .astype(np.float32)).astype(jnp.bfloat16)
-        # per-head inverse redistribution rows: a few 2^e channels per head
-        mask = rng.random((kvh, dh)) < 0.2
-        kw["k_redist"] = jnp.asarray(kvq.redist_from_mask(mask))
-        kw["v_redist"] = jnp.asarray(kvq.redist_from_mask(~mask & (
-            rng.random((kvh, dh)) < 0.2)))
-    else:
-        kp = jnp.asarray(rng.normal(size=(n_pages, kvh, ps, dh))
-                         .astype(np.float32))
-        vp = jnp.asarray(rng.normal(size=(n_pages, kvh, ps, dh))
-                         .astype(np.float32))
-    # distinct physical pages per slot, scrambled
-    table = np.zeros((b, pages), np.int32)
-    perm = rng.permutation(np.arange(1, n_pages))
-    for i in range(b):
-        table[i] = perm[i * pages:(i + 1) * pages]
+    kp, vp, kw = PT.random_pages(rng, n_pages, kvh, ps, dh, mode)
+    table = PT.scrambled_table(rng, b, pages, n_pages)
     pos = jnp.asarray(rng.integers(0, pages * ps, b), dtype=jnp.int32)
-    return q, kp, vp, kw, jnp.asarray(table), pos
+    return q, kp, vp, kw, table, pos
 
 
 @pytest.mark.parametrize("mode", ["fp", "int8", "int4"])
@@ -401,9 +365,9 @@ def test_paged_kernel_interpret_matches_ref(mode, window, softcap):
     seed = {"fp": 0, "int8": 1, "int4": 3}[mode]
     q, kp, vp, kw, table, pos = _random_paged_case(seed, mode=mode)
     kw = dict(kw, window=window, softcap=softcap)
-    ref = PA.paged_attention_ref(q, kp, vp, table, pos, **kw)
-    out = PA.paged_attention_pallas(q, kp, vp, table, pos, interpret=True,
-                                    **kw)
+    ref = PT.run(PA.paged_attention_ref, q, kp, vp, table, pos, **kw)
+    out = PT.run(PA.paged_attention_pallas, q, kp, vp, table, pos,
+                 interpret=True, **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -442,9 +406,9 @@ def test_paged_kernel_walks_only_live_pages(mode, sq, h, kvh, window,
         {"fp": 20, "int8": 21, "int4": 23}[mode] + sq, sq=sq, h=h, kvh=kvh,
         mode=mode, pos=pos)
     kw = dict(kw, window=window, softcap=softcap)
-    ref = PA.paged_attention_ref(q, kp, vp, table, pos, **kw)
-    out = PA.paged_attention_pallas(q, kp, vp, table, pos, interpret=True,
-                                    **kw)
+    ref = PT.run(PA.paged_attention_ref, q, kp, vp, table, pos, **kw)
+    out = PT.run(PA.paged_attention_pallas, q, kp, vp, table, pos,
+                 interpret=True, **kw)
     np.testing.assert_allclose(np.asarray(out)[live], np.asarray(ref)[live],
                                atol=2e-5, rtol=2e-5)
 
@@ -470,7 +434,8 @@ def test_pages_past_the_live_range_are_never_read(mode):
     def call(fn, a, **extra):
         a = dict(arrays, **a)
         ops = {n: a[n] for n in kw}
-        return np.asarray(fn(q, a["k"], a["v"], table, pos, **ops, **extra))
+        return np.asarray(PT.run(fn, q, a["k"], a["v"], table, pos, **ops,
+                                 **extra))
 
     out = call(PA.paged_attention_pallas, poisoned, interpret=True)
     ref = call(PA.paged_attention_ref, zeroed)
@@ -482,14 +447,15 @@ def test_paged_kernel_respects_page_table_indirection():
     """Swapping two physical pages while swapping the table entries leaves
     the output invariant — the kernel really reads through the table."""
     q, kp, vp, _, table, pos = _random_paged_case(2)
-    ref = PA.paged_attention_ref(q, kp, vp, table, pos)
+    ref = PT.run(PA.paged_attention_ref, q, kp, vp, table, pos)
     a, b_ = int(table[0, 0]), int(table[0, 1])
     swap = jnp.asarray([a, b_])
     swapped = jnp.asarray([b_, a])
     kp2 = kp.at[swap].set(kp[swapped])
     vp2 = vp.at[swap].set(vp[swapped])
     table2 = table.at[0, 0].set(b_).at[0, 1].set(a)
-    out = PA.paged_attention_pallas(q, kp2, vp2, table2, pos, interpret=True)
+    out = PT.run(PA.paged_attention_pallas, q, kp2, vp2, table2, pos,
+                 interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import paged_testlib as PT
 from repro.configs import get_config
 from repro.kernels import paged_attention as PA
 from repro.models import transformer as T
@@ -26,47 +27,19 @@ PS = 8          # page size for every case here
 
 def _block_case(seed, *, b, sq, h=8, kvh=4, dh=16, pages=4, mode="fp",
                 start=None):
-    """Random [b, sq] query-block operands over a scrambled page table.
-    ``start``: each slot's first query-row position (random if None)."""
-    from repro.serve import kvq
-
+    """Random [b, sq] query-block operands over a scrambled page table,
+    pages head-major (see ``paged_testlib``).  ``start``: each slot's
+    first query-row position (random if None)."""
     rng = np.random.default_rng(seed)
     n_pages = 1 + b * pages                        # + scratch page 0
     q = jnp.asarray(rng.normal(size=(b, sq, h, dh)).astype(np.float32))
-    kw = {}
-    if mode == "int8":
-        kp = jnp.asarray(rng.integers(-127, 128, (n_pages, kvh, PS, dh)),
-                         dtype=jnp.int8)
-        vp = jnp.asarray(rng.integers(-127, 128, (n_pages, kvh, PS, dh)),
-                         dtype=jnp.int8)
-        for s in ("k_scale", "v_scale"):
-            kw[s] = jnp.asarray(rng.uniform(1e-3, 2e-2, (n_pages, kvh, PS, 1))
-                                .astype(np.float32))
-    elif mode == "int4":
-        ki = rng.integers(-7, 8, (n_pages, kvh, PS, dh)).astype(np.int8)
-        vi = rng.integers(-7, 8, (n_pages, kvh, PS, dh)).astype(np.int8)
-        kp, vp = kvq.pack_int4(jnp.asarray(ki)), kvq.pack_int4(jnp.asarray(vi))
-        for s in ("k_scale", "v_scale"):
-            kw[s] = jnp.asarray(rng.uniform(1e-3, 2e-2, (n_pages, kvh, PS, 1))
-                                .astype(np.float32)).astype(jnp.bfloat16)
-        mask = rng.random((kvh, dh)) < 0.2
-        kw["k_redist"] = jnp.asarray(kvq.redist_from_mask(mask))
-        kw["v_redist"] = jnp.asarray(kvq.redist_from_mask(
-            ~mask & (rng.random((kvh, dh)) < 0.2)))
-    else:
-        kp = jnp.asarray(rng.normal(size=(n_pages, kvh, PS, dh))
-                         .astype(np.float32))
-        vp = jnp.asarray(rng.normal(size=(n_pages, kvh, PS, dh))
-                         .astype(np.float32))
-    table = np.zeros((b, pages), np.int32)
-    perm = rng.permutation(np.arange(1, n_pages))
-    for i in range(b):
-        table[i] = perm[i * pages:(i + 1) * pages]
+    kp, vp, kw = PT.random_pages(rng, n_pages, kvh, PS, dh, mode)
+    table = PT.scrambled_table(rng, b, pages, n_pages)
     if start is None:
         pos = rng.integers(0, pages * PS - sq + 1, b)
     else:
         pos = np.full(b, start)
-    return q, kp, vp, kw, jnp.asarray(table), jnp.asarray(pos, jnp.int32)
+    return q, kp, vp, kw, table, jnp.asarray(pos, jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -83,9 +56,9 @@ def test_prefill_block_interpret_matches_ref(mode, sq, window, softcap):
     q, kp, vp, kw, table, pos = _block_case(seed, b=1, sq=sq, mode=mode,
                                             start=PS + 3)
     kw = dict(kw, window=window, softcap=softcap)
-    ref = PA.paged_attention_ref(q, kp, vp, table, pos, **kw)
-    out = PA.paged_attention_pallas(q, kp, vp, table, pos, interpret=True,
-                                    **kw)
+    ref = PT.run(PA.paged_attention_ref, q, kp, vp, table, pos, **kw)
+    out = PT.run(PA.paged_attention_pallas, q, kp, vp, table, pos,
+                 interpret=True, **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -110,9 +83,9 @@ def test_prefill_step_walks_only_live_pages(mode, starts, h, kvh, window,
     table = table.at[0].set(0)
     assert (max(starts) + sq - 1) // PS < pages - 1
     kw = dict(kw, window=window, softcap=softcap)
-    ref = PA.paged_attention_ref(q, kp, vp, table, pos, **kw)
-    out = PA.paged_attention_pallas(q, kp, vp, table, pos, interpret=True,
-                                    **kw)
+    ref = PT.run(PA.paged_attention_ref, q, kp, vp, table, pos, **kw)
+    out = PT.run(PA.paged_attention_pallas, q, kp, vp, table, pos,
+                 interpret=True, **kw)
     np.testing.assert_allclose(np.asarray(out)[1:], np.asarray(ref)[1:],
                                atol=2e-5, rtol=2e-5)
 
@@ -123,9 +96,9 @@ def test_verify_block_interpret_matches_ref(mode):
     speculative-verify face of the same kernel."""
     seed = {"fp": 4, "int8": 5, "int4": 6}[mode]
     q, kp, vp, kw, table, pos = _block_case(seed, b=3, sq=4, mode=mode)
-    ref = PA.paged_attention_ref(q, kp, vp, table, pos, **kw)
-    out = PA.paged_attention_pallas(q, kp, vp, table, pos, interpret=True,
-                                    **kw)
+    ref = PT.run(PA.paged_attention_ref, q, kp, vp, table, pos, **kw)
+    out = PT.run(PA.paged_attention_pallas, q, kp, vp, table, pos,
+                 interpret=True, **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -137,10 +110,10 @@ def test_start_offset_causal_mask_rows():
     q, kp, vp, _, table, _ = _block_case(7, b=1, sq=4, start=0)
     start = 9
     pos = jnp.asarray([start], jnp.int32)
-    block = PA.paged_attention_ref(q, kp, vp, table, pos)
+    block = PT.run(PA.paged_attention_ref, q, kp, vp, table, pos)
     for i in range(q.shape[1]):
-        row = PA.paged_attention_ref(q[:, i], kp, vp, table,
-                                     jnp.asarray([start + i], jnp.int32))
+        row = PT.run(PA.paged_attention_ref, q[:, i], kp, vp, table,
+                     jnp.asarray([start + i], jnp.int32))
         np.testing.assert_array_equal(np.asarray(block[:, i]),
                                       np.asarray(row))
 
@@ -149,12 +122,12 @@ def test_decode_row_unchanged_by_block_generalization():
     """sq=1 block == the 3-D decode call bit for bit (the PR's no-regression
     contract for the existing decode path)."""
     q, kp, vp, _, table, pos = _block_case(8, b=3, sq=1)
-    out4 = PA.paged_attention_ref(q, kp, vp, table, pos)
-    out3 = PA.paged_attention_ref(q[:, 0], kp, vp, table, pos)
+    out4 = PT.run(PA.paged_attention_ref, q, kp, vp, table, pos)
+    out3 = PT.run(PA.paged_attention_ref, q[:, 0], kp, vp, table, pos)
     assert out4.shape == (3, 1, 8, 16) and out3.shape == (3, 8, 16)
     np.testing.assert_array_equal(np.asarray(out4[:, 0]), np.asarray(out3))
-    outp = PA.paged_attention_pallas(q[:, 0], kp, vp, table, pos,
-                                     interpret=True)
+    outp = PT.run(PA.paged_attention_pallas, q[:, 0], kp, vp, table, pos,
+                  interpret=True)
     np.testing.assert_allclose(np.asarray(outp), np.asarray(out3),
                                atol=2e-5, rtol=2e-5)
 

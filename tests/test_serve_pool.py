@@ -180,6 +180,53 @@ def test_fp_pages_bit_exact_vs_dense_decode(small_model):
     assert rel < 0.05, rel
 
 
+@pytest.mark.parametrize("heads", [4, 8],
+                         ids=["two-positions-a-row", "one-position-a-row"])
+@pytest.mark.parametrize("step", ["decode", "prefill", "verify"])
+def test_fp_page_steps_bit_exact_vs_dense_cache(heads, step):
+    """fp pages at both stored-row layouts (``kvq.page_fold``: 4 heads of
+    16 make 64-lane rows, two positions to a stored row; 8 heads, one):
+    each paged step's logits equal the dense-cache path's bit for bit.
+    Decode against ``decode_step`` after a dense prefill written into the
+    pages; a prefill chunk and a verify block of 16 rows from position 0,
+    over a page budget wider than the rows, against ``forward`` over the
+    same rows."""
+    cfg = get_config("gpt2-small", reduced=True).replace(
+        n_layers=2, d_model=16 * heads, n_heads=heads, n_kv_heads=heads,
+        d_ff=256, vocab_size=120)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    seq = np.random.default_rng(0).integers(0, 120, 16).astype(np.int32)
+    pool = PagePool(cfg, n_slots=2, s_max=32, page_size=8, mode="fp",
+                    dtype=jnp.float32)
+    assert pool.admit(0, 16) and pool.admit(1, 16)
+    if step == "decode":
+        s = 12
+        out = T.forward(cfg, params, jnp.tile(jnp.asarray(seq[:s])[None],
+                                              (2, 1)),
+                        cache=init_cache(cfg, 2, 32, dtype=jnp.float32))
+        want, _ = T.decode_step(cfg, params, jnp.full((2, 1), seq[s]),
+                                out["cache"])
+        pool.write_prefill(0, out["cache"]["k"][:, 0, :s],
+                           out["cache"]["v"][:, 0, :s])
+        pool.write_prefill(1, out["cache"]["k"][:, 1, :s],
+                           out["cache"]["v"][:, 1, :s])
+        got, _ = T.decode_step_paged(cfg, params, jnp.full((2, 1), seq[s]),
+                                     pool.state(), pool.table(),
+                                     jnp.full(2, s, jnp.int32))
+    else:
+        tokens = jnp.tile(jnp.asarray(seq)[None], (2, 1))
+        want = T.forward(cfg, params, tokens)["logits"]
+        zero, rows = jnp.zeros(2, jnp.int32), jnp.full(2, 16, jnp.int32)
+        if step == "prefill":
+            got, _ = T.prefill_chunk_paged(cfg, params, tokens, pool.state(),
+                                           pool.table(), zero, zero, rows)
+        else:
+            got, _ = T.decode_verify_paged(cfg, params, tokens, pool.state(),
+                                           pool.table(), zero, rows)
+    assert pool.kv["k"].shape[2:] == ((4, 128) if heads == 4 else (8, 128))
+    assert bool(jnp.array_equal(got, want))
+
+
 # ---------------------------------------------------------------------------
 # No-alignment-fallback guarantee (acceptance criterion)
 # ---------------------------------------------------------------------------

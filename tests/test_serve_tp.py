@@ -242,10 +242,12 @@ def test_tp_mesh_larger_than_devices_raises():
 
 def test_kernel_head_slice_parity():
     """The paged kernels derive kvh (and the GQA group) from array shapes,
-    so running the reference per KV-head-shard and concatenating equals the
-    full-width call — the property the mesh'd attention path relies on."""
-    import jax
+    and a stored page keeps each head's lanes in one contiguous run, so
+    running the reference on each KV-head shard's contiguous cut of the
+    lane axis and concatenating equals the full-width call — the property
+    the mesh'd attention path relies on."""
     import jax.numpy as jnp
+    import paged_testlib as PT
     from repro.kernels.paged_attention import paged_attention_ref
 
     b, h, kvh, dh, ps, npages = 2, 8, 4, 16, 8, 6
@@ -255,15 +257,16 @@ def test_kernel_head_slice_parity():
     v = jnp.asarray(rng.standard_normal((npages, kvh, ps, dh)), jnp.float32)
     table = jnp.asarray([[0, 2, 4], [1, 3, 5]], jnp.int32)
     pos = jnp.asarray([13, 9], jnp.int32)
+    k, v, kw = PT.stored(k, v, {}, table)
 
-    full = paged_attention_ref(q, k, v, table, pos)
+    full = paged_attention_ref(q, k, v, table, pos, **kw)
     g = h // kvh
     for shards in (2, 4):
-        kl, hl = kvh // shards, (kvh // shards) * g
+        hl, ll = (kvh // shards) * g, k.shape[-1] // shards
         parts = [paged_attention_ref(
             q[:, i * hl:(i + 1) * hl],
-            k[:, i * kl:(i + 1) * kl], v[:, i * kl:(i + 1) * kl],
-            table, pos) for i in range(shards)]
+            k[..., i * ll:(i + 1) * ll], v[..., i * ll:(i + 1) * ll],
+            table, pos, **kw) for i in range(shards)]
         np.testing.assert_array_equal(np.concatenate(parts, axis=1),
                                       np.asarray(full))
 

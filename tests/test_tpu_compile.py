@@ -6,7 +6,9 @@ not aligned to (8, 128), more VMEM than a kernel may use) is what the
 chip would refuse.  Shapes are GPT-2 small's published widths
 (d 768, 12 heads, head_dim 64, d_ff 3072) at the serve step's decode and
 prefill-chunk sizes; the paged-attention kernel also at GPT-2 large's
-(20 kv heads of 64) and the chat cells' pool.
+(20 kv heads of 64) and the chat cells' pool; and the whole paged step
+programs at GPT-2 large's and Moonlight's widths, checked for what the
+pool costs inside them.
 
 The topology is described only inside the fixture below: only one
 process at a time may load the TPU library, so doing it at import (here,
@@ -14,6 +16,7 @@ in ``skipif``/``parametrize`` or in ``conftest.py``) would break the other
 test workers.  Keep every such compile in this one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,8 +26,15 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.analysis.hlo import tpu_kernels
 from repro.configs import get_config
+from repro.configs.gpt2 import GPT2_LARGE
+from repro.configs.moonlight_16b_a3b import CHIP_SHARE
 from repro.kernels import dispatch
+from repro.kernels import paged_attention as PA
 from repro.kernels.paged_attention import paged_attention_pallas
+from repro.models import transformer as T
+from repro.models.attention import cache_rows
+from repro.models.common import group_name
+from repro.serve import kvq
 
 CFG = get_config("gpt2-small")
 D, FF, H, DH = CFG.d_model, CFG.d_ff, CFG.n_heads, CFG.head_dim
@@ -108,29 +118,34 @@ LARGE = (20, 20, 64, 32, 32, jnp.float32)
     ("fp", LARGE), ("int8", LARGE)],
     ids=["fp", "int8", "int4", "gpt2-large-fp", "gpt2-large-int8"])
 def test_paged_attention_compiles(one_chip, mode, widths, sq):
-    """Head-major pages [n_pages, kvh, ps, dh]: one grid step's block is a
-    page of every kv head, ``(kvh, ps, dh)``; int4 pages unpack their
-    nibbles in the kernel."""
+    """Stored pages [L, n_pages, R, lanes] (``kvq.page_shape``: a page's
+    rows of every kv head side by side) read at a traced layer: one grid
+    step's block is one stored page, each head a run of its lanes; int4
+    pages unpack their nibbles in the kernel."""
     kvh, h, dh, slots, pages_per_slot, int_q_dtype = widths
     n_pages = slots * pages_per_slot + 1
     pk = dh // 2 if mode == "int4" else dh
     dtype = jnp.bfloat16 if mode == "fp" else jnp.int8
     q_dtype = jnp.bfloat16 if mode == "fp" else int_q_dtype
-    pages = _shape(one_chip, (n_pages, kvh, PS, pk), dtype)
+    shape = kvq.page_shape(2, n_pages, PS, kvh, pk)
+    pages = _shape(one_chip, shape, dtype)
     q = _shape(one_chip, (slots, sq, h, dh), q_dtype)
     table = _shape(one_chip, (slots, pages_per_slot), jnp.int32)
     pos = _shape(one_chip, (slots,), jnp.int32)
+    layer = _shape(one_chip, (), jnp.int32)
     kw = {}
     if mode != "fp":
         sdt = jnp.bfloat16 if mode == "int4" else jnp.float32
-        kw["k_scale"] = kw["v_scale"] = _shape(one_chip,
-                                               (n_pages, kvh, PS, 1), sdt)
+        rows = shape[2]
+        kw["k_scale"] = kw["v_scale"] = _shape(
+            one_chip, (slots, pages_per_slot, rows, kvh * PS // rows), sdt)
     if mode == "int4":
         kw["k_redist"] = kw["v_redist"] = _shape(one_chip, (kvh, dh),
                                                  jnp.float32)
     compiled = jax.jit(
-        lambda q, k, v, t, p, kw: paged_attention_pallas(q, k, v, t, p, **kw)
-    ).lower(q, pages, pages, table, pos, kw).compile()
+        lambda q, k, v, t, p, l, kw: paged_attention_pallas(
+            q, k, v, t, p, page_size=PS, layer=l, **kw)
+    ).lower(q, pages, pages, table, pos, layer, kw).compile()
     assert tpu_kernels(compiled.as_text()) == {"paged_attention": 1}
 
 
@@ -194,21 +209,111 @@ def test_wide_dense_site_compiles(one_chip, m):
 @pytest.mark.parametrize("mode", ["fp", "int8"])
 def test_latent_paged_attention_compiles(one_chip, mode, sq):
     """Moonlight's latent pages: one 576-wide row per position (512 latent
-    + 64 rotary) read as key, its first 512 columns as value, 16 query
-    heads, float32 queries, at 32 slots and a 256-page bucket (4096
-    positions)."""
+    + 64 rotary), two to a stored row of 1152, read as key, its first 512
+    columns as value, 16 query heads, float32 queries, at 32 slots and a
+    256-page bucket (4096 positions)."""
     slots, pages_per_slot, h, width, v_dim = 32, 256, 16, 576, 512
     n_pages = slots * pages_per_slot + 1
     dtype = jnp.bfloat16 if mode == "fp" else jnp.int8
-    pages = _shape(one_chip, (n_pages, 1, PS, width), dtype)
+    shape = kvq.page_shape(2, n_pages, PS, 1, width)
+    assert shape[2:] == (PS // 2, 2 * width)
+    pages = _shape(one_chip, shape, dtype)
     q = _shape(one_chip, (slots, sq, h, width), jnp.float32)
     table = _shape(one_chip, (slots, pages_per_slot), jnp.int32)
     pos = _shape(one_chip, (slots,), jnp.int32)
+    layer = _shape(one_chip, (), jnp.int32)
     kw = {}
     if mode == "int8":
-        kw["k_scale"] = _shape(one_chip, (n_pages, 1, PS, 1), jnp.float32)
+        kw["k_scale"] = _shape(one_chip, (slots, pages_per_slot, PS // 2, 2),
+                               jnp.float32)
     compiled = jax.jit(
-        lambda q, k, t, p, kw: paged_attention_pallas(
-            q, k, None, t, p, v_dim=v_dim, scale=192 ** -0.5, **kw)
-    ).lower(q, pages, table, pos, kw).compile()
+        lambda q, k, t, p, l, kw: paged_attention_pallas(
+            q, k, None, t, p, page_size=PS, layer=l, v_dim=v_dim,
+            scale=192 ** -0.5, **kw)
+    ).lower(q, pages, table, pos, layer, kw).compile()
     assert tpu_kernels(compiled.as_text()) == {"paged_attention": 1}
+
+
+# The whole paged step programs, compiled as the chat cells run them: the
+# pool's arrays at 2049 pages of 16 (GPT-2 large, 2 layers, bf16 and int8
+# pages) and 8193 (Moonlight's dense layer and 2 expert layers, int8 latent
+# pages; one expert held: the pool does not depend on it), 32 slots.
+# What the pool may cost in them: nothing moves a pool array or one layer of
+# it (no copy, transpose, dynamic-slice or dynamic-update-slice of that
+# shape: each layer writes its rows in place and the kernel reads the
+# stacked pages), every pool array enters in row-major layout, stored
+# unpadded, and (where the pool is the largest state: GPT-2) the program's
+# temporaries stay under one layer's pages.
+_MOVES = ("copy", "transpose", "dynamic-slice", "dynamic-update-slice")
+_INSTR = re.compile(r"= (\w+)\[([\d,]*)\]\{([^}]*)\} ([\w-]+)\(")
+
+
+def _pool_shapes(cfg, mode, n_pages, one_chip):
+    kvh, dh = cache_rows(cfg)
+    quant = kvq.make_quantizer(mode, kvh=kvh, dh=dh, latent=cfg.is_mla)
+    out = {}
+    for key, lo, hi in cfg.layer_groups:
+        arrs = jax.eval_shape(
+            lambda: quant.page_arrays(hi - lo, n_pages, PS, kvh, dh))
+        out.update({group_name(key, n): _shape(one_chip, a.shape, a.dtype)
+                    for n, a in arrs.items()})
+    return out
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+@pytest.mark.parametrize("model,mode", [
+    ("gpt2-large", "fp"), ("gpt2-large", "int8"), ("moonlight", "int8")])
+def test_paged_step_keeps_the_pool_in_place(one_chip, model, mode, kind):
+    if model == "gpt2-large":
+        cfg, n_pages, pages_per_slot = GPT2_LARGE.replace(n_layers=2), 2049, 32
+    else:
+        cfg, n_pages, pages_per_slot = (CHIP_SHARE.replace(n_layers=3,
+                                                           n_held=1),
+                                        8193, 256)
+    slots = 32
+    params = jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda a: _shape(one_chip, a.shape, (
+        jnp.bfloat16 if a.dtype == jnp.float32 else a.dtype)), params)
+    kv = _pool_shapes(cfg, mode, n_pages, one_chip)
+    vec = _shape(one_chip, (slots,), jnp.int32)
+    table = _shape(one_chip, (slots, pages_per_slot), jnp.int32)
+    tokens = _shape(one_chip, (slots, 1 if kind == "decode" else CHUNK),
+                    jnp.int32)
+    if kind == "decode":
+        step = lambda p, t, kv, tab, pos: T.decode_step_paged(
+            cfg, p, t, kv, tab, pos)
+        args = (params, tokens, kv, table, vec)
+    else:
+        step = lambda p, t, kv, tab, s, lo, hi: T.prefill_chunk_paged(
+            cfg, p, t, kv, tab, s, lo, hi)
+        args = (params, tokens, kv, table, vec, vec, vec)
+    prev = PA.set_paged_impl("pallas")
+    try:
+        compiled = jax.jit(step, donate_argnums=(2,)).lower(*args).compile()
+    finally:
+        PA.set_paged_impl(prev)
+    text = compiled.as_text()
+    assert tpu_kernels(text).get("paged_attention") == len(cfg.layer_groups)
+
+    pool = {tuple(a.shape) for a in kv.values()}
+    pool |= {s[1:] for s in pool} | {(1,) + s[1:] for s in pool}
+    moved = [(op, dims) for _, dims, _, op in _INSTR.findall(text)
+             if op in _MOVES
+             and tuple(int(d) for d in dims.split(",") if d) in pool]
+    assert not moved, moved
+
+    entry = text.split("entry_computation_layout={(", 1)[1].split(")->", 1)[0]
+    for a in kv.values():
+        row_major = ",".join(map(str, range(len(a.shape) - 1, -1, -1)))
+        dims = ",".join(map(str, a.shape))
+        layouts = re.findall(r"\[" + dims + r"\]\{([\d,]*)", entry)
+        assert layouts and set(layouts) == {row_major}, (a.shape, layouts)
+
+    mem = compiled.memory_analysis()
+    logical = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(args))
+    assert mem.argument_size_in_bytes <= 1.07 * logical
+    layer_pages = max(a.size // a.shape[0] * a.dtype.itemsize
+                      for n, a in kv.items() if not n.endswith("_scale"))
+    if model == "gpt2-large":
+        assert mem.temp_size_in_bytes < layer_pages, (
+            mem.temp_size_in_bytes, layer_pages)
